@@ -36,7 +36,7 @@ class SQLDispatcher(FileDispatcher):
                 finally:
                     try:
                         conn.close()
-                    except Exception:  # graftlint: disable=EXC-HYGIENE -- DB driver surface (sqlalchemy/dbapi) has no stable exception taxonomy
+                    except Exception:  # graftlint: disable=EXC-HYGIENE -- DB driver surface (sqlalchemy/dbapi) has no stable exception classification
                         pass
             else:
                 df = pandas.read_sql(sql, con, index_col=index_col, **kwargs)

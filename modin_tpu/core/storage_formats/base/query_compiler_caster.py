@@ -10,7 +10,7 @@ one layer lower, on every public method of each concrete query compiler:
   including ``self`` — to the cheapest common backend, chosen by
   :class:`~.query_compiler_calculator.BackendCostCalculator` from the
   compilers' stay/move costs.  The TPU cost model makes this
-  PCIe/tunnel-transfer aware: big device frames pull small host frames to
+  PCIe-transfer aware: big device frames pull small host frames to
   the device, not the reverse.
 - **pre-op auto-switch** (``AutoSwitchBackend`` config, default off): even
   single-backend calls compare the cost of staying against moving to each

@@ -44,6 +44,16 @@ from modin_tpu.observability.spans import span
 _FOLD_DELAY_S = 0.0
 
 
+def _safely_castable(got: Any, want: Any) -> bool:
+    """``np.can_cast(..., "safe")``, where a dtype numpy cannot interpret
+    (a pandas extension dtype such as the pandas 3 string dtype) is simply
+    not safely castable."""
+    try:
+        return bool(np.can_cast(got, want, casting="safe"))
+    except TypeError:
+        return False
+
+
 class _BatchRecord:
     """One admitted micro-batch: its sequence number, row span, arrival
     stamps, and (until folded into every view) the host rows."""
@@ -343,7 +353,7 @@ class Feed:
             got = pdf[col].dtype
             if got == want:
                 continue
-            if np.can_cast(got, want, casting="safe"):
+            if _safely_castable(got, want):
                 pdf[col] = pdf[col].astype(want)
             else:
                 self._reject(

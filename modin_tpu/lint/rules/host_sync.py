@@ -6,7 +6,7 @@ crossing is where the resilience policy lives — classification, bounded
 retry, and the wall-clock watchdog.  A stray ``jax.device_get``, a
 ``.block_until_ready()``, or an ``np.asarray``/``float``/``int``/``bool``
 coercion of a device value performs the identical blocking transfer with
-*none* of that machinery: a wedged tunnel hangs the query forever and an
+*none* of that machinery: a wedged device hangs the query forever and an
 XlaRuntimeError surfaces raw at a random call site.
 
 Detection is a per-function forward pass:
@@ -46,7 +46,7 @@ from modin_tpu.lint.rules._ast_utils import (
 )
 
 #: modules that ARE the seam (or deliberately below it): the engine wrapper,
-#: the resilience policy itself, the version-compat shims, and the
+#: the resilience policy itself, the shared jax import site, and the
 #: fault-injection harness that wraps the seam in tests
 SEAM_MODULES = (
     "modin_tpu/parallel/engine.py",

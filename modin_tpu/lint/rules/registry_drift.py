@@ -22,7 +22,7 @@ Three quiet ways observability rots:
    ``modin_tpu/observability/spans.py``: undeclared span name, dead
    registry pattern, or undocumented family all fail.  Runtime-built names
    go through ``layer_span`` and are exempt (they are covered by the
-   layer-tag taxonomy, not the registry).
+   layer-tag classification, not the registry).
 
 3. **env vars** — a ``MODIN_TPU_*`` variable read via raw ``os.environ``
    bypasses ``config/envvars.py`` entirely: no default, no type checking,

@@ -7,7 +7,7 @@ breaker trips OPEN, or a device failure is classified terminal (OOM,
 device-lost, retries exhausted) — it calls ``dump_flight_record`` and the
 last N spans are written as a chrome://tracing-loadable JSON file under
 ``MODIN_TPU_TRACE_DIR``: the trace that *led up to* the failure, tying the
-PR-1 failure taxonomy to its preceding query activity.  The dump also
+PR-1 failure classification to its preceding query activity.  The dump also
 embeds the graftmeter metrics snapshot taken at dump time under
 ``otherData.metrics`` (counter state used to die with the process) plus
 the counter-track samples (device/host residency, live spans).

@@ -9,7 +9,7 @@ interval, attributes), spans nest via a thread-local stack, and finished
 spans are delivered to any active collectors (``profile()``) and to the
 flight-recorder ring buffer (modin_tpu/observability/flight_recorder.py).
 
-Layer tags reuse the ``modin_layer`` taxonomy the ``ClassLogger`` mixin
+Layer tags reuse the ``modin_layer`` classification the ``ClassLogger`` mixin
 already stamps on every subsystem (``PANDAS-API``, ``QUERY-COMPILER``,
 ``JAX-ENGINE``, ``CORE-IO``, ...) plus ``SHUFFLE`` for the range-partition
 shuffle, so a profile slices the same way the trace log always has.
@@ -27,7 +27,7 @@ REGISTRY-DRIFT rule exactly like ``emit_metric`` names are against
 ``METRICS`` — an undeclared span name, a dead registry pattern, or an
 undocumented family fails the lint.  The per-method spans emitted through
 ``layer_span`` by the logging decorator carry runtime-built names
-(``<Class>.<method>``) and are documented as the layer taxonomy instead.
+(``<Class>.<method>``) and are documented as the layer classification instead.
 """
 
 from __future__ import annotations
@@ -56,7 +56,7 @@ TRACE_ON: bool = False
 #: fails the lint — and requires each family's stable prefix to appear in
 #: docs/ (see docs/observability.md).  Per-method spans from the logging
 #: decorator (``layer_span``) have runtime names and are covered by the
-#: layer-tag taxonomy instead.
+#: layer-tag classification instead.
 SPANS = (
     (
         "engine.*.attempt",

@@ -31,12 +31,7 @@ def _build_bincount(n_blocks: int, g_padded: int, interpret: bool):
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        vmem = pltpu.VMEM
-    except ImportError:  # pragma: no cover
-        vmem = None
+    from jax.experimental.pallas import tpu as pltpu
 
     def kernel(codes_ref, out_ref):
         i = pl.program_id(0)
@@ -55,7 +50,7 @@ def _build_bincount(n_blocks: int, g_padded: int, interpret: bool):
         partial = jnp.sum(onehot, axis=(0, 1), dtype=jnp.int32)  # [g_padded]
         out_ref[0, :] += partial
 
-    block_spec_kwargs = {"memory_space": vmem} if vmem is not None else {}
+    block_spec_kwargs = {"memory_space": pltpu.VMEM}
     # index maps must yield int32: with x64 enabled a literal 0 traces as a
     # weak int64 and Mosaic refuses the (i32, i64) index tuple
     zero = np.int32(0)
@@ -73,29 +68,81 @@ def _build_bincount(n_blocks: int, g_padded: int, interpret: bool):
     )
 
 
-@functools.lru_cache(maxsize=None)
-def _jit_bincount_wrapper(p_len: int, num_groups: int, interpret: bool):
+def _bincount_fn(p_len: int, num_groups: int, interpret: bool, mesh: Any = None):
+    """The (unjitted) histogram program over a length-``p_len`` code vector.
+
+    ``mesh=None`` is the single-device program.  With a mesh, the kernel runs
+    under ``shard_map`` over the "rows" axis — Mosaic kernels cannot be
+    partitioned automatically, so a row-sharded operand in a plain ``jit``
+    is refused by the TPU compiler — each shard histograms its own
+    ``p_len / S`` codes and one ``psum`` adds the partials.
+    """
     import jax
     import jax.numpy as jnp
 
+    n_shards = 1 if mesh is None else int(mesh.shape["rows"])
+    local_len = p_len // n_shards
     # slots for every real group + the overflow bucket, padded to lanes
     g_padded = max(-(-(num_groups + 1) // _LANES) * _LANES, _LANES)
     block_elems = _BR * _LANES
-    n_blocks = -(-p_len // block_elems)
+    n_blocks = -(-local_len // block_elems)
     padded_len = n_blocks * block_elems
     call = _build_bincount(n_blocks, g_padded, interpret)
 
-    def fn(codes):
+    def local(codes):
         c = codes.astype(jnp.int32)
-        if padded_len > p_len:
+        if padded_len > local_len:
             # overflow bucket: padded tail must not count toward any group
             c = jnp.concatenate(
-                [c, jnp.full(padded_len - p_len, num_groups, jnp.int32)]
+                [c, jnp.full(padded_len - local_len, num_groups, jnp.int32)]
             )
-        counts = call(c.reshape(n_blocks * _BR, _LANES))
-        return counts[0, :num_groups].astype(jnp.int64)
+        return call(c.reshape(n_blocks * _BR, _LANES))
 
-    return jax.jit(fn)
+    if mesh is None:
+        counts_of = local
+    else:
+        from jax.sharding import PartitionSpec as P
+
+        from modin_tpu.parallel.jax_compat import shard_map
+
+        def local_then_psum(codes):
+            return jax.lax.psum(local(codes), "rows")
+
+        counts_of = shard_map(
+            local_then_psum,
+            mesh=mesh,
+            in_specs=P("rows"),
+            out_specs=P(),
+            check_vma=False,
+        )
+
+    def fn(codes):
+        return counts_of(codes)[0, :num_groups].astype(jnp.int64)
+
+    return fn
+
+
+@functools.lru_cache(maxsize=None)
+def _jit_bincount_wrapper(
+    p_len: int, num_groups: int, interpret: bool, mesh_key: str = ""
+):
+    """``mesh_key`` is "" for an operand on one device, else the live mesh's
+    shape key (cache key only, like ``shuffle._jit_shuffle``: the program
+    closes over the mesh captured here)."""
+    import jax
+
+    mesh = None
+    if mesh_key:
+        from modin_tpu.parallel.mesh import get_mesh
+
+        mesh = get_mesh()
+    return jax.jit(_bincount_fn(p_len, num_groups, interpret, mesh))
+
+
+def _row_shards_of(codes: Any) -> int:
+    """How many devices ``codes`` is laid out over (1 when unsharded)."""
+    sharding = getattr(codes, "sharding", None)
+    return len(sharding.device_set) if sharding is not None else 1
 
 
 def pallas_bincount(codes: Any, num_groups: int, interpret: bool = False) -> Any:
@@ -105,9 +152,14 @@ def pallas_bincount(codes: Any, num_groups: int, interpret: bool = False) -> Any
     """
     if num_groups > MAX_GROUPS:
         raise ValueError(f"pallas_bincount supports <= {MAX_GROUPS} groups")
-    return _jit_bincount_wrapper(int(codes.shape[0]), int(num_groups), bool(interpret))(
-        codes
-    )
+    mesh_key = ""
+    if _row_shards_of(codes) > 1:
+        from modin_tpu.parallel.mesh import mesh_shape_key
+
+        mesh_key = mesh_shape_key()
+    return _jit_bincount_wrapper(
+        int(codes.shape[0]), int(num_groups), bool(interpret), mesh_key
+    )(codes)
 
 
 def bincount_supported(codes: Any, num_groups: int) -> bool:
@@ -118,4 +170,10 @@ def bincount_supported(codes: Any, num_groups: int) -> bool:
         platform = next(iter(codes.devices())).platform
     except Exception:  # graftlint: disable=EXC-HYGIENE -- device-platform probe; any failure means 'no pallas path'
         return False
+    if _row_shards_of(codes) > 1:
+        from modin_tpu.parallel.mesh import num_row_shards
+
+        # the sharded form splits the vector evenly over the mesh rows
+        if int(codes.shape[0]) % num_row_shards():
+            return False
     return platform == "tpu"

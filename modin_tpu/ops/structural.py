@@ -106,8 +106,8 @@ def compact_rows(cols: List[Any], mask: Any, n: int) -> Tuple[List[Any], Any, An
     ``cols``/``mask`` may be deferred LazyExprs — the mask computation (e.g.
     ``df.a > 0``) fuses into the compaction program.  Returns (gathered
     columns, kept-count scalar, kept-positions array), all still on device:
-    the only host sync a filter needs is the scalar count (one RTT over a
-    remote tunnel, versus shipping an O(n) mask to host and positions back).
+    the only host sync a filter needs is the scalar count (one small fetch,
+    versus shipping an O(n) mask to host and positions back).
     Outputs keep the input padded size; pad rows land at the tail.
     """
     from modin_tpu.ops.lazy import run_fused

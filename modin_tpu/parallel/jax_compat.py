@@ -1,40 +1,12 @@
-"""Version-portable imports for jax APIs that moved between releases.
+"""The one import site for jax APIs the kernels share.
 
-The framework is written against the current jax surface; hosts pinned to an
-older jaxlib still carry the same functionality under earlier names.  Keep
-every such rename in this one module so kernel code imports a stable name.
+Written for the installed jax (0.9): ``jax.shard_map`` is the public name,
+with ``check_vma`` as its replication-check flag.  Kernel code imports it
+from here so graftlint's seam rules keep one module to name.
 """
 
 from __future__ import annotations
 
-from typing import Any, Callable
+from jax import shard_map
 
-
-def shard_map(
-    f: Callable,
-    *,
-    mesh: Any,
-    in_specs: Any,
-    out_specs: Any,
-    check_vma: bool = True,
-) -> Callable:
-    """``jax.shard_map``, falling back to ``jax.experimental.shard_map``.
-
-    The experimental form (jax < 0.5) spells the replication-check flag
-    ``check_rep`` instead of ``check_vma``; semantics are the same.
-    """
-    try:
-        from jax import shard_map as _shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        return _shard_map(
-            f,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            check_rep=check_vma,
-        )
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma
-    )
+__all__ = ["shard_map"]

@@ -200,7 +200,7 @@ def range_shuffle(
     Capacity slack doubles on overflow up to ``max_slack``; past that the
     keys are pathologically skewed and ShuffleSkewError tells the caller to
     take its non-shuffle path (a semantic fallback signal, NOT a device
-    failure — see modin_tpu/core/execution/resilience.py's taxonomy).
+    failure — see modin_tpu/core/execution/resilience.py's classification).
     """
     import jax.numpy as jnp
 

@@ -10,7 +10,7 @@ is a legal serving outcome — an untyped exception escaping the gate is a
 bug, and the chaos acceptance suite (scripts/serving_smoke.py) asserts it.
 
 These are *serving* decisions, deliberately disjoint from the
-infrastructure taxonomy in core/execution/resilience.py: a
+infrastructure classification in core/execution/resilience.py: a
 ``DeviceFailure`` means the accelerator runtime misbehaved; a
 ``ServingError`` means the system is protecting itself (or the caller's
 budget) on purpose.  ``classify_device_error`` therefore never captures

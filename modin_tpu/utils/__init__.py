@@ -385,7 +385,7 @@ def show_versions(as_json: Union[str, bool] = False) -> None:
     except ImportError:
         jax = None
     if jax is not None:
-        # device discovery can hang if a remote accelerator tunnel is down;
+        # device discovery can hang on a wedged accelerator runtime;
         # bound it with a daemon thread (NOT ThreadPoolExecutor: its atexit
         # hook would join a wedged worker and hang interpreter shutdown)
         result_queue: "queue.Queue" = queue.Queue()

@@ -178,3 +178,61 @@ class TestMemoryBudget:
         col = df._query_compiler._modin_frame._columns[0]
         ledger.enforce()
         assert col.host_cache is not None
+
+
+class TestCompilationCachePlacement:
+    """The persistent compile cache is placed from outside (engine.py)."""
+
+    @pytest.fixture
+    def config_updates(self, monkeypatch):
+        import jax
+
+        seen = {}
+        monkeypatch.setattr(
+            jax.config, "update", lambda name, value: seen.__setitem__(name, value)
+        )
+        return seen
+
+    def test_env_var_means_no_directory_set_in_code(
+        self, monkeypatch, tmp_path, config_updates
+    ):
+        from modin_tpu.parallel import engine
+
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "outside"))
+        engine._place_compilation_cache()
+        assert "jax_compilation_cache_dir" not in config_updates
+        assert config_updates["jax_persistent_cache_min_compile_time_secs"] == 0.0
+        assert not (tmp_path / "outside").exists()  # jax makes it, not us
+
+    def test_unset_means_one_fixed_path_in_the_checkout(
+        self, monkeypatch, tmp_path, config_updates
+    ):
+        import os
+
+        from modin_tpu.parallel import engine
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        monkeypatch.setattr(
+            engine, "DEFAULT_COMPILATION_CACHE_DIR", str(tmp_path / "jax_cache")
+        )
+        engine._place_compilation_cache()
+        assert config_updates["jax_compilation_cache_dir"] == str(
+            tmp_path / "jax_cache"
+        )
+        assert os.path.isdir(tmp_path / "jax_cache")
+
+    def test_default_path_is_fixed_and_inside_the_checkout(self):
+        import os
+
+        import modin_tpu
+        from modin_tpu.parallel import engine
+
+        checkout = os.path.dirname(os.path.dirname(os.path.abspath(modin_tpu.__file__)))
+        assert engine.DEFAULT_COMPILATION_CACHE_DIR == os.path.join(
+            checkout, ".modin_tpu", "jax_cache"
+        )
+
+    def test_option_is_gone(self):
+        import modin_tpu.config as config
+
+        assert not hasattr(config, "CompilationCacheDir")

@@ -1,0 +1,143 @@
+"""The main path's kernels, compiled at 1e8 rows for a DESCRIBED TPU v5e.
+
+The TPU compiler is installed in the CPU-only sandbox and compiles for a
+chip that is described, not attached (on-chip-measurement guide, section 2):
+what Mosaic or XLA:TPU would refuse on the machine with the chip (a tile
+that does not align, a program that does not fit 16 GB of HBM) is refused
+here at no chip time.  Nothing runs, so these tests say nothing about
+results or times.
+
+Rules this file keeps (they are why it is ONE file): the topology is
+described inside a module-scoped fixture and never at import time, in a
+``skipif`` or in ``parametrize`` arguments (only the worker that is handed
+this file loads libtpu, and it keeps the lock until it exits); no child
+process; the persistent compile cache is off around the compiles (a
+described-chip entry cannot be read back without a chip).  Code that asks
+``jax.default_backend()`` sees the CPU here, so the jitted builders are
+lowered directly over ``ShapeDtypeStruct``s.
+
+Only the fast compiles live here (each a second or two).  The 1e8-row
+lexsort (about 70 s) and the ewm blocked scan (minutes) were compiled once
+by hand for PR 22; their numbers are in PERF.md.
+"""
+
+import os
+
+import pytest
+
+ROWS = 100_000_000
+N_COLS = 5
+NUM_SEGMENTS = 101  # 100 groups + the overflow bucket
+P_OUT = 100  # pad_len(100) on a one-shard mesh
+HBM_BYTES = 16 * 1024**3  # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as err:  # noqa: BLE001 - any failure means "cannot describe"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {err}")
+
+
+@pytest.fixture(scope="module")
+def shapes(topo):
+    """``shape(dims, dtype)`` -> ShapeDtypeStruct on the first described chip,
+    with x64 on and the persistent compile cache off for the module."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    jax.config.update("jax_enable_x64", True)
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    yield shape
+    jax.config.update("jax_enable_compilation_cache", cache_was_on)
+    compilation_cache.reset_cache()
+
+
+def _lower(kernel: str, arg, shape):
+    """Lower one jitted builder of the main path over 1e8-row shapes."""
+    import numpy as np
+
+    from modin_tpu.ops import groupby
+    from modin_tpu.ops.pallas.groupby_kernels import _jit_bincount_wrapper
+
+    codes = shape((ROWS,), np.int64)
+    if kernel == "bincount":  # arg: number of groups
+        return _jit_bincount_wrapper(ROWS, arg, False).lower(codes)
+    chunk = groupby._SCAN_CHUNK
+    fn = {  # arg: the aggregation
+        "masked_scan_smc": lambda: groupby._jit_masked_scan_smc(
+            arg, N_COLS, NUM_SEGMENTS, P_OUT, chunk, True, False
+        ),
+        "masked_scan_agg": lambda: groupby._jit_masked_scan_agg(
+            arg, N_COLS, NUM_SEGMENTS, 1, P_OUT, chunk
+        ),
+        "segment_agg": lambda: groupby._jit_segment_agg(
+            arg, N_COLS, NUM_SEGMENTS, 1, P_OUT, True, False
+        ),
+    }[kernel]()
+    cols = tuple(shape((ROWS,), np.int64) for _ in range(N_COLS))
+    return fn.lower(cols, codes)
+
+
+@pytest.mark.parametrize(
+    "kernel, arg",
+    [
+        ("bincount", 100),
+        ("bincount", 512),
+        ("masked_scan_smc", "sum"),
+        ("masked_scan_smc", "mean"),
+        ("masked_scan_agg", "min"),
+        ("segment_agg", "sum"),
+        ("segment_agg", "var"),
+    ],
+)
+def test_kernel_compiles_for_v5e_at_1e8_rows(kernel, arg, shapes):
+    lowered = _lower(kernel, arg, shapes)
+    compiled = lowered.compile()  # raises what the chip's compiler would raise
+    mem = compiled.memory_analysis()
+    resident = (
+        mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes
+    )
+    assert resident < HBM_BYTES, (kernel, arg, mem)
+    if kernel == "bincount":
+        # the Mosaic kernel is really in the program, not an XLA scatter
+        assert "tpu_custom_call" in lowered.as_text()
+    else:
+        # the 4.8 GB frame is the argument: a compile that dropped the
+        # int64 columns (or x64) would show here
+        assert mem.argument_size_in_bytes >= ROWS * 8 * (N_COLS + 1)
+
+
+def test_sharded_bincount_compiles_for_four_v5e_chips(topo, shapes):
+    """Mosaic kernels cannot be partitioned automatically: over a row-sharded
+    operand the bincount must sit in a ``shard_map`` (first four-chip run of
+    PR 22 was refused with exactly that), one kernel per chip + a psum."""
+    import jax
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from modin_tpu.ops.pallas.groupby_kernels import _bincount_fn
+
+    mesh = Mesh(np.asarray(topo.devices).reshape(4, 1), ("rows", "cols"))
+    codes = jax.ShapeDtypeStruct(
+        (ROWS,), np.int64, sharding=NamedSharding(mesh, PartitionSpec("rows"))
+    )
+    lowered = jax.jit(_bincount_fn(ROWS, 100, False, mesh)).lower(codes)
+    assert "tpu_custom_call" in lowered.as_text()
+    compiled = lowered.compile()
+    assert "all-reduce" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    # each chip holds a quarter of the codes, not the whole vector
+    assert mem.argument_size_in_bytes < ROWS * 8 // 4 + (1 << 20)

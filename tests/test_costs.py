@@ -319,6 +319,30 @@ class TestRoofline:
         assert peaks is not None
         assert peaks["flops_per_s"] > 0 and peaks["bytes_per_s"] > 0
 
+    @pytest.mark.parametrize(
+        "kind, source",
+        [("TPU v5 lite", "spec:TPU v5 lite"), ("TPU v9 imaginary", None)],
+    )
+    def test_tpu_kind_answers_from_the_table_or_raises(
+        self, monkeypatch, kind, source
+    ):
+        # on platform "tpu" a host micro-benchmark is no roofline: a known
+        # kind answers from KNOWN_PEAKS, an unknown one is an error
+        import jax
+
+        class _FakeTpu:
+            platform = "tpu"
+            device_kind = kind
+
+        monkeypatch.setattr(jax, "devices", lambda *a, **k: [_FakeTpu()])
+        monkeypatch.setattr(costs, "_peaks_cache", None)
+        if source is None:
+            with pytest.raises(LookupError, match="KNOWN_PEAKS"):
+                costs.substrate_peaks()
+        else:
+            assert costs.substrate_peaks()["source"] == source
+        monkeypatch.setattr(costs, "_peaks_cache", None)
+
     def test_fraction_bounds_and_unknowns(self):
         assert costs.roofline_fraction(1e6, 1e6, 0.0) is None
         assert costs.roofline_fraction(None, None, 1.0) is None

@@ -235,6 +235,36 @@ def test_pallas_bincount_matches_scatter(monkeypatch):
         np.testing.assert_array_equal(got, want)
 
 
+def test_pallas_bincount_row_sharded_operand():
+    """Over a row-sharded operand the kernel runs per shard under shard_map
+    and the partial histograms are psum-ed (a Mosaic kernel in a plain jit
+    cannot be partitioned on a real multi-chip mesh)."""
+    import jax
+
+    from modin_tpu.ops.pallas.groupby_kernels import (
+        _jit_bincount_wrapper,
+        pallas_bincount,
+    )
+    from modin_tpu.parallel.mesh import mesh_shape_key, num_row_shards, row_sharding
+
+    shards = num_row_shards()
+    if shards < 2:
+        pytest.skip("needs a multi-device mesh")
+    rng = np.random.default_rng(2)
+    for per_shard, width in [(1000, 3), (6250, 100), (1543, 512)]:
+        n = per_shard * shards
+        ids_np = rng.integers(0, width + 1, n)
+        ids = jax.device_put(ids_np, row_sharding())
+        got = np.asarray(pallas_bincount(ids, width, interpret=True))
+        np.testing.assert_array_equal(
+            got, np.bincount(ids_np, minlength=width + 1)[:width]
+        )
+        # the sharded program is its own cache entry, keyed by the mesh
+        before = _jit_bincount_wrapper.cache_info().hits
+        _jit_bincount_wrapper(n, width, True, mesh_shape_key())
+        assert _jit_bincount_wrapper.cache_info().hits == before + 1
+
+
 def test_groupby_agg_list_device(dfs):
     md, pdf = dfs
     got = assert_no_fallback(

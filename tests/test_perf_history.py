@@ -80,12 +80,14 @@ class TestSeeding:
     def test_backfill_provenance_and_substrates(self):
         ledger = ph.seed_ledger(REPO_ROOT)
         runs = {r["run"]: r for r in ledger["runs"]}
-        assert runs["r02"]["provenance"]["substrate"] == "tpu"
-        assert runs["r03"]["provenance"]["substrate"] == "tpu"
+        # r02/r03 (the rounds measured through the gone accelerator plug-in)
+        # left the seed with their round files in PR 22
+        assert sorted(runs) == ["r01", "r04", "r05"]
         assert runs["r01"]["provenance"]["substrate"] == "cpu"
-        assert "backfill" in runs["r03"]["provenance"]["git_sha"]
+        assert runs["r04"]["provenance"]["substrate"] == "cpu"
+        assert "backfill" in runs["r04"]["provenance"]["git_sha"]
         assert runs["r05"]["failed"] is True
-        assert runs["r03"]["ops"]["sum"]["speedup"] == 6.03
+        assert runs["r04"]["ops"]["count"]["speedup"] == 2.63
 
     def test_round_file_without_parse_records_failure(self, tmp_path):
         path = tmp_path / "BENCH_r99.json"

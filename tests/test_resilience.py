@@ -1,4 +1,4 @@
-"""Fault-tolerant device execution: taxonomy, retry, breaker, fault harness.
+"""Fault-tolerant device execution: classification, retry, breaker, fault harness.
 
 Acceptance bar (ISSUE 1): with injected DeviceOOM / DeviceLost / slow-kernel
 faults at the JaxWrapper seam, representative queries across >= 5 ``_try_*``
@@ -93,11 +93,11 @@ def _names(metrics):
 
 
 # ====================================================================== #
-# taxonomy
+# classification
 # ====================================================================== #
 
 
-class TestTaxonomy:
+class TestClassification:
     def test_oom(self):
         err = make_device_error("oom")
         assert isinstance(classify_device_error(err), DeviceOOM)

@@ -97,7 +97,10 @@ def _watch_threads():
     return [
         t.name
         for t in threading.enumerate()
-        if t.name.startswith("modin-tpu-watch")
+        # the dash matters: "modin-tpu-watchdog-*" are the resilience
+        # watchdog's workers, which another test file may leave behind on
+        # this xdist worker
+        if t.name.startswith("modin-tpu-watch-")
     ]
 
 
