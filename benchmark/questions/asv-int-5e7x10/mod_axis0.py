@@ -1,0 +1,12 @@
+"""asv TimeArithmetic, axis 0: ``df.mod(2, axis=0)``."""
+
+ROW_LOCAL = True
+
+
+def run(pd, df):
+    return df.mod(2, axis=0)
+
+
+def least_bytes(config):
+    """Every column read once and written once."""
+    return 2 * 8 * config["columns"] * config["rows"]
