@@ -33,7 +33,8 @@ from pandas.api.types import is_object_dtype as _is_object_dtype
 
 from modin_tpu.core.dataframe.base.dataframe import BaseDataframe
 from modin_tpu.core.dataframe.tpu.metadata import LazyIndex, ensure_index
-from modin_tpu.logging import ClassLogger
+from modin_tpu.logging import ClassLogger, disable_logging
+from modin_tpu.observability import meters as _meters
 
 _DEVICE_NUMPY_KINDS = "biuf"  # bool, int, uint, float
 
@@ -358,6 +359,8 @@ class DeviceColumn:
                 sh = by_start[st]
                 if st == start:
                     arrays.append(jax.device_put(sl, sh.device))
+                    if _meters.ACCOUNTING_ON:
+                        _meters.note_h2d(int(sl.nbytes))
                 else:
                     # touching a dead device's buffer raises here, which is
                     # exactly the signal to fall back to the full re-seat
@@ -522,6 +525,7 @@ class TpuDataframe(BaseDataframe, ClassLogger, modin_layer="CORE-FRAME"):
     (core/dataframe/base/dataframe.py BaseDataframe; reference
     modin/core/dataframe/base/dataframe/dataframe.py:26)."""
 
+    @disable_logging  # field assignments: one to three a request
     def __init__(
         self,
         columns: List[Column],
@@ -622,6 +626,7 @@ class TpuDataframe(BaseDataframe, ClassLogger, modin_layer="CORE-FRAME"):
             [col.pandas_dtype for col in self._columns], index=self._col_labels
         )
 
+    @disable_logging  # trivial accessors: no span, no wrapper frames
     def __len__(self) -> int:
         if self._columns:
             return self._columns[0].length
@@ -913,5 +918,6 @@ class TpuDataframe(BaseDataframe, ClassLogger, modin_layer="CORE-FRAME"):
     def get_column(self, position: int) -> Column:
         return self._columns[position]
 
+    @disable_logging
     def column_position(self, label: Any) -> List[int]:
         return list(self._col_labels.get_indexer_for([label]))

@@ -35,6 +35,7 @@ from modin_tpu.core.dataframe.tpu.dataframe import (
 )
 from modin_tpu.core.dataframe.tpu.metadata import LazyIndex
 from modin_tpu.core.execution.resilience import device_path
+from modin_tpu.logging import disable_logging
 from modin_tpu.core.storage_formats.base.query_compiler import (
     BaseQueryCompiler,
     QCCoercionCost,
@@ -46,6 +47,7 @@ _SHUFFLE_APPLY_MIN_ROWS = 1 << 19
 
 
 from modin_tpu.parallel.engine import materialize as _engine_materialize
+from modin_tpu.parallel.engine import upload as _engine_upload
 from modin_tpu.plan import explain as graftplan_explain
 from modin_tpu.plan import runtime as graftplan
 from modin_tpu import streaming as graftstream
@@ -79,6 +81,7 @@ class TpuQueryCompiler(BaseQueryCompiler):
     storage_format = property(lambda self: "Tpu")
     engine = property(lambda self: "Jax")
 
+    @disable_logging  # field assignments: one to three a request
     def __init__(self, frame: TpuDataframe, shape_hint: Optional[str] = None):
         assert isinstance(frame, TpuDataframe), type(frame)
         self._frame = frame
@@ -2531,8 +2534,8 @@ class TpuQueryCompiler(BaseQueryCompiler):
                         int(v) for v in clean_arr
                         if info.min <= int(v) <= info.max
                     ]
-                    return jnp.asarray(np.asarray(ints, dtype=dtype))
-                return jnp.asarray(clean_arr.astype(np.float64))
+                    return _engine_upload(np.asarray(ints, dtype=dtype))
+                return _engine_upload(clean_arr.astype(np.float64))
 
             frame.materialize_device()
             datas = []
@@ -2551,7 +2554,7 @@ class TpuQueryCompiler(BaseQueryCompiler):
                     code_vals = code_vals[~np.isnan(code_vals)]
                     op = "isin_vals_nan" if match_missing else "isin_vals"
                     datas.append(
-                        lazy_op(op, col.data, jnp.asarray(code_vals))
+                        lazy_op(op, col.data, _engine_upload(code_vals))
                     )
             return self._wrap_device_result(
                 datas, dtypes=[np.dtype(bool)] * len(datas)

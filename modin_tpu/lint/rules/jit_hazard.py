@@ -84,9 +84,14 @@ def _jit_static_params(
 
 
 def _is_jit_callable(node: ast.AST) -> bool:
-    """Is this expression ``jax.jit`` / ``jit`` (possibly under partial)?"""
+    """Is this expression ``jax.jit`` / ``jit`` (possibly under partial), or
+    ``named_jit`` (ops/_program.py: the same call under a program name)?"""
     parts = dotted_parts(node)
-    return parts is not None and parts[-1] == "jit" and (
+    if parts is None:
+        return False
+    if parts[-1] == "named_jit":
+        return True
+    return parts[-1] == "jit" and (
         len(parts) == 1 or parts[-2] in ("jax", "compat")
     )
 

@@ -47,9 +47,13 @@ def enable_logging(
         if isinstance(obj, type):
             seen: dict = {}
             for attr_name, attr_value in vars(obj).items():
+                # a classmethod/staticmethod object does not proxy attributes:
+                # the mark of @disable_logging sits on the function inside it
                 if isinstance(
                     attr_value, (FunctionType, MethodType, classmethod, staticmethod)
-                ) and not hasattr(attr_value, _MODIN_LOGGER_NOWRAP):
+                ) and not hasattr(
+                    getattr(attr_value, "__func__", attr_value), _MODIN_LOGGER_NOWRAP
+                ):
                     try:
                         wrapped = seen.setdefault(
                             attr_value,

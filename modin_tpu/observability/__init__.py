@@ -64,6 +64,7 @@ from modin_tpu.observability.meters import (  # noqa: F401
     meter_alloc_count,
     meters_enabled,
     query_stats,
+    recent_queries,
 )
 from modin_tpu.observability.meters import (  # noqa: F401
     reset as meters_reset,

@@ -20,7 +20,12 @@ the measurement layer on top of that stream:
   sorted-rep / plan-scan caches.  Scopes nest and are thread-isolated: a
   metric emitted on thread A never lands in thread B's open scope.
   ``explain(analyze=True)`` runs a deferred plan inside such a scope and
-  annotates every executed plan node with its measured share.
+  annotates every executed plan node with its measured share.  A live scope
+  also switches graftscope on (``spans.open_request``) and is the
+  per-request record of it: the host's self time by layer, the time blocked
+  waiting for the device, every device-program launch by name, every
+  blocking device->host fetch and every uploaded byte, each counted where it
+  happens.  Closed scopes stay in a bounded ring (:func:`recent_queries`).
 
 Disabled-mode contract (the default, ``MODIN_TPU_METERS=0`` and no active
 query-stats scope): ``emit_metric`` pays one module-attribute read
@@ -34,12 +39,15 @@ from __future__ import annotations
 
 import contextlib
 import fnmatch
+import itertools
 import sys
 import threading
 import time
+from collections import deque
 from typing import Any, Dict, Iterator, List, Optional, Tuple, Union
 
 from modin_tpu.concurrency import named_lock
+from modin_tpu.observability import spans as _spans
 
 #: Module-level fast path, graftscope-style: True while the aggregation
 #: registry (``MODIN_TPU_METERS``) or at least one ``query_stats()`` scope
@@ -94,7 +102,10 @@ VALID_KINDS = ("counter", "gauge", "histogram")
 
 _alloc_count = 0  # meter objects ever constructed (the zero-alloc assertion)
 
-_qs_tls = threading.local()  # .stack: active QueryStats; .dispatches: count
+#: .dispatches: this thread's monotonic dispatch count.  The thread's stack
+#: of open QueryStats is graftscope's request stack (``spans.thread_requests``):
+#: one list serves the metric stream and the spans
+_qs_tls = threading.local()
 
 _scope_lock = named_lock("meters.scopes")
 _active_scopes = 0
@@ -103,6 +114,22 @@ _active_scopes = 0
 #: open order).  Maintained under _scope_lock by query_stats enter/exit;
 #: graftwatch's /debug/queries endpoint renders this live.
 _live_scopes: Dict[int, "QueryStats"] = {}
+
+#: the last closed scopes, oldest first (:func:`recent_queries`).  Sized for
+#: a benchmark window of single-client requests several times over (486 asv
+#: requests fill a 51 s window today)
+RECENT_QUERIES_MAX = 4096
+_recent_scopes: "deque[QueryStats]" = deque(maxlen=RECENT_QUERIES_MAX)
+
+_request_ids = itertools.count(1)
+
+#: engine-seam attempt spans whose whole self time is the host blocked on the
+#: device (``wait_s``), not host work
+_WAIT_SPANS = frozenset({"engine.wait.attempt", "engine.materialize.attempt"})
+
+#: ``host_self_s`` key for scope time under no span: the caller's own code
+#: between API calls (a benchmark's glue, its own ``block_until_ready``)
+CALLER = "CALLER"
 
 _env_enabled = False
 
@@ -433,7 +460,7 @@ def _dispatch_metric(name: str, value: Union[int, float]) -> None:
     try:
         if METERS_ON:
             _REGISTRY.record(name, value)
-        stack = getattr(_qs_tls, "stack", None)
+        stack = _spans.thread_requests()
         if stack:
             for qs in stack:
                 qs._on_metric(name, value)
@@ -465,6 +492,33 @@ def note_dispatch() -> None:
     emit_metric("engine.dispatch", 1)
 
 
+def note_launch(program: str) -> None:
+    """One device-program launch on this thread (``ops/_program.py``, which
+    checks ``ACCOUNTING_ON`` first): counted by name into every open scope,
+    and the first one stamps ``first_launch_s``."""
+    stack = _spans.thread_requests()
+    if stack:
+        now = time.perf_counter()
+        for qs in stack:
+            qs._on_launch(program, now)
+
+
+def note_host_sync(nbytes: int) -> None:
+    """One blocking device->host fetch of ``nbytes`` on this thread."""
+    stack = _spans.thread_requests()
+    if stack:
+        for qs in stack:
+            qs._on_host_sync(nbytes)
+
+
+def note_h2d(nbytes: int) -> None:
+    """``nbytes`` uploaded host->device on this thread."""
+    stack = _spans.thread_requests()
+    if stack:
+        for qs in stack:
+            qs._on_h2d(nbytes)
+
+
 def note_compile(duration_s: float) -> None:
     """One XLA backend compile observed by the monitoring listener."""
     from modin_tpu.logging.metrics import emit_metric
@@ -477,8 +531,6 @@ def _device_resident_bytes() -> int:
     """Device-ledger resident bytes, via the one shared sampling seam
     (``spans._ledger_bytes``: never imports core.memory, swallows ledger
     errors) so the no-import-recursion rule lives in a single place."""
-    from modin_tpu.observability import spans as _spans
-
     return _spans._ledger_bytes()[0]
 
 
@@ -506,7 +558,6 @@ class QueryStats:
         "recoveries",
         "cache_hits",
         "hbm_high_water",
-        "api_calls",
         "est_flops",
         "est_bytes",
         "padded_bytes",
@@ -522,6 +573,19 @@ class QueryStats:
         "view_hits",
         "view_folds",
         "view_invalidations",
+        "request_id",
+        "host_self_s",
+        "wait_s",
+        "first_launch_s",
+        "launches",
+        "launches_by_program",
+        "host_syncs",
+        "d2h_bytes",
+        "h2d_bytes",
+        "spans",
+        "_self_us",
+        "_root_us",
+        "_span_depth0",
         "_t0",
         "_lock",
         "_closed",
@@ -551,7 +615,6 @@ class QueryStats:
         self.recoveries = 0
         self.cache_hits = {"fused": 0, "sorted_rep": 0, "plan_scan": 0}
         self.hbm_high_water = 0
-        self.api_calls = 0
         # graftcost: estimated hardware cost + padding waste (0 until the
         # cost-capture seams observe work under this scope)
         self.est_flops = 0.0
@@ -584,6 +647,26 @@ class QueryStats:
         self.view_hits = 0
         self.view_folds = 0
         self.view_invalidations = 0
+        # graftscope, per request: the id every span of this scope carries
+        # (``request`` stat of its TraceAnnotation); host self time by layer
+        # tag plus CALLER (filled at close), seconds blocked on the device,
+        # scope open -> first device-program launch (None: none launched)
+        self.request_id = next(_request_ids)
+        self.host_self_s: Dict[str, float] = {}
+        self.wait_s = 0.0
+        self.first_launch_s: Optional[float] = None
+        # counted where they happen: named_jit launches (a groupby's
+        # kernels too, which never pass the engine seam's deploy), blocking
+        # device->host fetches with their bytes, and uploaded bytes
+        self.launches = 0
+        self.launches_by_program: Dict[str, int] = {}
+        self.host_syncs = 0
+        self.d2h_bytes = 0
+        self.h2d_bytes = 0
+        self.spans = 0
+        self._self_us: Dict[str, float] = {}
+        self._root_us = 0.0
+        self._span_depth0 = 0
         self._t0 = time.perf_counter()
 
     # -- stream routing -------------------------------------------------- #
@@ -667,8 +750,56 @@ class QueryStats:
             # sickness — counting it would cascade one tenant's failures
             # into the outer tenant's quarantine
             self.breaker_trips += int(value)
-        elif name.startswith("pandas-api."):
-            self.api_calls += 1
+
+    # -- graftscope / launch / transfer routing -------------------------- #
+
+    def _on_span(self, sp: Any, depth: int) -> None:
+        """A span finished under this scope (``spans.finish_span``):
+        ``depth`` open spans are left beneath it on its thread."""
+        self_us = sp.dur_us - sp.child_us
+        if self_us < 0.0:
+            self_us = 0.0
+        key = "wait" if sp.name in _WAIT_SPANS else sp.layer
+        with self._lock:
+            if self._closed:
+                return
+            self.spans += 1
+            self._self_us[key] = self._self_us.get(key, 0.0) + self_us
+            if depth == self._span_depth0:
+                self._root_us += sp.dur_us
+
+    def _on_launch(self, program: str, now: float) -> None:
+        with self._lock:
+            if self._closed:
+                return
+            if self.first_launch_s is None:
+                self.first_launch_s = now - self._t0
+            self.launches += 1
+            by = self.launches_by_program
+            by[program] = by.get(program, 0) + 1
+
+    def _on_host_sync(self, nbytes: int) -> None:
+        with self._lock:
+            if not self._closed:
+                self.host_syncs += 1
+                self.d2h_bytes += nbytes
+
+    def _on_h2d(self, nbytes: int) -> None:
+        with self._lock:
+            if not self._closed:
+                self.h2d_bytes += nbytes
+
+    def _close(self) -> None:
+        """Final wall and the host split; called once, by the owner."""
+        with self._lock:
+            self.wall_s = time.perf_counter() - self._t0
+            self._sample_hbm()
+            self._closed = True
+            self_us = self._self_us
+            self.wait_s = self_us.pop("wait", 0.0) / 1e6
+            split = {layer: us / 1e6 for layer, us in sorted(self_us.items())}
+            split[CALLER] = max(self.wall_s - self._root_us / 1e6, 0.0)
+            self.host_self_s = split
 
     def _sample_hbm(self) -> None:
         resident = _device_resident_bytes()
@@ -699,7 +830,6 @@ class QueryStats:
             "recoveries": self.recoveries,
             "cache_hits": dict(self.cache_hits),
             "hbm_high_water": self.hbm_high_water,
-            "api_calls": self.api_calls,
             "est_flops": self.est_flops,
             "est_bytes": self.est_bytes,
             "padded_bytes": self.padded_bytes,
@@ -715,6 +845,16 @@ class QueryStats:
             "view_hits": self.view_hits,
             "view_folds": self.view_folds,
             "view_invalidations": self.view_invalidations,
+            "request_id": self.request_id,
+            "host_self_s": dict(self.host_self_s),
+            "wait_s": self.wait_s,
+            "first_launch_s": self.first_launch_s,
+            "launches": self.launches,
+            "launches_by_program": dict(self.launches_by_program),
+            "host_syncs": self.host_syncs,
+            "d2h_bytes": self.d2h_bytes,
+            "h2d_bytes": self.h2d_bytes,
+            "spans": self.spans,
         }
 
     def summary(self) -> str:
@@ -729,6 +869,9 @@ class QueryStats:
             f"{self.spills} ({self.spill_bytes} bytes), restores: "
             f"{self.restores}, recoveries: {self.recoveries}",
             f"cache hits: {hits}",
+            f"launches: {self.launches}, host syncs: {self.host_syncs} "
+            f"({self.d2h_bytes} bytes down), {self.h2d_bytes} bytes up, "
+            f"waited {self.wait_s * 1e3:.3f} ms",
             self._cost_line(),
         ]
         if self.fused_dispatches:
@@ -792,6 +935,15 @@ def live_scopes() -> List["QueryStats"]:
         return list(_live_scopes.values())
 
 
+def recent_queries(label: Optional[str] = None) -> List[dict]:
+    """``as_dict()`` of the last closed scopes, oldest first (at most
+    ``RECENT_QUERIES_MAX``), of one ``label`` or of all: the closed-scope
+    complement of :func:`live_scopes`."""
+    with _scope_lock:
+        closed = list(_recent_scopes)
+    return [qs.as_dict() for qs in closed if label is None or qs.label == label]
+
+
 def snapshot_scopes() -> Optional[List["QueryStats"]]:
     """Copy of this thread's open QueryStats stack (outermost first), or None.
 
@@ -800,7 +952,7 @@ def snapshot_scopes() -> Optional[List["QueryStats"]]:
     with this so metrics they emit still roll into the owning query's
     scopes.
     """
-    stack = getattr(_qs_tls, "stack", None)
+    stack = _spans.thread_requests()
     return list(stack) if stack else None
 
 
@@ -821,7 +973,7 @@ def seed_thread_scopes(scopes: Optional[List["QueryStats"]]) -> None:
     outer scope on the original thread would have silently absorbed
     another query's metrics.
     """
-    _qs_tls.stack = list(scopes) if scopes else []
+    _spans.seed_requests(scopes)
 
 
 @contextlib.contextmanager
@@ -830,14 +982,15 @@ def query_stats(label: str = "query") -> Iterator[QueryStats]:
 
     Activates accounting for its duration even when ``MODIN_TPU_METERS`` is
     off (that is the point: ad-hoc EXPLAIN ANALYZE without a process
-    restart).  Scopes nest (inner metrics roll into every open scope on the
-    stack) and are thread-isolated.  The scope is seeded from the innermost
-    QUERY-COMPILER span open on this thread when tracing is active.
+    restart), and graftscope with it, exactly as ``profile()`` does: the
+    scope is the per-request record of its spans.  Scopes nest (inner
+    metrics and spans roll into every open scope on the stack) and are
+    thread-isolated.  The scope is seeded from the innermost QUERY-COMPILER
+    span open on this thread when tracing is active.  Once closed it joins
+    the ring :func:`recent_queries` reads.
     """
     global _active_scopes
     qs = QueryStats(label)
-    from modin_tpu.observability import spans as _spans
-
     if _spans.TRACE_ON:
         sig = _spans.attribution_signature()
         if sig != "<untraced>":
@@ -846,24 +999,17 @@ def query_stats(label: str = "query") -> Iterator[QueryStats]:
         _active_scopes += 1
         _live_scopes[id(qs)] = qs
         _refresh_enabled()
-    stack = getattr(_qs_tls, "stack", None)
-    if stack is None:
-        stack = _qs_tls.stack = []
-    stack.append(qs)
+    qs._span_depth0 = _spans.open_request(qs)
+    qs._t0 = time.perf_counter()  # the switches above are not the query's
     try:
         yield qs
     finally:
-        with qs._lock:
-            qs.wall_s = time.perf_counter() - qs._t0
-            qs._sample_hbm()
-            qs._closed = True
-        try:
-            stack.remove(qs)
-        except ValueError:
-            pass
+        qs._close()
+        _spans.close_request(qs)
         with _scope_lock:
             _active_scopes -= 1
             _live_scopes.pop(id(qs), None)
+            _recent_scopes.append(qs)
             _refresh_enabled()
 
 

@@ -19,7 +19,17 @@ an instrumented call pays is one module-attribute check of ``TRACE_ON`` —
 no span object is ever allocated (``span_alloc_count()`` lets tests assert
 exactly that), and ``span()`` returns a shared no-op context manager
 singleton.  ``TRACE_ON`` flips when the ``TraceEnabled`` config parameter
-changes (pubsub subscription) or while any ``profile()`` is active.
+changes (pubsub subscription), while any ``profile()`` is active, or while
+any ``query_stats()`` scope is open (the scope is the per-request record:
+see ``open_request``).
+
+The profiler's clock: while tracing is on every span is also a
+``jax.profiler.TraceAnnotation("mt/<LAYER>/<name>", request=<scope id>,
+**attrs)``, so a ``jax.profiler`` trace holds the spans in its host plane
+beside the device's ``XLA Ops`` line.  Times in an ``.xplane.pb`` are relative
+to the profiler session, so the in-process ``Span`` cannot be joined to a
+trace by its own clock: the annotation is the join, and its ``request`` stat
+is the id every span of one ``query_stats`` scope shares (0 outside any).
 
 Span names emitted with static (or f-string) names are declared in the
 ``SPANS`` registry below, cross-checked both ways by graftlint's
@@ -72,11 +82,6 @@ SPANS = (
         "breaker is open",
     ),
     (
-        "shuffle.sample_pivots",
-        "device key sample + host quantile pivot computation preceding a "
-        "range shuffle",
-    ),
-    (
         "shuffle.range_shuffle",
         "the all_to_all range-partition shuffle: bucketize/pack, collective, "
         "compaction; slack retries recorded in attributes",
@@ -102,11 +107,6 @@ SPANS = (
         "chosen side in attributes",
     ),
     (
-        "router.calibrate",
-        "the one-shot kernel-router micro-benchmark pass seeding the "
-        "cost model for this substrate (cached to CacheDir)",
-    ),
-    (
         "sortcache.build",
         "one batched sorted-representation build (the shared sort the "
         "rest of the sort-shaped family amortizes); column count in "
@@ -130,6 +130,13 @@ SPANS = (
         "one graftplan lowering pass: optimized plan nodes replayed "
         "through the eager dispatcher / query-compiler / engine seams "
         "(node count in attributes)",
+    ),
+    (
+        "lazy.linearize",
+        "the host half of one fused materialization (ops/lazy.py): the "
+        "pending expression forest flattened, fingerprinted and looked up "
+        "in (or traced into) the fused-program cache; root count in "
+        "attributes.  The dispatch that follows is an engine.deploy.attempt",
     ),
     (
         "opt.choose",
@@ -236,6 +243,9 @@ _live_lock = named_lock("spans.live")
 
 _env_enabled = False
 
+#: open ``query_stats`` scopes process-wide; tracing is on while any is
+_open_requests = 0
+
 
 class Span:
     """One timed, attributed interval on the query path."""
@@ -252,11 +262,17 @@ class Span:
         "thread_id",
         "thread_name",
         "status",
+        "request",
+        "child_us",
         "_counted",
+        "_annotation",
     )
 
     def __init__(self, name: str, layer: str, attrs: Optional[dict], parent_id: Optional[int]):
-        t = threading.current_thread()
+        ident = getattr(_tls, "ident", None)
+        if ident is None:  # looked up once a thread, not once a span
+            t = threading.current_thread()
+            ident = _tls.ident = (t.ident or 0, t.name)
         self.name = name
         self.layer = layer
         self.span_id = next(_span_ids)
@@ -265,10 +281,14 @@ class Span:
         self.wall_start_s = _EPOCH_WALL + self.start_us / 1e6
         self.dur_us = 0.0
         self.attrs = attrs if attrs is not None else {}
-        self.thread_id = t.ident or 0
-        self.thread_name = t.name
+        self.thread_id, self.thread_name = ident
         self.status = "open"
+        # the innermost open query_stats scope on this thread (0: none)
+        requests = getattr(_tls, "requests", None)
+        self.request = requests[-1].request_id if requests else 0
+        self.child_us = 0.0  # finished children's durations (self time = dur - this)
         self._counted = False  # did this span increment _live_spans?
+        self._annotation = None  # the TraceAnnotation twin, while open
 
     def __repr__(self) -> str:  # debugging aid, not part of the export
         return (
@@ -286,7 +306,7 @@ class Span:
 def _refresh_enabled() -> None:
     """Recompute TRACE_ON (and size the ring) from config + collectors."""
     global TRACE_ON, _RING, _COUNTERS, _live_spans
-    on = _env_enabled or bool(_collectors)
+    on = _env_enabled or bool(_collectors) or _open_requests > 0
     if on:
         from modin_tpu.config import TraceFlightRecorderSize
 
@@ -338,6 +358,67 @@ def span_alloc_count() -> int:
     return _alloc_count
 
 
+def _TraceAnnotation(name: str, **stats: Any) -> Any:
+    """``jax.profiler.TraceAnnotation``, imported at the first span (this
+    module loads before jax does) and bound in place of this function."""
+    global _TraceAnnotation
+    from jax.profiler import TraceAnnotation
+
+    _TraceAnnotation = TraceAnnotation
+    return TraceAnnotation(name, **stats)
+
+
+# ---------------------------------------------------------------------- #
+# requests: the query_stats scopes spans report to
+# ---------------------------------------------------------------------- #
+
+
+def open_request(scope: Any) -> int:
+    """A ``query_stats`` scope opened on this thread: tracing is on while it
+    lives, every span started under it carries ``scope.request_id``, and every
+    span finished under it is handed to ``scope._on_span(span, depth)``
+    (``depth``: open spans left on the thread's stack).  Returns the stack
+    depth at the opening, which marks the scope's root spans."""
+    global _open_requests
+    requests = getattr(_tls, "requests", None)
+    if requests is None:
+        requests = _tls.requests = []
+    requests.append(scope)
+    with _state_lock:
+        _open_requests += 1
+        first = _open_requests == 1
+    if first:
+        _refresh_enabled()
+    stack = getattr(_tls, "stack", None)
+    return len(stack) if stack else 0
+
+
+def close_request(scope: Any) -> None:
+    global _open_requests
+    requests = getattr(_tls, "requests", None)
+    if requests:
+        try:
+            requests.remove(scope)
+        except ValueError:
+            pass
+    with _state_lock:
+        _open_requests = max(_open_requests - 1, 0)
+        last = _open_requests == 0
+    if last:
+        _refresh_enabled()
+
+
+def thread_requests() -> Optional[list]:
+    """This thread's open scopes, outermost first (the live list), or None."""
+    return getattr(_tls, "requests", None)
+
+
+def seed_requests(scopes: Optional[list]) -> None:
+    """Adopt another thread's open scopes (the resilience watchdog's worker:
+    spans it finishes report to the query that spawned the work)."""
+    _tls.requests = list(scopes) if scopes else []
+
+
 # ---------------------------------------------------------------------- #
 # the span stack
 # ---------------------------------------------------------------------- #
@@ -377,15 +458,15 @@ def seed_thread(stack: Optional[list]) -> None:
 def attribution_signature() -> str:
     """The op signature compile time should be billed to.
 
-    Innermost QUERY-COMPILER span if one is open on this thread (the
-    per-operator granularity the compile ledger wants), else the innermost
-    span of any layer, else ``<untraced>``.
+    Innermost QUERY-COMPILER or PLAN span if one is open on this thread
+    (the per-operator granularity the compile ledger wants), else the
+    innermost span of any layer, else ``<untraced>``.
     """
     stack = getattr(_tls, "stack", None)
     if not stack:
         return "<untraced>"
     for sp in reversed(stack):
-        if sp.layer == "QUERY-COMPILER":
+        if sp.layer in ("QUERY-COMPILER", "PLAN"):
             return sp.name
     return stack[-1].name
 
@@ -419,12 +500,22 @@ def start_span(
         with _live_lock:
             _live_spans += 1
     stack.append(sp)
+    # the twin on the profiler's clock (a no-op object unless a jax.profiler
+    # session is recording); entered last so it nests inside its parent's.
+    # Attributes ride as event stats unless one would shadow an argument
+    stats = attrs if attrs and not ("name" in attrs or "request" in attrs) else {}
+    annotation = _TraceAnnotation(f"mt/{layer}/{name}", request=sp.request, **stats)
+    annotation.__enter__()
+    sp._annotation = annotation
     return sp
 
 
 def finish_span(sp: Span, status: str = "ok") -> None:
     """Close a span, pop it, and deliver it to collectors + the ring."""
     global _live_spans
+    if sp._annotation is not None:
+        sp._annotation.__exit__(None, None, None)
+        sp._annotation = None
     sp.dur_us = (time.perf_counter() - _EPOCH_PERF) * 1e6 - sp.start_us
     sp.status = status
     # only spans that incremented may decrement: a span opened before the
@@ -441,6 +532,13 @@ def finish_span(sp: Span, status: str = "ok") -> None:
                 stack.remove(sp)
             except ValueError:
                 pass
+        if stack and stack[-1].span_id == sp.parent_id:
+            stack[-1].child_us += sp.dur_us
+    requests = getattr(_tls, "requests", None)
+    if requests:
+        depth = len(stack) if stack else 0
+        for request in requests:
+            request._on_span(sp, depth)
     _deliver(sp)
 
 
@@ -448,7 +546,9 @@ def _deliver(sp: Span) -> None:
     ring = _RING
     if ring is not None:
         ring.append(sp)
-    counters = _COUNTERS
+    # counter tracks feed the chrome-trace export: sampled for the config
+    # switch and for profile() blocks, not for query_stats scopes alone
+    counters = _COUNTERS if (_env_enabled or _collectors) else None
     if counters is not None:
         counters.append(
             (
@@ -626,16 +726,16 @@ class Profile:
     # -- rollups -------------------------------------------------------- #
 
     def rollup(self) -> dict:
-        """Host / device / compile wall-clock attribution.
+        """Host / engine-seam / compile wall-clock attribution.  All of it is
+        the host's clock: device time is read from a ``jax.profiler`` trace,
+        where these spans appear as ``mt/<LAYER>/<name>`` annotations.
 
         - ``wall_s``: summed duration of root spans (no collected parent);
-        - ``engine_s``: time inside engine-seam attempts (device dispatch,
+        - ``engine_s``: host wall inside engine-seam attempts (dispatch,
           transfers, blocking fetches — includes any XLA compiles that
           happened there);
         - ``compile_s``: XLA compile wall time attributed to collected spans
           by the compile ledger's monitoring listener;
-        - ``device_s``: ``engine_s`` minus the compile time spent inside the
-          engine attempts (pure device/runtime time);
         - ``host_s``: everything else (``wall_s - engine_s``), the
           framework + pandas-fallback share;
         - ``by_layer_self_s``: per-layer *self* time (each span's duration
@@ -643,10 +743,6 @@ class Profile:
         """
         spans = self.spans
         by_id = {sp.span_id: sp for sp in spans}
-        child_us: Dict[int, float] = {}
-        for sp in spans:
-            if sp.parent_id in by_id:
-                child_us[sp.parent_id] = child_us.get(sp.parent_id, 0.0) + sp.dur_us
         wall_us = sum(sp.dur_us for sp in spans if sp.parent_id not in by_id)
         engine_attempts = [
             sp
@@ -655,17 +751,13 @@ class Profile:
         ]
         engine_us = sum(sp.dur_us for sp in engine_attempts)
         compile_s = sum(sp.attrs.get("compile_s", 0.0) for sp in spans)
-        engine_compile_s = sum(
-            sp.attrs.get("compile_s", 0.0) for sp in engine_attempts
-        )
         by_layer: Dict[str, float] = {}
         for sp in spans:
-            self_us = max(sp.dur_us - child_us.get(sp.span_id, 0.0), 0.0)
+            self_us = max(sp.dur_us - sp.child_us, 0.0)
             by_layer[sp.layer] = by_layer.get(sp.layer, 0.0) + self_us
         return {
             "wall_s": wall_us / 1e6,
             "engine_s": engine_us / 1e6,
-            "device_s": max(engine_us / 1e6 - engine_compile_s, 0.0),
             "compile_s": compile_s,
             "host_s": max((wall_us - engine_us) / 1e6, 0.0),
             "spans": len(spans),

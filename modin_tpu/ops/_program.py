@@ -1,0 +1,66 @@
+"""Named device programs: the one place ``jax.jit`` is called.
+
+Every jitted closure under ``ops/``, ``parallel/`` and the fused-plan
+program of ``ops/lazy.py`` is built through :func:`named_jit`, which
+
+- gives the program the name of its builder (``groupby_range_codes``,
+  ``sort_lexsort``, ``plan_mod``), so a profiler trace reads
+  ``jit_groupby_range_codes/fusion.1`` instead of ``jit_fn/fusion.1``; and
+- counts each launch into the open ``query_stats`` scopes where it happens
+  (``launches`` / ``launches_by_program`` / ``first_launch_s``), so a device
+  groupby that calls its kernels directly — never through
+  ``JaxWrapper.deploy`` — is counted too.
+
+With no scope open a launch costs one extra frame and one attribute check.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Callable
+
+from modin_tpu.observability import meters as _meters
+
+#: the trace reducer of ``benchmark/`` keeps programs named ``jit_bench_*``
+#: out of the program's busy time: they are the harness's own
+_RESERVED_PREFIX = "bench_"
+_NAME_MAX = 48
+
+
+class NamedProgram:
+    """A jitted callable under a stable name.
+
+    Calls go to the jitted function; every other attribute (``lower``,
+    ``trace``, ``_cache_size``, ...) is the jitted function's own."""
+
+    __slots__ = ("name", "_jitted", "__weakref__")
+
+    def __init__(self, name: str, jitted: Any) -> None:
+        self.name = name
+        self._jitted = jitted
+
+    def __call__(self, *args: Any, **kwargs: Any) -> Any:
+        if _meters.ACCOUNTING_ON:
+            _meters.note_launch(self.name)
+        return self._jitted(*args, **kwargs)
+
+    def __getattr__(self, attr: str) -> Any:
+        return getattr(self._jitted, attr)
+
+    def __repr__(self) -> str:
+        return f"<NamedProgram jit_{self.name}>"
+
+
+def named_jit(fn: Callable, name: str, **jit_kwargs: Any) -> NamedProgram:
+    """``jax.jit(fn, **jit_kwargs)`` compiled and traced as ``jit_<name>``."""
+    import jax
+
+    name = name[:_NAME_MAX]
+    if name.startswith(_RESERVED_PREFIX):
+        raise ValueError(f"program name {name!r}: {_RESERVED_PREFIX}* is the benchmark harness's")
+    # a wrapper of our own carries the name: the caller's function keeps its
+    # own (it may be a library's, or shard_map's, with no settable name)
+    def program(*args: Any, **kwargs: Any) -> Any:
+        return fn(*args, **kwargs)
+
+    program.__name__ = program.__qualname__ = name
+    return NamedProgram(name, jax.jit(program, **jit_kwargs))

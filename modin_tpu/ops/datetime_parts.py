@@ -59,6 +59,7 @@ TIMEDELTA_COMPONENT_NAMES = (
 
 
 from modin_tpu.parallel.engine import materialize as _engine_materialize
+from modin_tpu.ops._program import named_jit
 
 
 @functools.lru_cache(maxsize=None)
@@ -95,7 +96,7 @@ def _jit_td_component(name: str, unit: str, n: int, want_float: bool = False):
         dtype = jnp.int64 if name == "days" else jnp.int32
         return jnp.where(valid, out, 0).astype(dtype), has_nat
 
-    return jax.jit(fn)
+    return named_jit(fn, "datetime_td_component")
 
 
 def td_component(name: str, ticks: Any, unit: str, n: int) -> Tuple[Any, Any]:
@@ -205,7 +206,7 @@ def _jit_component(name: str, unit: str, n: int, want_float: bool = False):
             return jnp.where(valid, out.astype(jnp.float64), jnp.nan), has_nat
         return jnp.where(valid, out, 0).astype(jnp.int32), has_nat
 
-    return jax.jit(fn)
+    return named_jit(fn, "datetime_component")
 
 
 def dt_component(name: str, ticks: Any, unit: str, n: int) -> Tuple[Any, Any]:

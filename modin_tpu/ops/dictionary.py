@@ -159,7 +159,9 @@ def remap_codes_device(codes: Any, table: np.ndarray) -> Any:
     translation array (device_put once)."""
     import jax.numpy as jnp
 
-    t = jnp.asarray(table, dtype=jnp.float64)
+    from modin_tpu.parallel.engine import upload
+
+    t = upload(table, jnp.float64)
     safe = jnp.where(jnp.isnan(codes), 0.0, codes).astype(jnp.int32)
     gathered = jnp.take(t, safe, mode="clip")
     return jnp.where(jnp.isnan(codes), jnp.nan, gathered)

@@ -21,6 +21,7 @@ import functools
 from typing import Any, Callable, List, Optional, Tuple
 
 import numpy as np
+from modin_tpu.ops._program import named_jit
 
 
 def _trim(x, p_out):
@@ -277,7 +278,7 @@ def _jit_shift(n_cols: int, n: int, periods: int, as_diff: bool):
     def fn(cols: Tuple) -> Tuple:
         return tuple(one(c) for c in cols)
 
-    return jax.jit(fn)
+    return named_jit(fn, "elementwise_shift")
 
 
 def shift_columns(cols: List[Any], n: int, periods: int) -> List[Any]:

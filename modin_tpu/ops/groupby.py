@@ -41,6 +41,8 @@ _RANGE_LIMIT = 1 << 22  # max direct-range width before falling back to unique
 
 
 from modin_tpu.parallel.engine import materialize as _engine_materialize
+from modin_tpu.parallel.engine import upload as _engine_upload
+from modin_tpu.ops._program import named_jit
 
 
 class _TooManyGroups(Exception):
@@ -68,7 +70,7 @@ def _jit_key_minmax(n: int):
         kmax = jnp.max(jnp.where(valid, k, np.iinfo(np.int64).min))
         return kmin, kmax
 
-    return jax.jit(fn)
+    return named_jit(fn, "groupby_key_minmax")
 
 
 @functools.lru_cache(maxsize=None)
@@ -81,7 +83,7 @@ def _jit_range_ids(n: int, width: int):
         valid = jnp.arange(k.shape[0]) < n
         return jnp.where(valid, jnp.clip(k - kmin, 0, width), width)
 
-    return jax.jit(fn)
+    return named_jit(fn, "groupby_range_ids")
 
 
 @functools.lru_cache(maxsize=None)
@@ -92,7 +94,7 @@ def _jit_scatter_counts(width: int):
     def fn(ids):
         return jnp.zeros(width + 1, jnp.int64).at[ids].add(1)[:width]
 
-    return jax.jit(fn)
+    return named_jit(fn, "groupby_scatter_counts")
 
 
 def _count_ids(ids, width: int):
@@ -122,7 +124,7 @@ def _jit_range_codes(n: int, n_groups: int):
         safe = jnp.where(valid, jnp.clip(k - kmin, 0, width - 1), 0)
         return jnp.where(valid, jnp.take(remap, safe), n_groups)
 
-    return jax.jit(fn)
+    return named_jit(fn, "groupby_range_codes")
 
 
 @functools.lru_cache(maxsize=None)
@@ -135,7 +137,7 @@ def _jit_float_prep(n: int):
         has_nan = jnp.any(jnp.isnan(k) & valid)
         return jnp.where(valid, k, jnp.nan), has_nan
 
-    return jax.jit(fn)
+    return named_jit(fn, "groupby_float_prep")
 
 
 @functools.lru_cache(maxsize=None)
@@ -147,7 +149,7 @@ def _jit_int_prep(n: int):
         valid = jnp.arange(k.shape[0]) < n
         return jnp.where(valid, k, k[0])
 
-    return jax.jit(fn)
+    return named_jit(fn, "groupby_int_prep")
 
 
 @functools.lru_cache(maxsize=None)
@@ -159,7 +161,7 @@ def _jit_mask_codes(n: int, overflow: int):
         valid = jnp.arange(codes.shape[0]) < n
         return jnp.where(valid, codes, overflow)
 
-    return jax.jit(fn)
+    return named_jit(fn, "groupby_mask_codes")
 
 
 # Bounded memo of key factorizations.  Grouping by the same key columns
@@ -224,7 +226,7 @@ def factorize_keys(
                 remap = np.full(width, len(present), dtype=np.int64)
                 remap[present] = np.arange(len(present))
                 codes = _jit_range_codes(n, len(present))(
-                    k64, jnp.int64(kmin), jnp.asarray(remap)
+                    k64, jnp.int64(kmin), _engine_upload(remap)
                 )
                 uniques = (present + kmin).astype(np.int64)
                 if kdt == jnp.bool_:
@@ -276,9 +278,7 @@ def factorize_keys(
     present = np.nonzero(counts)[0]
     remap = np.full(total + 1, len(present), dtype=np.int64)
     remap[present] = np.arange(len(present))
-    import jax.numpy as jnp2
-
-    codes = _jit_remap(len(present))(composite, jnp2.asarray(remap))
+    codes = _jit_remap(len(present))(composite, _engine_upload(remap))
     keys_out: List[np.ndarray] = []
     rem = present.copy()
     for uniques_i, n_i in zip(reversed(level_uniques), reversed(n_groups_each)):
@@ -297,7 +297,7 @@ def _jit_clamp_codes(n: int, n_valid: int):
         valid = jnp.arange(codes.shape[0]) < n
         return jnp.where(valid, jnp.minimum(codes, n_valid), n_valid)
 
-    return jax.jit(fn)
+    return named_jit(fn, "groupby_clamp_codes")
 
 
 @functools.lru_cache(maxsize=None)
@@ -312,7 +312,7 @@ def _jit_nan_group_codes(n: int, n_valid: int):
         out = jnp.where(is_nan, n_valid, clamped)
         return jnp.where(valid, out, n_valid + 1)
 
-    return jax.jit(fn)
+    return named_jit(fn, "groupby_nan_group_codes")
 
 
 @functools.lru_cache(maxsize=None)
@@ -331,7 +331,7 @@ def _jit_composite(n_groups_each: Tuple[int, ...], n: int, total: int):
             composite = composite * n_i + jnp.minimum(codes_i, n_i - 1)
         return jnp.where(in_range, composite, total)
 
-    return jax.jit(fn)
+    return named_jit(fn, "groupby_composite")
 
 
 @functools.lru_cache(maxsize=None)
@@ -342,7 +342,7 @@ def _jit_bincount(total: int):
     def fn(composite):
         return jnp.zeros(total + 1, jnp.int64).at[composite].add(1)[:total]
 
-    return jax.jit(fn)
+    return named_jit(fn, "groupby_bincount")
 
 
 @functools.lru_cache(maxsize=None)
@@ -353,7 +353,7 @@ def _jit_remap(n_present: int):
     def fn(composite, remap):
         return jnp.take(remap, composite)
 
-    return jax.jit(fn)
+    return named_jit(fn, "groupby_remap")
 
 
 @functools.lru_cache(maxsize=None)
@@ -472,21 +472,23 @@ def _jit_segment_agg(
             if has_sizes:
                 sizes = sizes_in
             else:
-                sizes = jax.ops.segment_sum(
-                    jnp.ones(codes.shape, jnp.int64), codes,
-                    num_segments=num_segments,
-                )
+                with jax.named_scope("group_sizes"):
+                    sizes = jax.ops.segment_sum(
+                        jnp.ones(codes.shape, jnp.int64), codes,
+                        num_segments=num_segments,
+                    )
         out = []
-        for c in cols:
-            if sizes is not None and jnp.issubdtype(c.dtype, jnp.floating):
-                out.append(seg_adaptive(c, codes, sizes))
-            elif sizes is not None and agg == "count":
-                out.append(finish(sizes))
-            else:
-                out.append(finish(seg(c, codes)))
+        for i, c in enumerate(cols):
+            with jax.named_scope(f"{agg}_col{i}"):
+                if sizes is not None and jnp.issubdtype(c.dtype, jnp.floating):
+                    out.append(seg_adaptive(c, codes, sizes))
+                elif sizes is not None and agg == "count":
+                    out.append(finish(sizes))
+                else:
+                    out.append(finish(seg(c, codes)))
         return tuple(out)
 
-    return jax.jit(fn)
+    return named_jit(fn, "groupby_segment_agg")
 
 
 @functools.lru_cache(maxsize=None)
@@ -499,7 +501,7 @@ def _jit_pad_to(p_out: int):
             return jnp.concatenate([r, jnp.zeros(p_out - r.shape[0], r.dtype)])
         return r
 
-    return jax.jit(fn)
+    return named_jit(fn, "groupby_pad_to")
 
 
 @functools.lru_cache(maxsize=None)
@@ -517,7 +519,7 @@ def _jit_segment_size(num_segments: int, p_out: int):
             r = jnp.concatenate([r, jnp.zeros(p_out - n_groups, r.dtype)])
         return r
 
-    return jax.jit(fn)
+    return named_jit(fn, "groupby_segment_size")
 
 
 # Above this many groups the masked-scan kernel's O(n*G) work loses to the
@@ -641,7 +643,8 @@ def _jit_masked_scan_agg(agg: str, n_cols: int, num_segments: int, ddof: int, p_
                 init.append(jnp.zeros(G, bool))
             elif agg == "all":
                 init.append(jnp.ones(G, bool))
-        carry, _ = jax.lax.scan(body, tuple(init), (cpad, *xpads))
+        with jax.named_scope("masked_scan"):
+            carry, _ = jax.lax.scan(body, tuple(init), (cpad, *xpads))
 
         # finalize per column
         def finish(r):
@@ -669,7 +672,7 @@ def _jit_masked_scan_agg(agg: str, n_cols: int, num_segments: int, ddof: int, p_
                 out.append(finish(carry[ci])); ci += 1
         return tuple(out)
 
-    return jax.jit(fn)
+    return named_jit(fn, "groupby_masked_scan_agg")
 
 
 @functools.lru_cache(maxsize=None)
@@ -763,7 +766,8 @@ def _jit_masked_scan_smc(
                 init.append(jnp.zeros(G, jnp.int64))
         if need_sizes and not has_sizes:
             init.append(jnp.zeros(G, jnp.int64))
-        carry, _ = jax.lax.scan(body, tuple(init), (cpad, *xpads))
+        with jax.named_scope("masked_scan"):
+            carry, _ = jax.lax.scan(body, tuple(init), (cpad, *xpads))
 
         ci = 0
         sums, counts = [], []
@@ -793,7 +797,10 @@ def _jit_masked_scan_smc(
                     None,
                 )
 
-            out, _ = jax.lax.scan(cbody, jnp.zeros(G, jnp.int64), (cpad, xpad_c))
+            with jax.named_scope("count_scan"):
+                out, _ = jax.lax.scan(
+                    cbody, jnp.zeros(G, jnp.int64), (cpad, xpad_c)
+                )
             return out
 
         out = []
@@ -807,7 +814,8 @@ def _jit_masked_scan_smc(
             elif counts[i] is not None:
                 cnt = counts[i]
             else:
-                has_nan = jnp.any(jnp.isnan(c))
+                with jax.named_scope("nan_probe"):
+                    has_nan = jnp.any(jnp.isnan(c))
                 cnt = jax.lax.cond(
                     has_nan,
                     lambda i=i: count_scan(xpads[i]),
@@ -820,7 +828,7 @@ def _jit_masked_scan_smc(
                 out.append(finish(s / cnt.astype(s.dtype)))
         return tuple(out)
 
-    return jax.jit(fn)
+    return named_jit(fn, "groupby_masked_scan_smc")
 
 
 # read from inside jitted bodies (masked-scan min/max neutrals): immutable so
@@ -843,7 +851,7 @@ def _jit_first_position(num_segments: int):
         positions = jnp.arange(codes.shape[0], dtype=jnp.int64)
         return jax.ops.segment_min(positions, codes, num_segments=num_segments)
 
-    return jax.jit(fn)
+    return named_jit(fn, "groupby_first_position")
 
 
 def groupby_first_position(codes: Any, num_groups: int) -> Any:
@@ -899,7 +907,7 @@ def groupby_reduce(
         )
     if agg == "size":
         if sizes is not None:
-            return [jnp.asarray(pad_host(np.asarray(sizes, np.int64), num_groups))]
+            return [_engine_upload(pad_host(np.asarray(sizes, np.int64), num_groups))]
         from modin_tpu.ops.pallas.groupby_kernels import (
             bincount_supported,
             pallas_bincount,
@@ -932,7 +940,7 @@ def groupby_reduce(
                 scan_adaptive, scan_has_sizes,
             )
             if scan_has_sizes:
-                sizes_dev = jnp.asarray(
+                sizes_dev = _engine_upload(
                     np.append(np.asarray(sizes, np.int64), 1)
                 )
                 return list(fn(tuple(value_cols), codes, sizes_dev))
@@ -950,7 +958,7 @@ def groupby_reduce(
     if has_sizes:
         # operand layout matches the in-kernel histogram: ns slots with an
         # overflow bucket (its value is sliced off, 1 avoids a 0-divide)
-        sizes_dev = jnp.asarray(
+        sizes_dev = _engine_upload(
             np.append(np.asarray(sizes, np.int64), 1)
         )
         return list(fn(tuple(value_cols), codes, sizes_dev))
@@ -1033,7 +1041,7 @@ def _jit_group_quantile(
         starts = jnp.cumsum(total) - total
         return tuple(one(c, codes, starts) for c in cols)
 
-    return jax.jit(fn)
+    return named_jit(fn, "groupby_group_quantile")
 
 
 def groupby_quantile(
@@ -1089,7 +1097,7 @@ def _jit_group_nunique(n_cols: int, num_segments: int, p_out: int, dropna: bool)
     def fn(cols: Tuple, codes):
         return tuple(one(c, codes) for c in cols)
 
-    return jax.jit(fn)
+    return named_jit(fn, "groupby_group_nunique")
 
 
 def groupby_nunique(
@@ -1135,7 +1143,7 @@ def _jit_group_first_last(last: bool, n_cols: int, num_segments: int, p_out: int
     def fn(cols: Tuple, codes):
         return tuple(one(c, codes) for c in cols)
 
-    return jax.jit(fn)
+    return named_jit(fn, "groupby_group_first_last")
 
 
 def groupby_first_last(
@@ -1162,7 +1170,7 @@ def _jit_broadcast_groups(n_cols: int):
             out.append(jnp.take(a, safe))
         return tuple(out)
 
-    return jax.jit(fn)
+    return named_jit(fn, "groupby_broadcast_groups")
 
 
 def groupby_broadcast(agg_cols: List[Any], codes: Any) -> List[Any]:
@@ -1215,7 +1223,7 @@ def _jit_grouped_cum(op: str, n_cols: int):
         newgrp = jnp.concatenate([jnp.ones(1, bool), cs[1:] != cs[:-1]])
         return tuple(one(c, order, inv, newgrp) for c in cols)
 
-    return jax.jit(fn)
+    return named_jit(fn, "groupby_grouped_cum")
 
 
 def groupby_cumulative(op: str, value_cols: List[Any], codes: Any) -> List[Any]:

@@ -30,6 +30,7 @@ from modin_tpu.ops.structural import float_total_order as _total_order
 
 
 from modin_tpu.parallel.engine import materialize as _engine_materialize
+from modin_tpu.ops._program import named_jit
 
 
 @functools.lru_cache(maxsize=None)
@@ -67,7 +68,7 @@ def _jit_composite_codes(n_levels: int, float_flags: Tuple[bool, ...]):
                 lc, rc = rank_pair(lc * total + l_i, rc * total + r_i)
         return lc, rc
 
-    return jax.jit(fn)
+    return named_jit(fn, "join_composite_codes")
 
 
 def composite_key_codes(left_keys: list, right_keys: list) -> Tuple[Any, Any]:
@@ -117,7 +118,7 @@ def _jit_match_bounds(n_left: int, n_right: int):
         total_left = jnp.sum(jnp.where(l_valid, jnp.maximum(counts, 1), 0))
         return perm, lo, counts, total_inner, total_left
 
-    return jax.jit(fn)
+    return named_jit(fn, "join_match_bounds")
 
 
 @functools.lru_cache(maxsize=None)
@@ -147,7 +148,7 @@ def _jit_expand(p_out: int, n_left: int, how_left: bool):
             right_pos = jnp.where(has_match, right_pos, -1)
         return left_pos, right_pos
 
-    return jax.jit(fn)
+    return named_jit(fn, "join_expand")
 
 
 def sort_merge_positions(
@@ -238,7 +239,7 @@ def _jit_right_only(p_right: int, n_right: int, n_out: int):
         order = jnp.argsort(~unmatched, stable=True)
         return order, m
 
-    return jax.jit(fn)
+    return named_jit(fn, "join_right_only")
 
 
 def right_only_positions(right_pos, p_right: int, n_right: int, n_out: int):
@@ -271,7 +272,7 @@ def _jit_gather_with_null(n_cols: int):
             out.append(vals)
         return tuple(out)
 
-    return jax.jit(fn)
+    return named_jit(fn, "join_gather_with_null")
 
 
 def _null_sentinel(dtype):
@@ -329,7 +330,7 @@ def _jit_duplicated(n_cols: int, float_flags: Tuple[bool, ...], n: int, keep: An
             dup_sorted = ~(first & last)
         return jnp.zeros(P, bool).at[order].set(dup_sorted)
 
-    return jax.jit(fn)
+    return named_jit(fn, "join_duplicated")
 
 
 def duplicated_mask(cols: list, n: int, keep: Any):

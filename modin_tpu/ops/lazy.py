@@ -38,6 +38,8 @@ import numpy as np
 from modin_tpu.concurrency import named_lock
 from modin_tpu.logging.metrics import emit_metric
 from modin_tpu.observability import meters as graftmeter
+from modin_tpu.observability import spans as graftscope
+from modin_tpu.ops._program import named_jit
 from modin_tpu.serving import context as serving_context
 
 _MAX_NODES = 160
@@ -285,6 +287,83 @@ def _ensure_donation_warning_filter() -> None:
             _donation_filter_installed = True
 
 
+def _tail_name(tail_key: Optional[Tuple]) -> str:
+    """The leading strings of a tail's cache key: ``("reduce", "sum", n,
+    ...)`` -> ``reduce_sum``; ``tail`` when it leads with none."""
+    parts = []
+    for item in tail_key or ():
+        if not isinstance(item, str):
+            break
+        parts.append(item)
+    return "_".join(parts) or "tail"
+
+
+def _program_name(nodes: Sequence[Tuple], tail_key: Optional[Tuple]) -> str:
+    """``plan_<ops in first-seen order>[_<tail>]``: ``plan_mod``,
+    ``plan_add_reduce_sum``."""
+    parts = list(dict.fromkeys(op for op, _refs, _static in nodes))
+    if tail_key:
+        parts.append(_tail_name(tail_key))
+    return "plan_" + "_".join(parts)
+
+
+def _fused_program(
+    roots: Sequence[Any],
+    tail_key: Optional[Tuple],
+    tail_builder: Optional[Callable[[List[Any]], Any]],
+    donate: Optional[frozenset],
+):
+    """Linearize the forest and find (or build) its executable.
+
+    Returns ``(program, leaves, scalars, donate_positions)``."""
+    nodes, out_refs, leaves, scalars, fingerprint = _linearize(roots)
+    donate_positions: Tuple[int, ...] = ()
+    if donate:
+        donate_positions = tuple(
+            i for i, leaf in enumerate(leaves) if id(leaf) in donate
+        )
+    # the donated positions are part of the executable's identity: jit
+    # fixes donate_argnums at wrap time, so the same forest with and
+    # without donation is two programs
+    key = (fingerprint, tail_key, _cache_epoch_key(), donate_positions)
+    fn = _fused_cache_get(key)
+    if fn is None:
+        import jax
+
+        from modin_tpu.ops.elementwise import get_op
+
+        nodes_spec = tuple(nodes)
+
+        def execute(scalar_vals: Tuple, *leaf_vals):
+            vals: List[Any] = []
+
+            def res(ref):
+                kind, i = ref
+                if kind == "n":
+                    return vals[i]
+                if kind == "l":
+                    return leaf_vals[i]
+                return scalar_vals[i]
+
+            for op, refs, static in nodes_spec:
+                with jax.named_scope(op):
+                    vals.append(get_op(op)(*[res(r) for r in refs], **dict(static)))
+            outs = [res(r) for r in out_refs]
+            if tail_builder is None:
+                return tuple(outs)
+            with jax.named_scope(_tail_name(tail_key)):
+                return tail_builder(outs)
+
+        fn = named_jit(
+            execute,
+            _program_name(nodes, tail_key),
+            # +1: argument 0 is the scalar tuple (never donated)
+            donate_argnums=tuple(p + 1 for p in donate_positions),
+        )
+        _fused_cache_put(key, fn)
+    return fn, leaves, scalars, donate_positions
+
+
 def run_fused(
     roots: Sequence[Any],
     tail_key: Optional[Tuple] = None,
@@ -308,8 +387,6 @@ def run_fused(
     so later reads restore via lineage instead of touching the consumed
     buffer.
     """
-    import jax
-
     if serving_context.CONTEXT_ON:
         # graftgate deadline boundary: fused-chain materialization is where
         # a deferred query finally pays for its whole expression forest —
@@ -319,44 +396,10 @@ def run_fused(
     if tail_builder is None and not any(is_lazy(r) for r in roots):
         return [r._result if isinstance(r, LazyExpr) else r for r in roots]
 
-    nodes, out_refs, leaves, scalars, fingerprint = _linearize(roots)
-    donate_positions: Tuple[int, ...] = ()
-    if donate:
-        donate_positions = tuple(
-            i for i, leaf in enumerate(leaves) if id(leaf) in donate
+    with graftscope.span("lazy.linearize", layer="PLAN", roots=len(roots)):
+        fn, leaves, scalars, donate_positions = _fused_program(
+            roots, tail_key, tail_builder, donate
         )
-    # the donated positions are part of the executable's identity: jit
-    # fixes donate_argnums at wrap time, so the same forest with and
-    # without donation is two programs
-    key = (fingerprint, tail_key, _cache_epoch_key(), donate_positions)
-    fn = _fused_cache_get(key)
-    if fn is None:
-        from modin_tpu.ops.elementwise import get_op
-
-        nodes_spec = tuple(nodes)
-
-        def execute(scalar_vals: Tuple, *leaf_vals):
-            vals: List[Any] = []
-
-            def res(ref):
-                kind, i = ref
-                if kind == "n":
-                    return vals[i]
-                if kind == "l":
-                    return leaf_vals[i]
-                return scalar_vals[i]
-
-            for op, refs, static in nodes_spec:
-                vals.append(get_op(op)(*[res(r) for r in refs], **dict(static)))
-            outs = [res(r) for r in out_refs]
-            return tail_builder(outs) if tail_builder is not None else tuple(outs)
-
-        fn = jax.jit(
-            execute,
-            # +1: argument 0 is the scalar tuple (never donated)
-            donate_argnums=tuple(p + 1 for p in donate_positions),
-        )
-        _fused_cache_put(key, fn)
 
     # dispatch through the engine seam: the fused call gets the resilience
     # policy (classify/retry/recovery) and op-replay lineage provenance

@@ -19,6 +19,8 @@ from typing import Any
 
 import numpy as np
 
+from modin_tpu.ops._program import named_jit
+
 # block of codes processed per grid step: BR sublanes x 128 lanes
 _BR = 32
 _LANES = 128
@@ -65,6 +67,7 @@ def _build_bincount(n_blocks: int, g_padded: int, interpret: bool):
             (1, g_padded), lambda i: (zero, zero), **block_spec_kwargs
         ),
         interpret=interpret,
+        name="groupby_bincount_kernel",
     )
 
 
@@ -136,7 +139,9 @@ def _jit_bincount_wrapper(
         from modin_tpu.parallel.mesh import get_mesh
 
         mesh = get_mesh()
-    return jax.jit(_bincount_fn(p_len, num_groups, interpret, mesh))
+    return named_jit(
+        _bincount_fn(p_len, num_groups, interpret, mesh), "groupby_pallas_bincount"
+    )
 
 
 def _row_shards_of(codes: Any) -> int:

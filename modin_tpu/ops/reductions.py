@@ -21,6 +21,7 @@ import numpy as np
 
 
 from modin_tpu.parallel.engine import materialize as _engine_materialize
+from modin_tpu.ops._program import named_jit
 
 
 def _masked(c, n, neutral):
@@ -689,7 +690,7 @@ def _jit_idx_minmax(op_name: str, n_cols: int, n: int):
                 out.append(jnp.argmax(x))
         return tuple(out), tuple(counts)
 
-    return jax.jit(fn)
+    return named_jit(fn, "reduce_idx_minmax")
 
 
 def idx_minmax(op_name: str, cols: List[Any], n: int, skipna: bool = True):
@@ -764,7 +765,7 @@ def _jit_minmax(n_cols: int, n: int):
                 )
         return tuple(out)
 
-    return jax.jit(fn)
+    return named_jit(fn, "reduce_minmax")
 
 
 def plan_sort_reduce(op: str, specs: List[dict], n: int) -> List[ColumnPlan]:
@@ -887,7 +888,7 @@ def _jit_nunique_sorted(n_pairs: int, n: int, dropna: bool):
             out.append(count)
         return tuple(out)
 
-    return jax.jit(fn)
+    return named_jit(fn, "reduce_nunique_sorted")
 
 
 def _quantile_from_sorted(xs, n_valid, qs, interpolation: str):
@@ -934,7 +935,7 @@ def _jit_quantile_sorted(n_pairs: int, n_q: int, interpolation: str):
             for xs, n_valid in pairs
         )
 
-    return jax.jit(fn)
+    return named_jit(fn, "reduce_quantile_sorted")
 
 
 @functools.lru_cache(maxsize=None)
@@ -955,7 +956,7 @@ def _jit_median_sorted(n_pairs: int, n: int, skipna: bool):
             out.append(v)
         return tuple(out)
 
-    return jax.jit(fn)
+    return named_jit(fn, "reduce_median_sorted")
 
 
 @functools.lru_cache(maxsize=None)
@@ -987,7 +988,7 @@ def _jit_mode_sorted(n_pairs: int, k_bound: int):
             outs.append((vals, m))
         return tuple(outs)
 
-    return jax.jit(fn)
+    return named_jit(fn, "reduce_mode_sorted")
 
 
 @functools.lru_cache(maxsize=None)
@@ -1042,7 +1043,7 @@ def _jit_hist(n_cols: int, span_pad: int, n: int, want_mode: bool, dropna: bool)
             outs.append((mask, max_all, nan_modal))
         return tuple(outs)
 
-    return jax.jit(fn)
+    return named_jit(fn, "reduce_hist")
 
 
 def _hist_groups(plans: List[ColumnPlan]):
@@ -1207,7 +1208,7 @@ def _jit_nunique_axis1(n_cols: int, n: int, dropna: bool):
             distinct = distinct + (nv < k).astype(distinct.dtype)
         return distinct.astype(jnp.int64)
 
-    return jax.jit(fn)
+    return named_jit(fn, "reduce_nunique_axis1")
 
 
 def nunique_axis1(cols: List[Any], n: int, dropna: bool = True) -> Any:
@@ -1266,7 +1267,7 @@ def _jit_mode_axis1(n_cols: int, n: int):
         uniform = jnp.all(jnp.where(row_ok, m == m_max, True))
         return vals, vals_f, m_max, uniform
 
-    return jax.jit(fn)
+    return named_jit(fn, "reduce_mode_axis1")
 
 
 def mode_axis1(cols: List[Any], n: int) -> Tuple[Any, Any, int, bool]:

@@ -39,6 +39,7 @@ from modin_tpu.concurrency import named_lock
 from modin_tpu.logging.metrics import emit_metric
 from modin_tpu.observability import spans as graftscope
 from modin_tpu.ops import calibration as calstore
+from modin_tpu.ops._program import named_jit
 
 #: column strategies a sort-shaped plan may carry (see plan_strategies in
 #: ops/reductions.py): "dict" costs ~0 (host categories already known),
@@ -151,14 +152,16 @@ def _measure() -> Dict[str, float]:
     dev_wide = jnp.asarray(wide)
     dev_narrow_idx = jnp.asarray(narrow.astype(np.int32))
 
-    sort_fn = jax.jit(jnp.sort)
-    consume_fn = jax.jit(
+    sort_fn = named_jit(jnp.sort, "router_calibrate_sort")
+    consume_fn = named_jit(
         lambda xs: jnp.sum(
             jnp.concatenate([jnp.ones(1, bool), xs[1:] != xs[:-1]])
-        )
+        ),
+        "router_calibrate_consume",
     )
-    hist_fn = jax.jit(
-        lambda idx: jnp.zeros(1025, jnp.int64).at[idx].add(1)
+    hist_fn = named_jit(
+        lambda idx: jnp.zeros(1025, jnp.int64).at[idx].add(1),
+        "router_calibrate_hist",
     )
 
     sorted_dev = sort_fn(dev_wide)
@@ -240,14 +243,15 @@ def _measure_sharded(table: Dict[str, Any], rows: int, wide: Any) -> None:
             )
             return recv.reshape(-1)
 
-        fn = jax.jit(
+        fn = named_jit(
             shard_map(
                 local_roundtrip,
                 mesh=mesh,
                 in_specs=(P("rows"),),
                 out_specs=P("rows"),
                 check_vma=False,
-            )
+            ),
+            "router_calibrate_all_to_all",
         )
         data = JaxWrapper.put(np.zeros(S * S * cap, dtype=np.int64))
         wall = _time_best(lambda: np.asarray(fn(data)))
@@ -297,10 +301,7 @@ def get_calibration() -> Optional[Dict[str, float]]:
             _calibration_mesh = mesh_key
             return table
         try:
-            with graftscope.span(
-                "router.calibrate", layer="QUERY-COMPILER", platform=platform
-            ):
-                table = _measure()
+            table = _measure()
             emit_metric("router.calibrate", 1)
         except Exception:  # graftlint: disable=EXC-HYGIENE -- calibration is an optimization probe; ANY failure (no backend, OOM at micro size) must leave routing on the pre-router device default
             _calibration = False

@@ -20,6 +20,7 @@ import numpy as np
 
 
 from modin_tpu.parallel.engine import materialize as _engine_materialize
+from modin_tpu.ops._program import named_jit
 
 
 def _pad_sentinel(dtype, ascending: bool):
@@ -74,10 +75,11 @@ def _jit_lexsort(n_keys: int, n: int, n_asc: Tuple[bool, ...], na_last: bool):
         perm = jnp.arange(p, dtype=jnp.int64)
         # least-significant key first; stable sorts preserve prior order
         for i in range(n_keys - 1, -1, -1):
-            perm = order_one(masked[i], n_asc[i], perm)
+            with jax.named_scope(f"order_key{i}"):
+                perm = order_one(masked[i], n_asc[i], perm)
         return perm
 
-    return jax.jit(fn)
+    return named_jit(fn, "sort_lexsort")
 
 
 def lexsort_permutation(
@@ -133,7 +135,7 @@ def _jit_sorted_valid_multi(n_cols: int, n: int):
     def fn(cols: Tuple):
         return tuple(sorted_valid(c, n) for c in cols)
 
-    return jax.jit(fn)
+    return named_jit(fn, "sort_sorted_valid_multi")
 
 
 def sorted_valid_columns(arrays: List[Any], n: int) -> List[Tuple[Any, Any]]:
@@ -204,7 +206,7 @@ def _jit_top_k(n: int, k: int, largest: bool, is_float: bool, is_int64: bool, is
         _, nan_positions = lax.top_k(nan_key, k)
         return positions, nan_positions, n_valid
 
-    return jax.jit(fn)
+    return named_jit(fn, "sort_top_k")
 
 
 def top_k_positions(col, n: int, k: int, largest: bool):
@@ -304,7 +306,7 @@ def _jit_rank(n_cols: int, float_flags: Tuple[bool, ...], n: int, method: str,
     def fn(cols: Tuple):
         return tuple(one(c) for c in cols)
 
-    return jax.jit(fn)
+    return named_jit(fn, "sort_rank")
 
 
 def rank_columns(

@@ -36,6 +36,7 @@ from typing import Any, List, Optional, Tuple
 import numpy as np
 
 from modin_tpu.parallel.engine import materialize as _engine_materialize
+from modin_tpu.ops._program import named_jit
 
 
 @functools.lru_cache(maxsize=None)
@@ -65,7 +66,7 @@ def _jit_prep_sorted(n: int):
             n_valid = jnp.asarray(n, jnp.int64)
         return x, n_valid
 
-    return jax.jit(fn)
+    return named_jit(fn, "spmd_prep_sorted")
 
 
 @functools.lru_cache(maxsize=None)
@@ -86,7 +87,7 @@ def _jit_seal_tail(n: int):
             sentinel = _int_max(xs.dtype)
         return jnp.where(idx < n, xs, sentinel)
 
-    return jax.jit(fn)
+    return named_jit(fn, "spmd_seal_tail")
 
 
 def sharded_sorted_valid(c: Any, n: int) -> Optional[Tuple[Any, Any]]:
@@ -150,7 +151,7 @@ def _jit_total_codes():
     def fn(lk, rk):
         return enc(lk), enc(rk)
 
-    return jax.jit(fn)
+    return named_jit(fn, "spmd_total_codes")
 
 
 @functools.lru_cache(maxsize=None)
@@ -178,7 +179,7 @@ def _jit_match_presorted(n_left: int, n_right: int):
         total_left = jnp.sum(jnp.where(l_valid, jnp.maximum(counts, 1), 0))
         return lo, counts, total_inner, total_left
 
-    return jax.jit(fn)
+    return named_jit(fn, "spmd_match_presorted")
 
 
 def sharded_merge_positions(

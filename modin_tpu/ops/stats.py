@@ -25,6 +25,7 @@ import numpy as np
 
 
 from modin_tpu.parallel.engine import materialize as _engine_materialize
+from modin_tpu.ops._program import named_jit
 
 
 @functools.lru_cache(maxsize=None)
@@ -64,7 +65,7 @@ def _jit_corr_cov(
         out = jnp.where(N >= max(min_periods, 1), out, jnp.nan)
         return out, N
 
-    return jax.jit(fn)
+    return named_jit(fn, "stats_corr_cov")
 
 
 def corr_cov_matrix(

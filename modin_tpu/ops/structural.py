@@ -17,6 +17,7 @@ from typing import Any, Callable, List, Optional, Tuple
 import numpy as np
 
 from modin_tpu.observability import costs as _costs
+from modin_tpu.ops._program import named_jit
 
 #: graftfuse adaptive padding: while a quantizer is installed on this
 #: thread, ``pad_host`` rounds its padded length up through it, so a scan
@@ -97,7 +98,7 @@ def _jit_gather(n_cols: int):
     def fn(cols: Tuple, positions):
         return tuple(jnp.take(c, positions, axis=0) for c in cols)
 
-    return jax.jit(fn)
+    return named_jit(fn, "structural_gather")
 
 
 def compact_rows(cols: List[Any], mask: Any, n: int) -> Tuple[List[Any], Any, Any]:
@@ -185,7 +186,7 @@ def _jit_concat(n_parts: int, n_cols: int, lengths: Tuple[int, ...], p_out: int)
             out.append(jnp.take(big, pos, axis=0))
         return tuple(out)
 
-    return jax.jit(fn)
+    return named_jit(fn, "structural_concat")
 
 
 #: tail appends at least this many times smaller than the prefix take the
@@ -221,7 +222,7 @@ def _jit_tail_append(n_cols: int, p_out: int):
             out.append(jnp.where(in_tail, rolled, grown))
         return tuple(out)
 
-    return jax.jit(fn)
+    return named_jit(fn, "structural_tail_append")
 
 
 def concat_columns(parts: List[List[Any]], lengths: List[int]) -> Tuple[List[Any], int]:

@@ -21,6 +21,7 @@ import functools
 from typing import Any, List, Tuple
 
 import numpy as np
+from modin_tpu.ops._program import named_jit
 
 ROLLING_DEVICE_OPS = ("sum", "mean", "count", "min", "max", "var", "std", "sem")
 EXPANDING_DEVICE_OPS = ("sum", "mean", "count", "min", "max", "var", "std", "sem")
@@ -122,7 +123,7 @@ def _jit_rolling(op: str, n_cols: int, n: int, window: int, min_periods: int, dd
             _one_windowed(op, c, n, window, min_periods, ddof) for c in cols
         )
 
-    return jax.jit(fn)
+    return named_jit(fn, "window_rolling")
 
 
 def rolling_reduce(
@@ -467,7 +468,7 @@ def _jit_ewm_pair(op: str, n_cols: int, n: int, adjust: bool,
             for x, y in zip(xs, ys)
         )
 
-    return jax.jit(fn)
+    return named_jit(fn, "window_ewm_pair")
 
 
 def ewm_pair_reduce(
@@ -500,7 +501,7 @@ def _jit_ewm(op: str, n_cols: int, n: int, adjust: bool, ignore_na: bool,
             for c in cols
         )
 
-    return jax.jit(fn)
+    return named_jit(fn, "window_ewm")
 
 
 def ewm_reduce(

@@ -32,8 +32,9 @@ from modin_tpu.config import BenchmarkMode, DeviceCount
 from modin_tpu.core import memory as _memory
 from modin_tpu.core.execution import recovery as _recovery
 from modin_tpu.core.execution.resilience import engine_call
-from modin_tpu.logging import ClassLogger
+from modin_tpu.logging import ClassLogger, disable_logging
 from modin_tpu.observability import costs as _costs
+from modin_tpu.observability import meters as _meters
 
 
 def _estimate_deploy_bytes(f_args: tuple) -> tuple:
@@ -59,6 +60,18 @@ def _estimate_deploy_bytes(f_args: tuple) -> tuple:
             total += int(item.nbytes)
             ids.add(id(item))
     return total, ids
+
+
+def _device_nbytes(tree: Any) -> Optional[int]:
+    """Bytes of the device arrays in ``tree``; None when it holds none."""
+    import jax
+
+    sizes = [
+        int(leaf.nbytes)
+        for leaf in jax.tree_util.tree_leaves(tree)
+        if isinstance(leaf, jax.Array)
+    ]
+    return sum(sizes) if sizes else None
 
 
 def initialize_jax() -> None:
@@ -190,6 +203,8 @@ class JaxWrapper(ClassLogger, modin_layer="JAX-ENGINE"):
 
             sharding = row_sharding()
         result = engine_call("put", lambda: jax.device_put(data, sharding))
+        if _meters.ACCOUNTING_ON:
+            _meters.note_h2d(_device_nbytes(result) or 0)
         if _recovery.RECOVERY_ON:
             _recovery.record_put(data, result)
         return result
@@ -199,6 +214,11 @@ class JaxWrapper(ClassLogger, modin_layer="JAX-ENGINE"):
         """Device -> host (blocks until the value is computed and fetched)."""
         import jax
 
+        if _meters.ACCOUNTING_ON:
+            # a host sync where it happens: only a device value blocks
+            nbytes = _device_nbytes(obj_refs)
+            if nbytes is not None:
+                _meters.note_host_sync(nbytes)
         return engine_call(
             "materialize", lambda: jax.device_get(obj_refs), watchdog=True
         )
@@ -215,10 +235,23 @@ class JaxWrapper(ClassLogger, modin_layer="JAX-ENGINE"):
         engine_call("wait", lambda: jax.block_until_ready(obj_refs), watchdog=True)
 
     @classmethod
+    @disable_logging  # a one-line predicate asked per column per request
     def is_future(cls, item: Any) -> bool:
         import jax
 
         return isinstance(item, jax.Array)
+
+
+def upload(values: Any, dtype: Any = None) -> Any:
+    """``jnp.asarray`` of a small host table (a remap, a lookup, group
+    sizes) with its bytes counted as host->device traffic.  Frame columns go
+    through ``JaxWrapper.put``, which carries the resilience policy."""
+    import jax.numpy as jnp
+
+    result = jnp.asarray(values, dtype)
+    if _meters.ACCOUNTING_ON:
+        _meters.note_h2d(int(result.nbytes))
+    return result
 
 
 def materialize(obj_refs: Any) -> Any:

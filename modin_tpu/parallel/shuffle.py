@@ -33,6 +33,7 @@ import numpy as np
 
 from modin_tpu.observability import spans as graftscope
 from modin_tpu.parallel.engine import materialize as _engine_materialize
+from modin_tpu.ops._program import named_jit
 
 
 class ShuffleSkewError(RuntimeError):
@@ -51,25 +52,22 @@ def _jit_sample(step: int):
     def fn(key):
         return key[::step]
 
-    return jax.jit(fn)
+    return named_jit(fn, "shuffle_sample")
 
 
 def sample_pivots(key: Any, n: int, num_partitions: int, num_samples: int = 4096) -> np.ndarray:
     """Quantile pivots from a strided device sample (one small fetch)."""
-    with graftscope.span(
-        "shuffle.sample_pivots", layer="SHUFFLE", rows=int(n), shards=num_partitions
-    ):
-        step = max(1, key.shape[0] // num_samples)
-        sample = np.asarray(_engine_materialize(_jit_sample(step)(key)))
-        positions = np.arange(0, key.shape[0], step)
-        sample = sample[positions[: len(sample)] < n]
-        if sample.dtype.kind == "f":
-            sample = sample[~np.isnan(sample)]
-        if len(sample) == 0:
-            return np.zeros(max(num_partitions - 1, 1), dtype=sample.dtype)
-        qs = np.linspace(0, 1, num_partitions + 1)[1:-1]
-        pivots = np.quantile(sample, qs, method="inverted_cdf")
-        return np.asarray(pivots, dtype=sample.dtype)
+    step = max(1, key.shape[0] // num_samples)
+    sample = np.asarray(_engine_materialize(_jit_sample(step)(key)))
+    positions = np.arange(0, key.shape[0], step)
+    sample = sample[positions[: len(sample)] < n]
+    if sample.dtype.kind == "f":
+        sample = sample[~np.isnan(sample)]
+    if len(sample) == 0:
+        return np.zeros(max(num_partitions - 1, 1), dtype=sample.dtype)
+    qs = np.linspace(0, 1, num_partitions + 1)[1:-1]
+    pivots = np.quantile(sample, qs, method="inverted_cdf")
+    return np.asarray(pivots, dtype=sample.dtype)
 
 
 @functools.lru_cache(maxsize=None)
@@ -169,7 +167,7 @@ def _jit_shuffle(
             *payload,
         )
 
-    return jax.jit(
+    return named_jit(
         shard_map(
             local_fn,
             mesh=mesh,
@@ -178,7 +176,8 @@ def _jit_shuffle(
             out_specs=(P("rows"), P("rows"))
             + tuple(P("rows") for _ in range(n_cols + 1)),
             check_vma=False,
-        )
+        ),
+        "shuffle_range_shuffle",
     )
 
 

@@ -425,7 +425,7 @@ def _maybe_fuse(node: PlanNode, memo: Dict[int, Any], groupby: bool) -> Optional
     compiles_before = compiles_on_this_thread()
     with graftscope.span(
         "fuse.lower",
-        layer="QUERY-COMPILER",
+        layer="PLAN",
         sig=f"{hash(sig) & 0xFFFFFFFF:08x}",
         rows=n,
         donated=len(donate_cols),

@@ -112,7 +112,7 @@ def lower_traced(
         _tls.opt_active = True
     try:
         with graftscope.span(
-            "plan.lower", layer="QUERY-COMPILER", nodes=count_nodes(root)
+            "plan.lower", layer="PLAN", nodes=count_nodes(root)
         ):
             result = _lower(root, memo)
     finally:

@@ -510,7 +510,7 @@ def choose(
     exclude = exclude or set()
     with graftscope.span(
         "opt.choose",
-        layer="QUERY-COMPILER",
+        layer="PLAN",
         replanning=replanning,
         correction=round(state.correction, 3),
     ):
@@ -793,7 +793,7 @@ def _replan(state: PlanStrategies, trigger: str, key: Any, **attrs: Any) -> bool
         graftscope.finish_span(
             graftscope.start_span(
                 "opt.replan",
-                layer="QUERY-COMPILER",
+                layer="PLAN",
                 attrs={
                     **event,
                     "replan_s": round(time.perf_counter() - t0, 6),

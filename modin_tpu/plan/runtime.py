@@ -439,7 +439,7 @@ def _optimize_and_lower(
 
     cost_model = optimizer.plan_cost if optimizer.OPT_ON else None
     with graftscope.span(
-        "plan.optimize", layer="QUERY-COMPILER", nodes=count_nodes(root)
+        "plan.optimize", layer="PLAN", nodes=count_nodes(root)
     ):
         optimized, applied = optimize(root, cost_model=cost_model)
     passes = (applied[-1][1] + 1) if applied else 1

@@ -327,6 +327,65 @@ class TestQueryStats:
             assert key in d
         text = qs.summary()
         assert "device dispatches: 1" in text
+        assert "launches: 0, host syncs: 0" in text
+
+    @pytest.mark.parametrize(
+        "key",
+        [
+            "request_id", "host_self_s", "wait_s", "first_launch_s", "launches",
+            "launches_by_program", "host_syncs", "d2h_bytes", "h2d_bytes", "spans",
+        ],
+    )
+    def test_as_dict_carries_the_request_record(self, key):
+        with meters.query_stats("q") as qs:
+            pass
+        record = qs.as_dict()
+        assert key in record
+        assert "api_calls" not in record  # removed: nothing read it
+        json.dumps(record)  # /debug/queries and recent_queries serialise it
+
+    def test_uploads_are_counted_at_put_and_upload(self):
+        from modin_tpu.parallel.engine import JaxWrapper, upload
+
+        host = np.arange(64, dtype=np.int64)
+        with meters.query_stats("up") as qs:
+            device = JaxWrapper.put(host)
+            upload(np.arange(4, dtype=np.int64))
+        assert qs.h2d_bytes == int(device.nbytes) + 32
+        assert qs.host_syncs == 0
+
+    @pytest.mark.parametrize(
+        "value, syncs",
+        [("device", 1), ("host", 0)],
+    )
+    def test_host_sync_is_a_fetch_of_a_device_value(self, value, syncs):
+        import jax.numpy as jnp
+
+        from modin_tpu.parallel.engine import JaxWrapper
+
+        obj = jnp.arange(16, dtype=jnp.int64) if value == "device" else np.arange(16)
+        with meters.query_stats("down") as qs:
+            JaxWrapper.materialize(obj)
+        assert qs.host_syncs == syncs
+        assert qs.d2h_bytes == (128 if syncs else 0)
+        assert qs.wait_s > 0  # the blocked stretch is wait, not JAX-ENGINE host work
+        assert qs.host_self_s.get("JAX-ENGINE", 0.0) >= 0
+
+    def test_launches_reach_every_open_scope_and_no_closed_one(self):
+        import jax.numpy as jnp
+
+        from modin_tpu.ops._program import named_jit
+
+        program = named_jit(lambda x: x + 1, "reduce_probe")
+        with meters.query_stats("outer") as outer:
+            with meters.query_stats("inner") as inner:
+                program(jnp.arange(4))
+            program(jnp.arange(4))
+        program(jnp.arange(4))  # no scope: counted nowhere
+        assert inner.launches_by_program == {"reduce_probe": 1}
+        assert outer.launches_by_program == {"reduce_probe": 2}
+        assert outer.first_launch_s is not None
+        assert outer.first_launch_s <= outer.wall_s
 
 
 # ====================================================================== #

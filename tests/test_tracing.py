@@ -171,7 +171,7 @@ class TestChromeTraceExport:
         assert any(e.get("ph") == "M" for e in trace["traceEvents"])
         # rollup rides along
         rollup = trace["otherData"]["rollup"]
-        for key in ("wall_s", "host_s", "device_s", "compile_s", "spans"):
+        for key in ("wall_s", "host_s", "engine_s", "compile_s", "spans"):
             assert key in rollup
 
     def test_rollup_accounting(self):
@@ -523,3 +523,260 @@ class TestConfigureLoggingRace:
             assert cfg._mem_sampler is sampler_before
         finally:
             cfg.__LOGGER_CONFIGURED__ = saved
+
+
+# ====================================================================== #
+# the per-request record: query_stats switches graftscope on, spans lie on
+# the profiler's clock, device programs carry their builder's name
+# ====================================================================== #
+
+_REPO = __import__("pathlib").Path(__file__).resolve().parent.parent
+_BENCH = _REPO / "benchmark"
+
+
+def _bench_module(*parts):
+    import importlib.util
+
+    path = _BENCH.joinpath(*parts)
+    spec = importlib.util.spec_from_file_location("t_" + path.stem, path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def _first_run(question, frame):
+    """One request as ``benchmark/run.py`` asks it: derived answers dropped,
+    the question, the answer materialised."""
+    from modin_tpu.ops.groupby import clear_factorize_cache
+    from modin_tpu.views import registry
+
+    registry.reset()
+    clear_factorize_cache()
+    answer = question(frame)
+    answer._query_compiler.execute()
+    return answer
+
+
+@pytest.fixture(scope="module")
+def small_frame():
+    rng = np.random.default_rng(7)
+    frame = pd.DataFrame({f"c{i}": rng.integers(0, 100, 4096) for i in range(5)})
+    frame._query_compiler.execute()
+    return frame
+
+
+#: the two cells' questions (and q5, which waits for its cell): cell file
+#: name -> the configuration it runs on
+_CELL_CONFIGS = {
+    "h2o_q4_mean_by_id4": "h2o-groupby-g1-1e8-1e2",
+    "asv_time_arithmetic": "asv-int-5e7x10",
+}
+
+
+def _cell_questions():
+    out = []
+    for config in sorted(set(_CELL_CONFIGS.values())):
+        for path in sorted((_BENCH / "questions" / config).glob("*.py")):
+            out.append((config, path.stem))
+    return out
+
+
+@pytest.fixture(scope="module")
+def cell_frames():
+    """Each configuration's table at its rehearsal size, made once."""
+    frames = {}
+
+    def frame_of(config_name):
+        if config_name not in frames:
+            config = json.loads((_BENCH / "configs" / f"{config_name}.json").read_text())
+            rows = int(config["rehearse_rows"])
+            host = _bench_module("datasets", config["generator"] + ".py").make(
+                11, dict(config, rows=rows), rows
+            )
+            frame = pd.DataFrame(host)
+            frame._query_compiler.execute()
+            frames[config_name] = frame
+        return frames[config_name]
+
+    return frame_of
+
+
+class TestRequestRecord:
+    def test_scope_switches_tracing_on_for_its_life_only(self):
+        assert not graftscope.trace_enabled()
+        with graftscope.query_stats("q") as outer:
+            assert graftscope.trace_enabled()
+            with graftscope.query_stats("q2"):
+                assert graftscope.trace_enabled()
+            assert graftscope.trace_enabled()  # the outer scope still lives
+            with graftscope.layer_span("Some.op", "QUERY-COMPILER") as sp:
+                assert sp.request == outer.request_id
+        assert not graftscope.trace_enabled()
+
+    def test_device_groupby_counts_launches_and_host_syncs(self, small_frame):
+        _require_tpu_on_jax()
+        question = lambda df: df.groupby("c0").agg({"c1": "mean", "c2": "mean"})  # noqa: E731
+        _first_run(question, small_frame)
+        with graftscope.profile() as prof:
+            with graftscope.query_stats("gb") as stats:
+                _first_run(question, small_frame)
+        assert stats.launches >= 3
+        assert stats.host_syncs >= 1 and stats.d2h_bytes > 0
+        assert sum(stats.launches_by_program.values()) == stats.launches
+        assert any(name.startswith("groupby_") for name in stats.launches_by_program)
+        # dispatches keeps its meaning: successful engine-seam deploys
+        deploys = [sp for sp in prof.find("engine.deploy.attempt") if sp.status == "ok"]
+        assert stats.dispatches == len(deploys)
+        assert stats.first_launch_s is not None and 0 < stats.first_launch_s < stats.wall_s
+        assert stats.h2d_bytes > 0  # the remap table is uploaded
+
+    @pytest.mark.parametrize(
+        "question",
+        [
+            lambda df: df.add(2),
+            lambda df: df.sum(),
+            lambda df: df.count(axis=1),
+            lambda df: df.groupby("c0").agg({"c1": "mean"}),
+        ],
+        ids=["add", "sum", "count_axis1", "groupby_mean"],
+    )
+    def test_host_split_sums_to_the_wall(self, small_frame, question):
+        _first_run(question, small_frame)
+        with graftscope.query_stats("split") as stats:
+            _first_run(question, small_frame)
+        record = stats.as_dict()
+        total = sum(record["host_self_s"].values()) + record["wait_s"]
+        assert total == pytest.approx(record["wall_s"], rel=0.05)
+        assert all(seconds >= 0 for seconds in record["host_self_s"].values())
+        assert "PANDAS-API" in record["host_self_s"] and "CALLER" in record["host_self_s"]
+        assert record["spans"] > 0
+
+    def test_add_emits_few_spans_and_no_is_future(self, small_frame):
+        _first_run(lambda df: df.add(2), small_frame)
+        with graftscope.profile() as prof:
+            _first_run(lambda df: df.add(2), small_frame)
+        names = [sp.name for sp in prof.spans]
+        assert not [n for n in names if "is_future" in n]
+        assert len(names) <= 20, names
+        assert "lazy.linearize" in names  # the plan/lazy step has a span of its own
+
+    def test_no_spans_allocated_outside_any_scope(self, small_frame):
+        with graftscope.query_stats("warm"):
+            _first_run(lambda df: df.add(2), small_frame)
+        assert not graftscope.trace_enabled()
+        before = graftscope.span_alloc_count()
+        for _ in range(100):
+            _first_run(lambda df: df.add(2), small_frame)
+        assert graftscope.span_alloc_count() == before
+
+    def test_recent_queries_is_bounded_and_ordered(self):
+        from modin_tpu.observability import meters
+
+        for i in range(meters.RECENT_QUERIES_MAX + 8):
+            with graftscope.query_stats(f"ring{i % 2}"):
+                pass
+        every = graftscope.recent_queries()
+        assert len(every) == meters.RECENT_QUERIES_MAX
+        ids = [record["request_id"] for record in every]
+        assert ids == sorted(ids) and len(set(ids)) == len(ids)  # oldest first
+        ours = graftscope.recent_queries("ring1")
+        assert ours and {record["label"] for record in ours} == {"ring1"}
+        assert ours[-1]["request_id"] == max(r["request_id"] for r in ours)
+        assert len(ours) < len(every)
+
+    def test_spans_land_in_a_profiler_trace_inside_the_callers_annotation(
+        self, small_frame, tmp_path
+    ):
+        import glob
+
+        import jax
+        from jax.profiler import ProfileData
+
+        _first_run(lambda df: df.add(2), small_frame)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 1
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            with jax.profiler.TraceAnnotation("outer/request"):
+                with graftscope.query_stats("traced") as stats:
+                    with graftscope.span("io.read", layer="CORE-IO", dispatcher="Probe"):
+                        _first_run(lambda df: df.add(2), small_frame)
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+        events = [
+            ev
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines
+            for ev in line.events
+            if ev.name.startswith(("mt/", "outer/"))
+        ]
+        (outer,) = [ev for ev in events if ev.name == "outer/request"]
+        ours = [ev for ev in events if ev.name.startswith("mt/")]
+        assert {ev.name.split("/")[1] for ev in ours} >= {"PANDAS-API", "QUERY-COMPILER", "JAX-ENGINE"}
+        for ev in ours:
+            assert outer.start_ns <= ev.start_ns
+            assert ev.start_ns + ev.duration_ns <= outer.start_ns + outer.duration_ns
+            assert dict(ev.stats)["request"] == stats.request_id
+        (probe,) = [ev for ev in ours if ev.name == "mt/CORE-IO/io.read"]
+        assert dict(probe.stats)["dispatcher"] == "Probe"  # span attributes are event stats
+
+    @pytest.mark.parametrize("config, name", _cell_questions())
+    def test_every_program_a_cells_question_launches_is_named(
+        self, cell_frames, config, name
+    ):
+        _require_tpu_on_jax()
+        frame = cell_frames(config)
+        question = _bench_module("questions", config, name + ".py")
+        _first_run(lambda df: question.run(pd, df), frame)
+        with graftscope.query_stats(name) as stats:
+            _first_run(lambda df: question.run(pd, df), frame)
+        assert stats.launches > 0, "the question launched no device program"
+        for program in stats.launches_by_program:
+            assert program not in ("fn", "execute", "program") and "<" not in program
+            assert not program.startswith("bench_")
+            assert program.split("_")[0] in (
+                "plan", "groupby", "reduce", "sort", "elementwise", "structural",
+                "join", "window", "stats", "datetime", "spmd", "shuffle", "router",
+            ), program
+
+
+class TestNamedPrograms:
+    def test_program_carries_its_name_into_the_lowering(self):
+        import jax.numpy as jnp
+
+        from modin_tpu.ops import groupby
+
+        program = groupby._jit_range_codes(8, 3)
+        assert program.name == "groupby_range_codes"
+        lowered = program.lower(
+            jnp.arange(8), jnp.int64(0), jnp.arange(8)
+        )  # .lower() is the jitted function's own
+        assert "jit_groupby_range_codes" in lowered.as_text()
+
+    def test_bench_prefix_is_refused(self):
+        from modin_tpu.ops._program import named_jit
+
+        with pytest.raises(ValueError):
+            named_jit(lambda x: x, "bench_slices")
+
+    @pytest.mark.parametrize(
+        "nodes, tail_key, want",
+        [
+            ((("mod", (), ()),) * 3, None, "plan_mod"),
+            ((("add", (), ()), ("abs", (), ())), ("reduce", "sum", 7, True), "plan_add_abs_reduce_sum"),
+            ((), ("reduce_axis1", "count", True), "plan_reduce_axis1_count"),
+            ((), (3, "x"), "plan_tail"),
+        ],
+    )
+    def test_fused_program_is_named_after_its_ops(self, nodes, tail_key, want):
+        from modin_tpu.ops.lazy import _program_name
+
+        assert _program_name(nodes, tail_key) == want
+
+    def test_long_names_are_cut(self):
+        from modin_tpu.ops._program import named_jit
+
+        assert len(named_jit(lambda x: x, "plan_" + "x" * 80).name) == 48
