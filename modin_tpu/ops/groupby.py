@@ -8,7 +8,10 @@ array XLA emits per-shard segment partials + a psum over ICI — exactly the
 map/tree-reduce structure of the reference, compiled instead of scheduled.
 
 Key factorization strategies:
-- int-like keys with a small value range     -> direct offset codes (no sort)
+- int-like keys with a small value range     -> direct offset codes (no sort);
+                                                when every id of the range
+                                                occurs the offsets are the
+                                                codes, else one remap gather
 - anything else                              -> jnp.unique (device sort, one
                                                 host sync for the group count)
 
@@ -223,11 +226,16 @@ def factorize_keys(
                 ids = _jit_range_ids(n, width)(k64, jnp.int64(kmin))
                 counts = np.asarray(_engine_materialize(_count_ids(ids, width)))
                 present = np.nonzero(counts)[0]
-                remap = np.full(width, len(present), dtype=np.int64)
-                remap[present] = np.arange(len(present))
-                codes = _jit_range_codes(n, len(present))(
-                    k64, jnp.int64(kmin), _engine_upload(remap)
-                )
+                if len(present) == width:
+                    # every id of the range occurs: the remap would be the
+                    # identity and the ids (pads -> width) are the codes
+                    codes = ids
+                else:
+                    remap = np.full(width, len(present), dtype=np.int64)
+                    remap[present] = np.arange(len(present))
+                    codes = _jit_range_codes(n, len(present))(
+                        k64, jnp.int64(kmin), _engine_upload(remap)
+                    )
                 uniques = (present + kmin).astype(np.int64)
                 if kdt == jnp.bool_:
                     uniques = uniques.astype(bool)
@@ -276,9 +284,13 @@ def factorize_keys(
     composite = _jit_composite(tuple(n_groups_each), n, total)(tuple(level_codes))
     counts = np.asarray(_engine_materialize(_jit_bincount(total)(composite)))
     present = np.nonzero(counts)[0]
-    remap = np.full(total + 1, len(present), dtype=np.int64)
-    remap[present] = np.arange(len(present))
-    codes = _jit_remap(len(present))(composite, _engine_upload(remap))
+    if len(present) == total:
+        # every combination of level codes occurs: identity remap, as above
+        codes = composite
+    else:
+        remap = np.full(total + 1, len(present), dtype=np.int64)
+        remap[present] = np.arange(len(present))
+        codes = _jit_remap(len(present))(composite, _engine_upload(remap))
     keys_out: List[np.ndarray] = []
     rem = present.copy()
     for uniques_i, n_i in zip(reversed(level_uniques), reversed(n_groups_each)):

@@ -265,6 +265,103 @@ def test_pallas_bincount_row_sharded_operand():
         assert _jit_bincount_wrapper.cache_info().hits == before + 1
 
 
+_case_rng = np.random.default_rng(27)
+
+
+def _covering(values, n):
+    """n draws from ``values`` with every one of them present."""
+    values = np.asarray(values)
+    return _case_rng.permutation(
+        np.concatenate([values, _case_rng.choice(values, n - len(values))])
+    )
+
+
+def _pairs(pairs, n):
+    rows = np.asarray(pairs)[_covering(np.arange(len(pairs)), n)]
+    return {"k1": rows[:, 0], "k2": rows[:, 1]}
+
+
+_ALL_PAIRS = [(a, b) for a in range(4) for b in range(3)]
+
+# name -> (key columns of logical length n, launches of the two remap programs).
+# 208 rows fill the 8-shard padding exactly; 203 leave five pad rows.
+_RANGE_CODE_CASES = {
+    "dense_int": ({"k": _covering(np.arange(10), 208)}, {}),
+    "dense_negative_kmin": ({"k": _covering(np.arange(-7, 6), 208)}, {}),
+    "dense_bool": ({"k": _covering([False, True], 208)}, {}),
+    "dense_pad_rows": ({"k": _covering(np.arange(10), 203)}, {}),
+    "holes": (
+        {"k": _covering([-5, 3, 4, 70, 1000], 203)},
+        {"groupby_range_codes": 1},
+    ),
+    "multikey_all_present": (_pairs(_ALL_PAIRS, 203), {}),
+    "multikey_one_absent": (
+        _pairs([p for p in _ALL_PAIRS if p != (2, 1)], 203),
+        {"groupby_remap": 1},
+    ),
+}
+
+
+@pytest.mark.parametrize("case", list(_RANGE_CODE_CASES))
+def test_range_codes_skip_identity_remap(case):
+    """A key range (or a product of level codes) with every id present needs
+    no remap: the range ids are the group codes, and the gather program is
+    launched only when the range has holes."""
+    import jax.numpy as jnp
+
+    import modin_tpu.observability as graftscope
+    from modin_tpu.ops.groupby import clear_factorize_cache, factorize_keys
+    from modin_tpu.views import registry
+
+    keys, remap_launches = _RANGE_CODE_CASES[case]
+    n = len(next(iter(keys.values())))
+    padded = -(-n // 8) * 8
+
+    def remap_programs(stats):
+        return {
+            name: count
+            for name, count in stats.launches_by_program.items()
+            if name in ("groupby_range_codes", "groupby_remap")
+        }
+
+    # pad rows hold a key far outside the range: only position may mask them
+    key_cols = [
+        jnp.asarray(np.concatenate([k, np.full(padded - n, 10**6).astype(k.dtype)]))
+        for k in keys.values()
+    ]
+    with graftscope.query_stats("factorize") as stats:
+        codes, n_groups, uniques, sizes = factorize_keys(key_cols, n)
+    stacked = np.stack([k.astype(np.int64) for k in keys.values()], axis=1)
+    want_uniques, want_codes, want_sizes = np.unique(
+        stacked, axis=0, return_inverse=True, return_counts=True
+    )
+    assert codes.dtype == jnp.int64 and codes.shape == (padded,)
+    np.testing.assert_array_equal(
+        np.asarray(codes),
+        np.concatenate([want_codes.ravel(), np.full(padded - n, n_groups)]),
+    )
+    assert n_groups == len(want_uniques)
+    for got, want, k in zip(uniques, want_uniques.T, keys.values()):
+        assert got.dtype == k.dtype
+        np.testing.assert_array_equal(got, want.astype(k.dtype))
+    np.testing.assert_array_equal(sizes, want_sizes)
+    assert remap_programs(stats) == remap_launches
+
+    pdf = pandas.DataFrame(
+        {**keys, "v": _case_rng.uniform(-1, 1, n), "w": _case_rng.integers(-9, 9, n)}
+    )
+    md = pd.DataFrame(pdf)
+    md._query_compiler.execute()
+    registry.reset()
+    clear_factorize_cache()
+    with graftscope.query_stats("groupby") as stats:
+        got = assert_no_fallback(lambda: md.groupby(list(keys)).mean())
+        got._query_compiler.execute()
+    df_equals(got, pdf.groupby(list(keys)).mean())
+    assert "groupby_range_ids" in stats.launches_by_program
+    assert remap_programs(stats) == remap_launches
+
+
 def test_groupby_agg_list_device(dfs):
     md, pdf = dfs
     got = assert_no_fallback(

@@ -613,8 +613,14 @@ class TestRequestRecord:
                 assert sp.request == outer.request_id
         assert not graftscope.trace_enabled()
 
-    def test_device_groupby_counts_launches_and_host_syncs(self, small_frame):
+    @pytest.mark.parametrize("key_step", [1, 3], ids=["dense_key", "key_with_holes"])
+    def test_device_groupby_counts_launches_and_host_syncs(self, small_frame, key_step):
         _require_tpu_on_jax()
+        if key_step > 1:  # c0 in [0, 100) times 3: two of every three ids absent
+            host = small_frame.modin.to_pandas()
+            host["c0"] *= key_step
+            small_frame = pd.DataFrame(host)
+            small_frame._query_compiler.execute()
         question = lambda df: df.groupby("c0").agg({"c1": "mean", "c2": "mean"})  # noqa: E731
         _first_run(question, small_frame)
         with graftscope.profile() as prof:
@@ -628,7 +634,10 @@ class TestRequestRecord:
         deploys = [sp for sp in prof.find("engine.deploy.attempt") if sp.status == "ok"]
         assert stats.dispatches == len(deploys)
         assert stats.first_launch_s is not None and 0 < stats.first_launch_s < stats.wall_s
-        assert stats.h2d_bytes > 0  # the remap table is uploaded
+        # only a key range with holes needs (and uploads) a remap table
+        remapped = "groupby_range_codes" in stats.launches_by_program
+        assert remapped == (key_step > 1)
+        assert (stats.h2d_bytes > 0) == remapped
 
     @pytest.mark.parametrize(
         "question",
