@@ -8,7 +8,8 @@ pd``, default execution, no ``MODIN_TPU_*`` option set): it makes the cell's
 table from ``--seed``, ingests it, asks each of the cell's questions once
 (set-up: compiles or cache loads), then asks them again in blocks shuffled
 from the seed, one closed-loop client, until the first block that ends at or
-after ``--seconds``.  Before each request
+after ``--seconds`` (with ``--trace 1``: and after the profiler's start, so a
+traced run always traces a whole request).  Before each request
 the program's derived answers are dropped, so every request is a first run.
 Once the window has closed the answers are compared with plain pandas.
 
@@ -56,18 +57,21 @@ sys.path.insert(0, ROOT)
 sys.path.insert(0, BENCH)
 
 # an answer of at most this many device bytes is kept there until the window
-# has closed and compared whole.  Of the larger ones a sample drawn from the
-# seed is looked into between requests: the first answer to each question (in
-# set-up) and every SAMPLE_EVERY-th after it, at SAMPLED_RUNS runs of
-# SAMPLED_RUN_ROWS consecutive rows.  Not each one, because any program that
-# reads a 64-bit buffer on a TPU first splits the whole of it into 32-bit
+# has closed and compared whole, while the kept ones stay within
+# KEEP_TOTAL_BYTES together.  Of the others (larger, or past the total) a
+# sample drawn from the seed is looked into between requests: the first such
+# answer to each question and every SAMPLE_EVERY-th after it, at SAMPLED_RUNS
+# runs of SAMPLED_RUN_ROWS consecutive rows.  Not each one, because any program
+# that reads a 64-bit buffer on a TPU first splits the whole of it into 32-bit
 # halves: 26 ms of device time for a 4 GB answer, to a request's 68.
 KEEP_BYTES = 64 << 20
 KEEP_TOTAL_BYTES = 1 << 30
 SAMPLE_EVERY = 8
 SAMPLED_RUNS = 16
 SAMPLED_RUN_ROWS = 256
-TRACE_SECONDS = 3.0  # of the window, from its second block of requests on, with --trace 1
+# of the window with --trace 1: from its second block of requests on, or from
+# its first where the first pass says that one block alone reaches --seconds
+TRACE_SECONDS = 3.0
 TRACE_DIR = os.path.join(ROOT, ".modin_tpu", "benchmark_trace")
 
 
@@ -192,7 +196,6 @@ class Run:
         self.kept = []  # (request number, question, the program's answer)
         self.kept_bytes = 0
         self.sampled = []  # (request number, question, sample)
-        self.unchecked = 0
         self.unsampled = 0
         self.tracing = False
         self.keep_bytes = KEEP_BYTES
@@ -246,11 +249,12 @@ class Run:
         return record
 
     def keep_evidence(self, number, question, answer, buffers):
+        """Keeps the answer whole while it and the kept total allow; an answer
+        too large, or one that would pass the total, is sampled or passed over
+        as the sampler's turn says.  (No buffers: the answer left the device,
+        the request has failed, and what there is of it is kept.)"""
         nbytes = sum(b.nbytes for _, b, _ in buffers) if buffers else 0
-        if buffers is None or nbytes <= self.keep_bytes:
-            if self.kept_bytes + nbytes > KEEP_TOTAL_BYTES:
-                self.unchecked += 1
-                return
+        if buffers is None or (nbytes <= self.keep_bytes and self.kept_bytes + nbytes <= KEEP_TOTAL_BYTES):
             self.kept.append((number, question, answer))
             self.kept_bytes += nbytes
             return
@@ -258,6 +262,99 @@ class Run:
             self.unsampled += 1
             return
         self.sampled.append((number, question, self.sampler.take(buffers)))
+
+    def warm_sampler(self, names, blocks):
+        """Set-up's last step.  Where ``blocks`` blocks (``names``: a block's
+        questions) of answers like the first pass's would pass the kept total,
+        the window will sample answers of shapes that no sample has been taken
+        of: each kept answer is looked into once now, so that the sampler's
+        program for its shape compiles here and not in the window."""
+        buffers = {}
+        for _, question, answer in self.kept:
+            with contextlib.suppress(self.hooks.NotOnDevice):
+                buffers[question] = self.hooks.device_buffers(answer)
+        nbytes = {q: sum(b.nbytes for _, b, _ in bufs) for q, bufs in buffers.items()}
+        if self.kept_bytes + blocks * sum(nbytes.get(q, 0) for q in names) <= KEEP_TOTAL_BYTES:
+            return 0
+        for bufs in buffers.values():
+            self.sampler.take(bufs)
+        return len(buffers)
+
+
+class Profiler:
+    """``jax.profiler`` over the traced part of a window; while it records,
+    ``Run.phase`` writes the harness's phases into the trace."""
+
+    def __init__(self, run):
+        self.run = run
+
+    def start(self):
+        import jax
+
+        shutil.rmtree(TRACE_DIR, ignore_errors=True)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 1
+        jax.profiler.start_trace(TRACE_DIR, profiler_options=options)
+        self.run.tracing = True
+
+    def stop(self):
+        import jax
+
+        jax.profiler.stop_trace()
+        self.run.tracing = False
+
+
+def block_seconds(first, names):
+    """What the first pass says a block of the window will take: ``first``
+    holds the first pass's request of each question (what it spent compiling
+    does not come again), ``names`` a block's questions."""
+    wall_s = {r["question"]: max(0.0, r["wall_s"] - r["compile_s"]) for r in first}
+    return sum(wall_s[q] for q in names)
+
+
+def trace_from_block(block_s, seconds):
+    """The block of the window before which a traced run starts the profiler:
+    the second (1), so that the trace holds steady requests, or the first (0)
+    where the first pass says that one block alone (``block_s``) reaches
+    ``seconds``: the window would close with it."""
+    return 0 if block_s >= seconds else 1
+
+
+def drive_window(ask, order, block, seconds, profiler, from_block, clock=time.perf_counter):
+    """The window: the questions of ``order`` through ``ask(number, question)``
+    until the first block that ends at or after ``seconds``, so that every
+    window holds the questions in the cell's proportions.  With a ``profiler``
+    (``--trace 1``) it records from block ``from_block`` until the first request
+    that ends TRACE_SECONDS later, and the window does not close before it has
+    started: where the first block outlasts ``seconds`` against the first pass's
+    word, the window stays open for one block more and that one is traced.
+    Returns the requests' records, the traced questions and the window's seconds."""
+    requests, traced = [], []
+    tracing = False
+    trace_started = None
+    window_start = clock()
+    while True:
+        number = len(requests)
+        if profiler is not None and trace_started is None and number >= from_block * block:
+            profiler.start()
+            tracing = True
+            trace_started = clock()
+        record = ask(number, next(order))
+        requests.append(record)
+        now = clock()
+        if tracing:
+            traced.append(record["question"])
+            if now - trace_started >= TRACE_SECONDS:
+                profiler.stop()
+                tracing = False
+        if now - window_start >= seconds and len(requests) % block == 0:
+            if profiler is None or trace_started is not None:
+                break
+    window_s = clock() - window_start
+    if tracing:
+        profiler.stop()
+    return requests, traced, window_s
 
 
 def judge(run, cell, host, with_control):
@@ -348,7 +445,12 @@ def measure(cell, args, hooks, pd, trap, t0):
     # the first pass: every question once, in the file's order, as the window
     # will ask it; compiles or loads every program the window uses
     compiles0, compile_s0 = hooks.compile_totals()
-    first = [run.request(-1 - i, q) for i, q in enumerate(cell.questions)]
+    first = []
+    for i, question in enumerate(cell.questions):
+        compiled_s = hooks.compile_totals()[1]
+        record = run.request(-1 - i, question)
+        record["compile_s"] = hooks.compile_totals()[1] - compiled_s
+        first.append(record)
     compiles1, compile_s1 = hooks.compile_totals()
     first_pass = {
         "wall_s": sum(r["wall_s"] for r in first),
@@ -357,41 +459,23 @@ def measure(cell, args, hooks, pd, trap, t0):
         "failed": sum(1 for r in first if r["failed"]),
     }
     log(phase="first_pass", **first_pass)
+    names = traffic.block(cell.file)
+    block_s = block_seconds(first, names)
+    looked_into = run.warm_sampler(names, 1 + int(args.seconds / block_s) if block_s > 0 else sys.maxsize)
+    log(phase="warm_sampler", predicted_block_s=block_s, looked_into=looked_into)
     gc.collect()
 
     # the window
-    block = len(traffic.block(cell.file))
-    order = traffic.requests(cell.file, args.seed)
-    requests = []
-    traced = []
-    trace_started = None
+    profiler = Profiler(run) if args.trace else None
+    from_block = trace_from_block(block_s, args.seconds) if args.trace else None
     setup_s = time.perf_counter() - t0
     compiles0, _ = hooks.compile_totals()
-    window_start = time.perf_counter()
-    while True:
-        number = len(requests)
-        if args.trace and trace_started is None and number >= block:
-            shutil.rmtree(TRACE_DIR, ignore_errors=True)
-            options = jax.profiler.ProfileOptions()
-            options.python_tracer_level = 0
-            options.host_tracer_level = 1
-            jax.profiler.start_trace(TRACE_DIR, profiler_options=options)
-            run.tracing = True
-            trace_started = time.perf_counter()
-        record = run.request(number, next(order))
-        requests.append(record)
-        now = time.perf_counter()
-        if run.tracing:
-            traced.append(record["question"])
-            if now - trace_started >= TRACE_SECONDS:
-                jax.profiler.stop_trace()
-                run.tracing = False
-        if now - window_start >= args.seconds and len(requests) % block == 0:
-            break  # at the end of a block: every run's window holds the questions in the cell's proportions
-    window_s = time.perf_counter() - window_start
-    if run.tracing:
-        jax.profiler.stop_trace()
-        run.tracing = False
+    requests, traced, window_s = drive_window(
+        run.request, traffic.requests(cell.file, args.seed), len(names), args.seconds, profiler, from_block
+    )
+    if args.trace and not traced:
+        print("run.py: the harness's own fault: --trace 1 and the window closed with no request traced", file=sys.stderr)
+        raise SystemExit(2)
     compiles1, _ = hooks.compile_totals()
     log(phase="window", wall_s=window_s, requests=[[r["question"], round(r["wall_s"], 6)] for r in requests])
 
@@ -428,7 +512,7 @@ def measure(cell, args, hooks, pd, trap, t0):
     t = time.perf_counter()
     tally, control = judge(run, cell, host, args.control)
     reference_s = time.perf_counter() - t
-    unanswered = sum(1 for r in requests + first if not r["answered"]) + run.unchecked
+    unanswered = sum(1 for r in requests + first if not r["answered"])
     breaks = guarantee_breaks(requests + first, trap, trace, least_bytes, peaks)
     if args.trace and not args.rehearse and not (trace and trace["busy_s"]):
         breaks["traced_window_without_device_work"] = 1
@@ -473,7 +557,11 @@ def measure(cell, args, hooks, pd, trap, t0):
         "window_s": window_s,
         "reference_s": reference_s,
         "checked": run.checked,
+        "kept_whole": run.checked - len(run.sampled),
         "large_answers_not_in_the_sample": run.unsampled,
+        "traced_requests": len(traced),
+        "trace_from_block": from_block,
+        "device_programs": trace["device_programs"] if trace else None,
         "between_requests_s_per_query": (window_s - sum(r["wall_s"] for r in requests)) / len(requests),
         "median_wall_s_by_question": {q: statistics.median(w) for q, w in walls.items() if w},
         "why_failed": [r.get("why") for r in requests + first if r["failed"]][:3],

@@ -52,17 +52,24 @@ class Copy:
         self.add_entries("workloads", [entry])
         return cell
 
-    def rehearse(self, cell, *extra):
-        """``run.py --rehearse`` on the CPU; returns the result line's object."""
+    def rehearse(self, cell, *extra, keep_total_bytes=None):
+        """``run.py --rehearse`` on the CPU; returns the result line's object.
+        ``keep_total_bytes`` stands in for ``run.KEEP_TOTAL_BYTES`` (no
+        rehearsal's answers reach the 1 GB that is committed)."""
         env = {k: v for k, v in os.environ.items() if not k.startswith("MODIN_TPU_")}
         env.update(JAX_PLATFORMS="cpu", PYTHONPATH="")
         env.pop("XLA_FLAGS", None)
+        program = [os.path.join(self.root, "benchmark", "run.py")]
+        if keep_total_bytes is not None:
+            program = ["-c", "import sys; sys.path.insert(0, 'benchmark'); import run; "
+                       f"run.KEEP_TOTAL_BYTES = {int(keep_total_bytes)}; sys.exit(run.main(sys.argv[1:]))"]
         done = subprocess.run(
-            [sys.executable, os.path.join(self.root, "benchmark", "run.py"), "--workload", cell,
+            [sys.executable, *program, "--workload", cell,
              "--seed", "2147483659", "--seconds", "1", "--rehearse", *extra],
             capture_output=True, text=True, env=env, cwd=self.root, timeout=600,
         )
         assert done.returncode == 1, done.stderr[-3000:]
+        self.last_stderr = done.stderr
         return json.loads(done.stdout.strip().splitlines()[-1])
 
 

@@ -80,3 +80,19 @@ def test_recorded_chip_trace():
     each = got["busy_s_per_request"]
     assert max(each) < 1.5 * min(each)
     assert all(name.startswith("jit_") and "/" in name for name, _ in got["device_ops"])
+
+
+def test_programs_by_hand_and_of_the_recorded_chip_trace():
+    launches = {"/device:TPU:0": [(8, 32, "a"), (49, 61, "b"), (70, 90, "a")]}
+    got = trace_reduce.reduce_events(OPS, PHASES, launches)
+    assert got["device_programs"] == [["a", pytest.approx(34e-9)], ["b", pytest.approx(12e-9)]]  # clipped at 80
+    assert trace_reduce.reduce_events(OPS, PHASES)["device_programs"] == []
+    ops, phases, programs = trace_reduce.read_planes(RECORDED)
+    assert (ops, phases) == trace_reduce.read_events(RECORDED)
+    got = trace_reduce.reduce_events(ops, phases, programs)
+    assert got["device_programs"][0][0] == "jit_fn", "recorded before the program's closures carried names"
+    # a program's launches hold its operations and the gaps between them: together
+    # they are the busy time and a little more, which its nesting operations' sum is not
+    by_program = sum(seconds for _, seconds in got["device_programs"])
+    assert got["busy_s"] <= by_program < 1.01 * got["busy_s"]
+    assert sum(seconds for _, seconds in got["device_ops"]) > by_program, "the top ten alone pass it"

@@ -4,8 +4,10 @@ Read with nothing but JAX (``jax.profiler.ProfileData``).  What is taken:
 
 * device operations: the events of the line ``XLA Ops`` of every plane named
   ``/device:TPU:<n>`` (one plane a chip), each named ``<program>/<op>`` after
-  the event of the line ``XLA Modules`` it starts in (the program's jitted
-  closures are nearly all called ``fn``, so expect many ``jit_fn``);
+  the event of the line ``XLA Modules`` it starts in;
+* device programs: the events of that line ``XLA Modules`` themselves, one a
+  launch.  A program's operations nest (a ``while`` holds its body's), so
+  their seconds do not add up to the program's: these do;
 * the harness's own phases: host events named ``bench/<phase>``, written by
   ``jax.profiler.TraceAnnotation`` around each request (``reset``,
   ``api_call``, ``execute_wait``, ``between_requests``).
@@ -42,11 +44,17 @@ def find_xplane(trace_dir):
 
 
 def read_events(path):
-    """``(ops, phases)``: ``{plane: [(start_ns, end_ns, name)]}`` of device
-    operations and ``[(start_ns, end_ns, phase)]`` of the harness's phases."""
+    """``(ops, phases)`` of ``read_planes``."""
+    return read_planes(path)[:2]
+
+
+def read_planes(path):
+    """``(ops, phases, programs)``: ``{plane: [(start_ns, end_ns, name)]}`` of
+    device operations, ``[(start_ns, end_ns, phase)]`` of the harness's phases
+    and ``{plane: [(start_ns, end_ns, program)]}`` of the programs' launches."""
     from jax.profiler import ProfileData
 
-    ops, phases = {}, []
+    ops, phases, programs = {}, [], {}
     for plane in ProfileData.from_file(path).planes:
         if plane.name.startswith(DEVICE_PLANE) and plane.name[len(DEVICE_PLANE):].isdigit():
             lines = {line.name: line for line in plane.lines}
@@ -55,6 +63,7 @@ def read_events(path):
                     (ev.start_ns, ev.start_ns + ev.duration_ns, ev.name.split("(")[0])
                     for ev in (lines[MODULES_LINE].events if MODULES_LINE in lines else ())
                 )
+                programs[plane.name] = modules
                 starts = [m[0] for m in modules]
                 named = ops.setdefault(plane.name, [])
                 for ev in lines[OPS_LINE].events:
@@ -72,7 +81,7 @@ def read_events(path):
                             (ev.start_ns, ev.start_ns + ev.duration_ns, ev.name[len(PHASE_PREFIX):])
                         )
     phases.sort()
-    return ops, phases
+    return ops, phases, programs
 
 
 def union(intervals, lo, hi):
@@ -93,7 +102,7 @@ def covered(disjoint, lo, hi):
     return sum(max(0.0, min(e, hi) - max(s, lo)) for s, e in disjoint)
 
 
-def reduce_events(ops, phases):
+def reduce_events(ops, phases, programs=None):
     if not phases:
         raise ValueError("the trace holds no bench/ phase: nothing marks the window")
     if not ops:
@@ -105,7 +114,6 @@ def reduce_events(ops, phases):
     busy_ns = 0.0
     per_request = [0.0] * len(bounds)
     idle_by_phase = {}
-    seconds_by_op = {}
     busy_all_ns = 0.0
     for every in ops.values():
         ours = union([(s, e) for s, e, name in every if not name.startswith(HARNESS_PROGRAMS)], lo, hi)
@@ -122,14 +130,19 @@ def reduce_events(ops, phases):
         outside = (hi - lo) - covered(busy, lo, hi) - attributed * chips
         if outside > 0:
             idle_by_phase["outside_phases"] = idle_by_phase.get("outside_phases", 0.0) + outside / chips
-        for s, e, name in every:
-            inside = min(e, hi) - max(s, lo)
-            if inside > 0:
-                seconds_by_op[name] = seconds_by_op.get(name, 0.0) + inside / chips
 
     def top(table):
         ranked = sorted(table.items(), key=lambda kv: -kv[1])[:10]
         return [[name, ns / 1e9] for name, ns in ranked]
+
+    def top_by_name(events_by_plane):
+        table = {}
+        for events in events_by_plane.values():
+            for s, e, name in events:
+                inside = min(e, hi) - max(s, lo)
+                if inside > 0:
+                    table[name] = table.get(name, 0.0) + inside / chips
+        return top(table)
 
     return {
         "window_s": (hi - lo) / 1e9,
@@ -138,11 +151,12 @@ def reduce_events(ops, phases):
         "harness_busy_s": (busy_all_ns - busy_ns) / 1e9,
         "requests": len(bounds),
         "busy_s_per_request": [ns / 1e9 for ns in per_request],
-        "device_ops": top(seconds_by_op),
+        "device_ops": top_by_name(ops),
+        "device_programs": top_by_name(programs or {}),
         "idle_gaps": top(idle_by_phase),
         "chips": chips,
     }
 
 
 def reduce(trace_dir):
-    return reduce_events(*read_events(find_xplane(trace_dir)))
+    return reduce_events(*read_planes(find_xplane(trace_dir)))
