@@ -3942,6 +3942,7 @@ class TpuQueryCompiler(BaseQueryCompiler):
             return None
         if n_groups == 0:
             return None
+        codes = gb_ops.codes_array(codes)  # shuffled and indexed below, row by row
 
         import jax
         import jax.numpy as jnp

@@ -503,6 +503,16 @@ def note_launch(program: str) -> None:
             qs._on_launch(program, now)
 
 
+def note_groupby_form(form: str) -> None:
+    """One choice of a groupby reduction's or histogram's device form
+    (``ops/groupby.py``, which checks ``ACCOUNTING_ON`` first): ``masked_scan``,
+    ``sorted_tiles``, ``segment``, ``pallas_bincount`` or ``scatter_counts``."""
+    stack = _spans.thread_requests()
+    if stack:
+        for qs in stack:
+            qs._on_groupby_form(form)
+
+
 def note_host_sync(nbytes: int) -> None:
     """One blocking device->host fetch of ``nbytes`` on this thread."""
     stack = _spans.thread_requests()
@@ -579,6 +589,7 @@ class QueryStats:
         "first_launch_s",
         "launches",
         "launches_by_program",
+        "groupby_forms",
         "host_syncs",
         "d2h_bytes",
         "h2d_bytes",
@@ -660,6 +671,9 @@ class QueryStats:
         # device->host fetches with their bytes, and uploaded bytes
         self.launches = 0
         self.launches_by_program: Dict[str, int] = {}
+        # which device form each groupby reduction / histogram took, noted
+        # where it is chosen: a scatter form on a TPU is a minute, not seconds
+        self.groupby_forms: Dict[str, int] = {}
         self.host_syncs = 0
         self.d2h_bytes = 0
         self.h2d_bytes = 0
@@ -778,6 +792,11 @@ class QueryStats:
             by = self.launches_by_program
             by[program] = by.get(program, 0) + 1
 
+    def _on_groupby_form(self, form: str) -> None:
+        with self._lock:
+            if not self._closed:
+                self.groupby_forms[form] = self.groupby_forms.get(form, 0) + 1
+
     def _on_host_sync(self, nbytes: int) -> None:
         with self._lock:
             if not self._closed:
@@ -851,6 +870,7 @@ class QueryStats:
             "first_launch_s": self.first_launch_s,
             "launches": self.launches,
             "launches_by_program": dict(self.launches_by_program),
+            "groupby_forms": dict(self.groupby_forms),
             "host_syncs": self.host_syncs,
             "d2h_bytes": self.d2h_bytes,
             "h2d_bytes": self.h2d_bytes,

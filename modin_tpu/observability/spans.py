@@ -139,6 +139,20 @@ SPANS = (
         "attributes.  The dispatch that follows is an engine.deploy.attempt",
     ),
     (
+        "groupby.reduce",
+        "one device groupby aggregation (ops/groupby.py groupby_reduce): the "
+        "form chosen for it (masked_scan / sorted_tiles / segment / "
+        "pallas_bincount / scatter_counts), agg, num_groups and n_cols in "
+        "attributes; innermost QUERY-COMPILER span, so the compile ledger "
+        "bills the reduction's programs to it",
+    ),
+    (
+        "groupby.factorize",
+        "the histogram of an integer key's range in factorize_keys (the "
+        "codes' by-product that names the groups present): form and width "
+        "in attributes",
+    ),
+    (
         "opt.choose",
         "one graftopt joint strategy pass over an optimized plan: every "
         "node annotated with estimated rows/bytes/seconds and its chosen "

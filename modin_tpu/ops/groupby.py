@@ -43,6 +43,8 @@ ORDER_AGGS = {"median", "quantile", "nunique", "first", "last"}
 _RANGE_LIMIT = 1 << 22  # max direct-range width before falling back to unique
 
 
+from modin_tpu.observability import meters as _meters
+from modin_tpu.observability import spans as _spans
 from modin_tpu.parallel.engine import materialize as _engine_materialize
 from modin_tpu.parallel.engine import upload as _engine_upload
 from modin_tpu.ops._program import named_jit
@@ -84,9 +86,60 @@ def _jit_range_ids(n: int, width: int):
 
     def fn(k, kmin):
         valid = jnp.arange(k.shape[0]) < n
-        return jnp.where(valid, jnp.clip(k - kmin, 0, width), width)
+        # width <= _RANGE_LIMIT: the ids fit int32, half the bytes to hold and
+        # to read again in every pass over the codes
+        return jnp.where(valid, jnp.clip(k - kmin, 0, width), width).astype(jnp.int32)
 
     return named_jit(fn, "groupby_range_ids")
+
+
+class RangeCodes:
+    """The codes of an integer key whose range is dense, not written out: row
+    i's code is ``key[i] - kmin`` (pads and rows past ``n``: ``width``).
+
+    A request over 1e8 rows holds 0.4 GB less for it.  The sorted tiles, which
+    take rows a chunk at a time anyway, derive a chunk's codes from the key;
+    every other consumer asks :func:`codes_array`, which writes them out once.
+    """
+
+    __slots__ = ("key", "kmin", "width", "n", "_array")
+    dtype = np.dtype(np.int32)
+
+    def __init__(self, key: Any, kmin: int, width: int, n: int) -> None:
+        self.key, self.kmin, self.width, self.n = key, int(kmin), int(width), int(n)
+        self._array = None
+
+    @property
+    def shape(self) -> Tuple[int, ...]:
+        return self.key.shape
+
+    def devices(self):
+        return self.key.devices()
+
+    def operand(self) -> Tuple:
+        """What a program derives the codes from: the key and two scalars (no
+        recompile for another ``kmin`` or ``n``)."""
+        return (self.key, np.int64(self.kmin), np.int64(self.n))
+
+
+def _tiles_operand(codes: Any) -> Any:
+    """``codes`` as the sorted tiles take them: the array, or what to derive
+    them from a chunk at a time."""
+    return codes.operand() if isinstance(codes, RangeCodes) else codes
+
+
+def codes_array(codes: Any) -> Any:
+    """``codes`` as a device array (what ``factorize_keys`` returns may be a
+    :class:`RangeCodes`)."""
+    if not isinstance(codes, RangeCodes):
+        return codes
+    if codes._array is None:
+        import jax.numpy as jnp
+
+        codes._array = _jit_range_ids(codes.n, codes.width)(
+            codes.key, jnp.int64(codes.kmin)
+        )
+    return codes._array
 
 
 @functools.lru_cache(maxsize=None)
@@ -100,20 +153,44 @@ def _jit_scatter_counts(width: int):
     return named_jit(fn, "groupby_scatter_counts")
 
 
-def _count_ids(ids, width: int):
-    """Histogram of ids in [0, width); overflow id == width is dropped.
-
-    On TPU uses the pallas VPU kernel (XLA's scatter-add serializes there);
-    elsewhere the scatter path.
-    """
-    from modin_tpu.ops.pallas.groupby_kernels import (
-        bincount_supported,
-        pallas_bincount,
-    )
+def _histogram_form(ids, width: int) -> str:
+    """The device form of a histogram of ``ids`` in [0, width), read from the
+    width, the platform and the shard count.  An XLA scatter-add serialises on
+    a TPU (14.7 s at 1e8 rows), so there the Pallas one-hot kernel counts up to
+    its ``MAX_GROUPS`` ids and the sorted tiles (no value column) a wider range
+    of an unsharded key; the scatter is left with the CPU, where it is fine,
+    and with a wide range of a row-sharded key."""
+    from modin_tpu.ops.pallas.groupby_kernels import MAX_GROUPS, bincount_supported
+    from modin_tpu.parallel.mesh import num_row_shards
 
     if bincount_supported(ids, width):
-        return pallas_bincount(ids, width)
-    return _jit_scatter_counts(width)(ids)
+        return "pallas_bincount"
+    if width > MAX_GROUPS and _tpu_forms(ids) and num_row_shards() == 1:
+        return "sorted_tiles"
+    return "scatter_counts"
+
+
+def _histogram(ids, width: int, form: str):
+    """Device histogram of ids in [0, width) (int64); the overflow id
+    ``width`` and anything past it is dropped."""
+    if form == "sorted_tiles":
+        fn = _jit_sorted_tiles("size", 0, width + 1, width, False, _SORT_CHUNK)
+        return fn((), _tiles_operand(ids))
+    if form == "pallas_bincount":
+        from modin_tpu.ops.pallas.groupby_kernels import pallas_bincount
+
+        return pallas_bincount(codes_array(ids), width)
+    return _jit_scatter_counts(width)(codes_array(ids))
+
+
+def _count_ids(ids, width: int) -> np.ndarray:
+    """Host histogram of a key's range ids (or composite codes): the
+    factorisation's by-product that says which groups are present."""
+    form = _histogram_form(ids, width)
+    if _meters.ACCOUNTING_ON:
+        _meters.note_groupby_form(form)
+    with _spans.span("groupby.factorize", layer="QUERY-COMPILER", form=form, width=width):
+        return np.asarray(_engine_materialize(_histogram(ids, width, form)))
 
 
 @functools.lru_cache(maxsize=None)
@@ -125,7 +202,7 @@ def _jit_range_codes(n: int, n_groups: int):
         valid = jnp.arange(k.shape[0]) < n
         width = remap.shape[0]
         safe = jnp.where(valid, jnp.clip(k - kmin, 0, width - 1), 0)
-        return jnp.where(valid, jnp.take(remap, safe), n_groups)
+        return jnp.where(valid, jnp.take(remap, safe), n_groups).astype(jnp.int32)
 
     return named_jit(fn, "groupby_range_codes")
 
@@ -206,7 +283,9 @@ def factorize_keys(
 
     Returns (codes, num_groups, group_key_arrays_host, sizes): ``codes`` maps
     each row to [0, num_groups), with pads (and NaN keys when dropna) mapped
-    to ``num_groups``.  Group key values are host-side, sorted ascending
+    to ``num_groups``; the ``groupby_*`` functions of this module take them as
+    returned, anything else goes through :func:`codes_array` (a dense wide
+    range's codes come as :class:`RangeCodes`).  Group key values are host-side, sorted ascending
     (pandas sort=True order); a NaN group, when kept, is last.  ``sizes`` is
     a host int64 array of per-group row counts where the factorization
     computed one anyway (range/multi-key paths), else None — callers reuse it
@@ -223,8 +302,12 @@ def factorize_keys(
             kmin, kmax = (int(v) for v in _engine_materialize(_jit_key_minmax(n)(k64)))
             width = kmax - kmin + 1
             if width <= _RANGE_LIMIT:
-                ids = _jit_range_ids(n, width)(k64, jnp.int64(kmin))
-                counts = np.asarray(_engine_materialize(_count_ids(ids, width)))
+                # where the sorted tiles make the histogram the range ids are
+                # not written out (RangeCodes): the tiles read the key
+                ids = RangeCodes(k64, kmin, width, n)
+                if _histogram_form(k64, width) != "sorted_tiles":
+                    ids = codes_array(ids)
+                counts = _count_ids(ids, width)
                 present = np.nonzero(counts)[0]
                 if len(present) == width:
                     # every id of the range occurs: the remap would be the
@@ -275,14 +358,14 @@ def factorize_keys(
     n_groups_each = []
     for k in key_cols:
         codes_i, n_i, uniques_i, _sizes_i = factorize_keys([k], n, dropna=dropna)
-        level_codes.append(codes_i)
+        level_codes.append(codes_array(codes_i))
         level_uniques.append(uniques_i[0])
         n_groups_each.append(n_i)
     total = int(np.prod(n_groups_each))
     if total > _RANGE_LIMIT * 4:
         raise _TooManyGroups()
     composite = _jit_composite(tuple(n_groups_each), n, total)(tuple(level_codes))
-    counts = np.asarray(_engine_materialize(_jit_bincount(total)(composite)))
+    counts = _count_ids(composite, total)
     present = np.nonzero(counts)[0]
     if len(present) == total:
         # every combination of level codes occurs: identity remap, as above
@@ -341,20 +424,10 @@ def _jit_composite(n_groups_each: Tuple[int, ...], n: int, total: int):
         composite = jnp.zeros(level_codes[0].shape, jnp.int64)
         for codes_i, n_i in zip(level_codes, n_groups_each):
             composite = composite * n_i + jnp.minimum(codes_i, n_i - 1)
-        return jnp.where(in_range, composite, total)
+        # total <= 4 * _RANGE_LIMIT: int32 like a single key's range codes
+        return jnp.where(in_range, composite, total).astype(jnp.int32)
 
     return named_jit(fn, "groupby_composite")
-
-
-@functools.lru_cache(maxsize=None)
-def _jit_bincount(total: int):
-    import jax
-    import jax.numpy as jnp
-
-    def fn(composite):
-        return jnp.zeros(total + 1, jnp.int64).at[composite].add(1)[:total]
-
-    return named_jit(fn, "groupby_bincount")
 
 
 @functools.lru_cache(maxsize=None)
@@ -363,7 +436,7 @@ def _jit_remap(n_present: int):
     import jax.numpy as jnp
 
     def fn(composite, remap):
-        return jnp.take(remap, composite)
+        return jnp.take(remap, composite).astype(jnp.int32)
 
     return named_jit(fn, "groupby_remap")
 
@@ -516,30 +589,32 @@ def _jit_pad_to(p_out: int):
     return named_jit(fn, "groupby_pad_to")
 
 
-@functools.lru_cache(maxsize=None)
-def _jit_segment_size(num_segments: int, p_out: int):
-    import jax
-    import jax.numpy as jnp
-
-    n_groups = num_segments - 1
-
-    def fn(codes):
-        r = jax.ops.segment_sum(
-            jnp.ones(codes.shape, jnp.int64), codes, num_segments=num_segments
-        )[:n_groups]
-        if p_out > n_groups:
-            r = jnp.concatenate([r, jnp.zeros(p_out - n_groups, r.dtype)])
-        return r
-
-    return named_jit(fn, "groupby_segment_size")
-
-
-# Above this many groups the masked-scan kernel's O(n*G) work loses to the
-# scatter-based segment ops; below it, the scan avoids TPU's slow scatters
-# (measured: segment_sum ~1s vs masked reduce ~50ms at 1e7 rows, G=101).
+# The three forms of a segment reduction, by group count (chip readings in
+# PERF.md): up to _MASKED_SCAN_MAX_GROUPS the masked scan's one-hot spans every
+# group at once (O(n*G) on the VPU, no scatter); above it, up to _RANGE_LIMIT,
+# sum/mean/count take the sorted-tiles form below (sort row chunks by code,
+# one-hot each run of sorted rows against the few consecutive codes it holds);
+# XLA's scatter-based segment ops, which serialise on a TPU (146 ns a row and
+# 64-bit column at 1e8 rows), are left with min/max/prod/any/all/var/std/sem
+# above the masked scan's limit, with row-sharded operands, and with the CPU,
+# where scatters are fine.
 _MASKED_SCAN_MAX_GROUPS = 1024
 _SCAN_CHUNK = 65536
-_FORCE_KERNEL = None  # test hook: "masked_scan" | "segment" | None
+# sorted tiles: rows sorted at a time, and consecutive codes the one-hot of a
+# block of sorted rows spans (rows a block: _tile_rows)
+_SORT_CHUNK = 1 << 22
+_TILE_IDS = 512
+# test hook: "tpu" (what a TPU would choose, on any platform) | "masked_scan"
+# (the same, older name) | "segment" | None
+_FORCE_KERNEL = None
+
+
+def _tpu_forms(arr) -> bool:
+    """Whether the scatter-free forms are chosen for ``arr``: its platform is a
+    TPU (or the test hook says so)."""
+    if _FORCE_KERNEL is not None:
+        return _FORCE_KERNEL != "segment"
+    return next(iter(arr.devices())).platform == "tpu"
 
 
 @functools.lru_cache(maxsize=None)
@@ -843,6 +918,197 @@ def _jit_masked_scan_smc(
     return named_jit(fn, "groupby_masked_scan_smc")
 
 
+def _tile_rows(chunk: int, num_groups: int) -> int:
+    """Sorted rows a block: as many as ``_TILE_IDS`` consecutive codes hold in
+    a chunk of an evenly spread key (``chunk / num_groups`` rows a code),
+    rounded down to a power of two between 256 and 4096, so that a block's
+    first tile spans it or nearly (a second tile for the last few codes costs
+    less than twice the blocks would).  A key that is not spread evenly needs
+    fewer tiles a row where it is dense and further tiles where it is sparse."""
+    rows = max(_TILE_IDS * chunk // max(num_groups, 1), 1)
+    return min(max(1 << rows.bit_length() - 1, 256), 4096)
+
+
+@functools.lru_cache(maxsize=None)
+def _jit_sorted_tiles(
+    agg: str,
+    n_cols: int,
+    num_segments: int,
+    p_out: int,
+    has_sizes: bool,
+    chunk: int,
+):
+    """sum/mean/count (and ``size``: the histogram, no value column) for many
+    groups, with neither scatter nor per-row gather.
+
+    Rows are taken ``chunk`` at a time and sorted by code, the value columns
+    travelling with their key (one variadic ``lax.sort``).  Sorted rows are cut
+    into blocks of ``_tile_rows``; a block's codes ascend from its first, so a
+    one-hot against ``first + arange(_TILE_IDS)`` reduces the block into
+    ``_TILE_IDS`` partial sums (the masked scan's ``where(oh, x, 0).sum``), for
+    every block of the chunk at once.  The partials are then added into the
+    ``[G]`` accumulators at offset ``first``, one contiguous
+    ``dynamic_update_slice`` a block.  A block whose codes reach past its first
+    tile (a sparse stretch of the key) takes further tiles afterwards, each
+    starting at its next code not yet summed, so a chunk costs at most
+    ``rows / _tile_rows + G / _TILE_IDS`` tiles whatever the key's shape.
+
+    Sums are exact for integers and accumulated in the column's own float
+    width, NaN skipped, as in the other forms; only the order of a group's
+    float additions differs.  Pad and dropped rows carry the overflow code
+    (``factorize_keys``) and land in its bucket, which is sliced off.
+    """
+    import jax
+    import jax.lax as lax
+    import jax.numpy as jnp
+
+    n_groups = num_segments - 1  # also the code of pad and dropped rows
+    T = _TILE_IDS
+    acc_len = num_segments + T  # a tile written at offset n_groups still fits
+    need_sum = agg in ("sum", "mean")
+    need_cnt = agg in ("mean", "count")
+
+    def fn(cols: Tuple, codes, sizes_in=None):
+        # ``codes``: the array, or (key, kmin, n) of a dense range (RangeCodes)
+        key, kmin, n = codes if isinstance(codes, tuple) else (None, None, None)
+        P = (codes if key is None else key).shape[0]
+        take = min(int(chunk), P)  # rows sliced a step
+        steps = -(-P // take)
+        W = _tile_rows(take, n_groups)
+        cp = -(-take // W) * W  # rows sorted a step
+        B = cp // W
+        is_float = [bool(jnp.issubdtype(c.dtype, jnp.floating)) for c in cols]
+        # which one-hot sums a tile makes: a sum a column, a valid count a
+        # float column (NaN skipped), and one shared row count for the integer
+        # columns and ``size`` unless the factorisation brought it
+        col_cnt = [need_cnt and f for f in is_float]
+        shared_cnt = agg == "size" or (
+            need_cnt and not has_sizes and not all(is_float)
+        )
+        # the columns that travel with the codes (a count reads only floats)
+        moved = [i for i in range(n_cols) if need_sum or col_cnt[i]]
+        tid = jnp.arange(T, dtype=jnp.int32)
+
+        def tile(s, xs, first):
+            """Partial sums of blocks ``s`` ([b, W] sorted codes, ``xs`` their
+            values) against the codes ``first[:, None] + arange(T)``."""
+            oh = (s - first[:, None])[:, :, None] == tid[None, None, :]
+            out = []
+            for i, x in zip(moved, xs):
+                nanm = jnp.isnan(x) if is_float[i] else None
+                if need_sum:
+                    xz = jnp.where(nanm, 0, x) if is_float[i] else x
+                    out.append(jnp.sum(jnp.where(oh, xz[:, :, None], 0), axis=1))
+                if col_cnt[i]:
+                    out.append(
+                        jnp.sum(oh & ~nanm[:, :, None], axis=1, dtype=jnp.int32)
+                    )
+            if shared_cnt:
+                out.append(jnp.sum(oh, axis=1, dtype=jnp.int32))
+            return out
+
+        def add_at(accs, offset, parts):
+            return [
+                lax.dynamic_update_slice(
+                    a, lax.dynamic_slice(a, (offset,), (T,)) + p.astype(a.dtype), (offset,)
+                )
+                for a, p in zip(accs, parts)
+            ]
+
+        def step(i, accs):
+            start = jnp.minimum(i * take, P - take)
+            pos = start + jnp.arange(take)
+            # the last step reaches back over rows the one before has summed
+            live = pos >= i * take
+            with jax.named_scope("chunk_sort"):
+                if key is None:
+                    cc = lax.dynamic_slice(codes, (start,), (take,))
+                else:
+                    live &= pos < n
+                    kc = lax.dynamic_slice(key, (start,), (take,)).astype(jnp.int64)
+                    cc = jnp.clip(kc - kmin, 0, n_groups)
+                cc = jnp.where(live, cc.astype(jnp.int32), n_groups)
+                xs = [lax.dynamic_slice(cols[i], (start,), (take,)) for i in moved]
+                if cp > take:
+                    cc = jnp.concatenate([cc, jnp.full(cp - take, n_groups, jnp.int32)])
+                    xs = [jnp.concatenate([x, jnp.zeros(cp - take, x.dtype)]) for x in xs]
+                s, *xs = lax.sort((cc, *xs), num_keys=1, is_stable=False)
+                s = s.reshape(B, W)
+                xs = [x.reshape(B, W) for x in xs]
+            first, last = s[:, 0], s[:, -1]
+            with jax.named_scope("tile_reduce"):
+                parts = tile(s, xs, first)
+            with jax.named_scope("tile_place"):
+                accs = lax.fori_loop(
+                    0, B,
+                    lambda b, accs: add_at(accs, first[b], [p[b] for p in parts]),
+                    accs, unroll=4,
+                )
+
+            # the blocks whose codes reach past their first tile, in turn: each
+            # takes tiles from its next code not yet summed until its last
+            spills = last - first >= T
+            n_spills = jnp.sum(spills, dtype=jnp.int32)
+            turn = jnp.argsort(~spills, stable=True).astype(jnp.int32)
+
+            def further(state):
+                k, done, accs = state
+                b = turn[k]
+                sb = lax.dynamic_slice(s, (b, jnp.int32(0)), (1, W))
+                xb = [lax.dynamic_slice(x, (b, jnp.int32(0)), (1, W)) for x in xs]
+                nxt = jnp.min(jnp.where(sb >= done, sb, n_groups))
+                accs = add_at(accs, nxt, [p[0] for p in tile(sb, xb, nxt[None])])
+                over = nxt + T > last[b]
+                after = turn[jnp.minimum(k + 1, B - 1)]
+                return (
+                    jnp.where(over, k + 1, k),
+                    jnp.where(over, first[after] + T, nxt + T),
+                    accs,
+                )
+
+            with jax.named_scope("tile_spill"):
+                return lax.while_loop(
+                    lambda state: state[0] < n_spills,
+                    further,
+                    (jnp.int32(0), first[turn[0]] + T, accs),
+                )[2]
+
+        init = []
+        for i in moved:
+            if need_sum:
+                init.append(jnp.zeros(acc_len, cols[i].dtype))
+            if col_cnt[i]:
+                init.append(jnp.zeros(acc_len, jnp.int64))
+        if shared_cnt:
+            init.append(jnp.zeros(acc_len, jnp.int64))
+        accs = lax.fori_loop(0, steps, step, init) if init else []
+        accs = [a[:num_segments] for a in accs]
+
+        if agg == "size":
+            return _slice_pad(accs[0], n_groups, p_out)
+        sizes = sizes_in if has_sizes else (accs[-1] if shared_cnt else None)
+        out = []
+        k = 0
+        for i in range(n_cols):
+            total = cnt = None
+            if need_sum:
+                total = accs[k]; k += 1
+            if col_cnt[i]:
+                cnt = accs[k]; k += 1
+            elif need_cnt:
+                cnt = sizes
+            if agg == "sum":
+                r = total
+            elif agg == "count":
+                r = cnt.astype(jnp.int64)
+            else:  # mean: divide in the sum's dtype so f32 means stay f32
+                r = total / cnt.astype(total.dtype)
+            out.append(_slice_pad(r, n_groups, p_out))
+        return tuple(out)
+
+    return named_jit(fn, "groupby_sorted_tiles_" + agg)
+
+
 # read from inside jitted bodies (masked-scan min/max neutrals): immutable so
 # tracing can't bake in contents that a later mutation would silently miss
 _INT_KINDS = ("int8", "int16", "int32", "int64", "uint8", "uint16", "uint32", "uint64")
@@ -871,7 +1137,7 @@ def groupby_first_position(codes: Any, num_groups: int) -> Any:
 
     Pad rows carry the overflow code, so they land in the sliced-off bucket.
     """
-    return _jit_first_position(num_groups + 1)(codes)[:num_groups]
+    return _jit_first_position(num_groups + 1)(codes_array(codes))[:num_groups]
 
 
 def groupby_reduce(
@@ -896,6 +1162,7 @@ def groupby_reduce(
 
     from modin_tpu.observability import costs as _costs
     from modin_tpu.ops.structural import pad_host, pad_len
+    from modin_tpu.parallel.mesh import num_row_shards
 
     ns = num_groups + 1
     p_out = pad_len(num_groups)
@@ -917,64 +1184,69 @@ def groupby_reduce(
             out_width * max(ns, p_out) * 8,
             out_width * num_groups * 8,
         )
-    if agg == "size":
-        if sizes is not None:
-            return [_engine_upload(pad_host(np.asarray(sizes, np.int64), num_groups))]
-        from modin_tpu.ops.pallas.groupby_kernels import (
-            bincount_supported,
-            pallas_bincount,
-        )
-
-        if bincount_supported(codes, num_groups):
-            counts = pallas_bincount(codes, num_groups)
-            return [_jit_pad_to(p_out)(counts)]
-        return [_jit_segment_size(ns, p_out)(codes)]
-    on_tpu = next(iter(codes.devices())).platform == "tpu"
-    if _FORCE_KERNEL == "masked_scan":
-        on_tpu = True
-    elif _FORCE_KERNEL == "segment":
-        on_tpu = False
-    use_masked_scan = (
-        on_tpu
-        and num_groups <= _MASKED_SCAN_MAX_GROUPS
-        # var/std/sem need the two-pass centered form -> segment path
-        and agg in ("sum", "count", "mean", "min", "max", "prod", "any", "all")
-    )
-    from modin_tpu.parallel.mesh import num_row_shards
-
-    if use_masked_scan:
-        # TPU scatters serialize badly; the masked scan keeps the work on the VPU
-        if agg in ("sum", "mean", "count"):
-            scan_adaptive = num_row_shards() == 1
-            scan_has_sizes = sizes is not None and agg in ("mean", "count")
-            fn = _jit_masked_scan_smc(
-                agg, len(value_cols), ns, p_out, _SCAN_CHUNK,
-                scan_adaptive, scan_has_sizes,
-            )
-            if scan_has_sizes:
-                sizes_dev = _engine_upload(
-                    np.append(np.asarray(sizes, np.int64), 1)
+    form = _reduce_form(agg, codes, num_groups, sizes)
+    if _meters.ACCOUNTING_ON and form != "host_sizes":
+        _meters.note_groupby_form(form)
+    with _spans.span(
+        "groupby.reduce", layer="QUERY-COMPILER", form=form, agg=agg,
+        num_groups=num_groups, n_cols=len(value_cols),
+    ):
+        if agg == "size":
+            if sizes is not None:
+                return [_engine_upload(pad_host(np.asarray(sizes, np.int64), num_groups))]
+            return [_jit_pad_to(p_out)(_histogram(codes, num_groups, form))]
+        single = num_row_shards() == 1
+        # only the sorted tiles take codes that were not written out
+        codes = _tiles_operand(codes) if form == "sorted_tiles" else codes_array(codes)
+        if form == "masked_scan":
+            if agg not in ("sum", "mean", "count"):
+                fn = _jit_masked_scan_agg(
+                    agg, len(value_cols), ns, int(ddof), p_out, _SCAN_CHUNK
                 )
-                return list(fn(tuple(value_cols), codes, sizes_dev))
-            return list(fn(tuple(value_cols), codes))
-        fn = _jit_masked_scan_agg(agg, len(value_cols), ns, int(ddof), p_out, _SCAN_CHUNK)
+                return list(fn(tuple(value_cols), codes))
+            has_sizes = sizes is not None and agg in ("mean", "count")
+            fn = _jit_masked_scan_smc(
+                agg, len(value_cols), ns, p_out, _SCAN_CHUNK, single, has_sizes
+            )
+        elif form == "sorted_tiles":
+            has_sizes = sizes is not None and agg in ("mean", "count")
+            fn = _jit_sorted_tiles(
+                agg, len(value_cols), ns, p_out, has_sizes, _SORT_CHUNK
+            )
+        else:
+            has_sizes = single and sizes is not None and agg in ("sum", "mean", "count")
+            fn = _jit_segment_agg(
+                agg, len(value_cols), ns, int(ddof), p_out, single, has_sizes
+            )
+        if has_sizes:
+            # the factorisation's row counts, the denominator of a column that
+            # holds no NaN: ns slots like the in-kernel histogram (the overflow
+            # bucket's value is sliced off, 1 avoids a 0-divide)
+            sizes_dev = _engine_upload(np.append(np.asarray(sizes, np.int64), 1))
+            return list(fn(tuple(value_cols), codes, sizes_dev))
         return list(fn(tuple(value_cols), codes))
 
-    adaptive = num_row_shards() == 1
-    has_sizes = (
-        adaptive and sizes is not None and agg in ("sum", "mean", "count")
-    )
-    fn = _jit_segment_agg(
-        agg, len(value_cols), ns, int(ddof), p_out, adaptive, has_sizes
-    )
-    if has_sizes:
-        # operand layout matches the in-kernel histogram: ns slots with an
-        # overflow bucket (its value is sliced off, 1 avoids a 0-divide)
-        sizes_dev = _engine_upload(
-            np.append(np.asarray(sizes, np.int64), 1)
-        )
-        return list(fn(tuple(value_cols), codes, sizes_dev))
-    return list(fn(tuple(value_cols), codes))
+
+def _reduce_form(agg: str, codes: Any, num_groups: int, sizes: Any) -> str:
+    """The device form of one aggregation, read from the aggregation, the
+    group count, the platform and the shard count (no option): see the note
+    above ``_MASKED_SCAN_MAX_GROUPS``.  min/max/prod/any/all above the masked
+    scan's limit, var/std/sem (two-pass, centred), and everything on a
+    row-sharded mesh above that limit keep the scatter-based segment ops."""
+    from modin_tpu.parallel.mesh import num_row_shards
+
+    if agg == "size":
+        return "host_sizes" if sizes is not None else _histogram_form(codes, num_groups)
+    if _tpu_forms(codes) and agg not in ("var", "std", "sem"):
+        if num_groups <= _MASKED_SCAN_MAX_GROUPS:
+            return "masked_scan"
+        if (
+            agg in ("sum", "mean", "count")
+            and num_groups <= _RANGE_LIMIT
+            and num_row_shards() == 1
+        ):
+            return "sorted_tiles"
+    return "segment"
 
 
 # ---------------------------------------------------------------------- #
@@ -1072,7 +1344,7 @@ def groupby_quantile(
         len(value_cols), num_groups + 1, pad_len(num_groups), float(q),
         str(interpolation), bool(preserve_float_dtype),
     )
-    return list(fn(tuple(value_cols), codes))
+    return list(fn(tuple(value_cols), codes_array(codes)))
 
 
 @functools.lru_cache(maxsize=None)
@@ -1120,7 +1392,7 @@ def groupby_nunique(
     fn = _jit_group_nunique(
         len(value_cols), num_groups + 1, pad_len(num_groups), bool(dropna)
     )
-    return list(fn(tuple(value_cols), codes))
+    return list(fn(tuple(value_cols), codes_array(codes)))
 
 
 @functools.lru_cache(maxsize=None)
@@ -1166,7 +1438,7 @@ def groupby_first_last(
     fn = _jit_group_first_last(
         agg == "last", len(value_cols), num_groups + 1, pad_len(num_groups)
     )
-    return list(fn(tuple(value_cols), codes))
+    return list(fn(tuple(value_cols), codes_array(codes)))
 
 
 @functools.lru_cache(maxsize=None)
@@ -1187,7 +1459,9 @@ def _jit_broadcast_groups(n_cols: int):
 
 def groupby_broadcast(agg_cols: List[Any], codes: Any) -> List[Any]:
     """Row-shaped device arrays where row i holds its group's aggregate."""
-    return list(_jit_broadcast_groups(len(agg_cols))(tuple(agg_cols), codes))
+    return list(
+        _jit_broadcast_groups(len(agg_cols))(tuple(agg_cols), codes_array(codes))
+    )
 
 
 # row-shaped cumulative aggregations (segmented scan)
@@ -1241,7 +1515,7 @@ def _jit_grouped_cum(op: str, n_cols: int):
 def groupby_cumulative(op: str, value_cols: List[Any], codes: Any) -> List[Any]:
     """Row-shaped grouped cumsum/cumprod/cummax/cummin."""
     fn = _jit_grouped_cum(op, len(value_cols))
-    return list(fn(tuple(value_cols), codes))
+    return list(fn(tuple(value_cols), codes_array(codes)))
 
 
 # ---------------------------------------------------------------------- #

@@ -16,9 +16,11 @@ described-chip entry cannot be read back without a chip).  Code that asks
 ``jax.default_backend()`` sees the CPU here, so the jitted builders are
 lowered directly over ``ShapeDtypeStruct``s.
 
-Only the fast compiles live here (each a second or two).  The 1e8-row
-lexsort (about 70 s) and the ewm blocked scan (minutes) were compiled once
-by hand for PR 22; their numbers are in PERF.md.
+Only the fast compiles live here (each a second or two; the many-groups
+histogram, which sorts, about five).  The 1e8-row lexsort (about 70 s) and
+the ewm blocked scan (minutes) were compiled once by hand for PR 22, the
+sorted-tiles sums (25 s a 64-bit column here, 12 s on the chip's host) for
+PR 31; their numbers are in PERF.md.
 """
 
 import os
@@ -75,6 +77,10 @@ def _lower(kernel: str, arg, shape):
     codes = shape((ROWS,), np.int64)
     if kernel == "bincount":  # arg: number of groups
         return _jit_bincount_wrapper(ROWS, arg, False).lower(codes)
+    if kernel == "sorted_tiles_size":  # arg: number of groups; the codes come from the key
+        return groupby._jit_sorted_tiles(
+            "size", 0, arg + 1, arg, False, groupby._SORT_CHUNK
+        ).lower((), (codes, np.int64(1), np.int64(ROWS)))
     chunk = groupby._SCAN_CHUNK
     fn = {  # arg: the aggregation
         "masked_scan_smc": lambda: groupby._jit_masked_scan_smc(
@@ -101,6 +107,7 @@ def _lower(kernel: str, arg, shape):
         ("masked_scan_agg", "min"),
         ("segment_agg", "sum"),
         ("segment_agg", "var"),
+        ("sorted_tiles_size", 1_000_000),
     ],
 )
 def test_kernel_compiles_for_v5e_at_1e8_rows(kernel, arg, shapes):
@@ -114,6 +121,11 @@ def test_kernel_compiles_for_v5e_at_1e8_rows(kernel, arg, shapes):
     if kernel == "bincount":
         # the Mosaic kernel is really in the program, not an XLA scatter
         assert "tpu_custom_call" in lowered.as_text()
+    elif kernel == "sorted_tiles_size":
+        # chunks are sorted and nothing scatters
+        text = compiled.as_text()
+        assert " sort(" in text and " scatter(" not in text
+        assert mem.argument_size_in_bytes >= ROWS * 8
     else:
         # the 4.8 GB frame is the argument: a compile that dropped the
         # int64 columns (or x64) would show here

@@ -335,7 +335,8 @@ def test_range_codes_skip_identity_remap(case):
     want_uniques, want_codes, want_sizes = np.unique(
         stacked, axis=0, return_inverse=True, return_counts=True
     )
-    assert codes.dtype == jnp.int64 and codes.shape == (padded,)
+    # range codes fit int32 (the range is at most 4 Mi wide, a product 16 Mi)
+    assert codes.dtype == jnp.int32 and codes.shape == (padded,)
     np.testing.assert_array_equal(
         np.asarray(codes),
         np.concatenate([want_codes.ravel(), np.full(padded - n, n_groups)]),
@@ -820,3 +821,283 @@ class TestShuffleGroupbyApplyWidened:
             md, pdf,
             lambda df: df.groupby("fk", dropna=False).apply(lambda g: g["v"].sum()),
         )
+
+
+# ---------------------------------------------------------------------- #
+# many groups: the sorted-tiles form (what a TPU takes above the masked
+# scan's 1024 groups), forced on the CPU through the test hook
+# ---------------------------------------------------------------------- #
+
+_MANY_SHAPES = [
+    "dense_range", "range_with_holes", "two_keys", "nan_key_kept",
+    "half_the_rows_one_group", "length_no_multiple_of_the_chunk",
+]
+_MANY_DTYPES = ["int64", "float64_nan", "float32", "bool"]
+# a chunk this small makes a frame many chunks, each sparse in the key: most
+# blocks take further tiles, and the last chunk reaches back over the one before
+_SMALL_CHUNK = 1 << 13
+
+
+class _one_shard_tpu_choice:
+    """A one-shard mesh and the forms a TPU would choose (or ``force``)."""
+
+    def __init__(self, force="tpu", chunk=None):
+        self.force, self.chunk = force, chunk
+
+    def __enter__(self):
+        from modin_tpu.config import MeshShape
+        from modin_tpu.ops import groupby as gb_ops
+        from modin_tpu.parallel.mesh import reset_mesh
+
+        self.was = (gb_ops._FORCE_KERNEL, gb_ops._SORT_CHUNK, MeshShape.get())
+        gb_ops._FORCE_KERNEL = self.force
+        if self.chunk:
+            gb_ops._SORT_CHUNK = self.chunk
+        MeshShape.put((1, 1))
+        reset_mesh()
+
+    def __exit__(self, *exc):
+        from modin_tpu.config import MeshShape
+        from modin_tpu.ops import groupby as gb_ops
+        from modin_tpu.parallel.mesh import reset_mesh
+
+        gb_ops._FORCE_KERNEL, gb_ops._SORT_CHUNK, shape = self.was
+        MeshShape.put(shape)
+        reset_mesh()
+
+
+def _many_groups_frame(groups, shape):
+    rng = np.random.default_rng([groups, _MANY_SHAPES.index(shape)])
+    n = 2 * groups + 11
+    ids = np.concatenate([np.arange(groups), rng.integers(0, groups, n - groups)])
+    rng.shuffle(ids)
+    keys = {"k": ids}
+    if shape == "range_with_holes":
+        keys = {"k": ids * 10 - 3}
+    elif shape == "two_keys":
+        side = int(groups**0.5)
+        keys = {"k": ids % side, "k2": ids // side}
+    elif shape == "nan_key_kept":
+        keys = {"k": np.where(ids == 5, np.nan, ids * 0.5)}
+    elif shape == "half_the_rows_one_group":
+        keys = {"k": np.where(np.arange(n) % 2 == 0, 7, ids)}
+    values = {
+        "int64": rng.integers(-50, 50, n),
+        "float64_nan": np.where(rng.random(n) < 0.1, np.nan, rng.uniform(-1, 1, n)),
+        "float32": rng.uniform(-1, 1, n).astype(np.float32),
+        "bool": rng.random(n) < 0.5,
+    }
+    return pandas.DataFrame({**keys, **values}), list(keys)
+
+
+_many_groups_answers = {}
+
+
+def _many_groups_answer(groups, shape, agg):
+    """pandas', the sorted tiles' and the segment form's answer to one
+    aggregation of all four value columns, and the forms the first took."""
+    import modin_tpu.observability as graftscope
+    from modin_tpu.ops.groupby import clear_factorize_cache
+    from modin_tpu.views import registry
+
+    case = (groups, shape, agg)
+    if case not in _many_groups_answers:
+        pdf, by = _many_groups_frame(groups, shape)
+        dropna = shape != "nan_key_kept"
+        want = getattr(pdf.groupby(by, dropna=dropna), agg)()
+        # (the CPU materialises a whole chunk's one-hot: a frame of 70 000
+        # groups goes in four chunks, whatever its shape)
+        chunk = _SMALL_CHUNK if shape in _MANY_SHAPES[-2:] else (1 << 15 if groups > 2_000 else None)
+        got = {}
+        for force in ("tpu", "segment"):
+            with _one_shard_tpu_choice(force, chunk):
+                md = pd.DataFrame(pdf)
+                registry.reset()
+                clear_factorize_cache()
+                with graftscope.query_stats("many-groups") as stats:
+                    answer = assert_no_fallback(
+                        lambda: getattr(md.groupby(by, dropna=dropna), agg)()
+                    )
+                    got[force] = answer.modin.to_pandas()
+                got[force + "_forms"] = dict(stats.groupby_forms)
+        _many_groups_answers[case] = (want, got)
+    return _many_groups_answers[case]
+
+
+@pytest.mark.parametrize("dtype", _MANY_DTYPES)
+@pytest.mark.parametrize("shape", _MANY_SHAPES)
+@pytest.mark.parametrize("groups", [2_000, 70_000])
+@pytest.mark.parametrize("agg", ["sum", "mean", "count"])
+def test_sorted_tiles_match_pandas_and_the_segment_form(agg, groups, shape, dtype):
+    from modin_tpu.utils import get_current_execution
+
+    if get_current_execution() != "TpuOnJax":
+        pytest.skip("device kernels")
+    want, got = _many_groups_answer(groups, shape, agg)
+    # every reduction took the new form; the histogram too where the key is an
+    # integer range (a float key is factorised by a sort, without a histogram)
+    assert got["tpu_forms"].get("sorted_tiles", 0) >= 1 and "segment" not in got["tpu_forms"]
+    # (the two levels of two keys are narrow ranges: the Pallas kernel's on a
+    # TPU, the scatter's here; their product's histogram is the wide one)
+    assert got["tpu_forms"].get("scatter_counts", 0) == (2 if shape == "two_keys" else 0)
+    assert got["segment_forms"].get("segment", 0) >= 1
+    tiles, segment = got["tpu"][dtype], got["segment"][dtype]
+    assert tiles.index.equals(want.index) and tiles.dtype == want[dtype].dtype == segment.dtype
+    if tiles.dtype.kind in "iu":
+        np.testing.assert_array_equal(tiles.to_numpy(), want[dtype].to_numpy())
+        np.testing.assert_array_equal(tiles.to_numpy(), segment.to_numpy())
+    else:
+        tol = 1e-12 if tiles.dtype == np.float64 else 2e-5
+        np.testing.assert_allclose(tiles.to_numpy(), want[dtype].to_numpy(), rtol=tol, atol=tol)
+        np.testing.assert_allclose(tiles.to_numpy(), segment.to_numpy(), rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize(
+    "n,width,chunk",
+    [(777, 513, None), (50_000, 3_000, None), (123_457, 70_000, 1 << 14), (40_000, 4_000_000, None)],
+)
+def test_sorted_tiles_histogram_matches_bincount(n, width, chunk):
+    """The histogram of a range wider than the Pallas kernel's: ids past the
+    range (pads, dropped keys) count for nothing."""
+    import jax.numpy as jnp
+
+    from modin_tpu.ops import groupby as gb_ops
+
+    rng = np.random.default_rng(n)
+    ids_np = rng.integers(0, width + 1, n).astype(np.int32)
+    ids_np[: n // 3] = width // 2  # a third of the rows one id
+    with _one_shard_tpu_choice("tpu", chunk):
+        ids = jnp.asarray(ids_np)
+        assert gb_ops._histogram_form(ids, width) == "sorted_tiles"
+        got = np.asarray(gb_ops._histogram(ids, width, "sorted_tiles"))
+    assert got.dtype == np.int64
+    np.testing.assert_array_equal(got, np.bincount(ids_np, minlength=width + 1)[:width])
+
+
+def test_groupby_form_reads_group_count_platform_and_shards():
+    """No option: the form of a reduction follows from the aggregation, the
+    group count, the platform and the shard count."""
+    import jax.numpy as jnp
+
+    from modin_tpu.ops import groupby as gb_ops
+
+    codes = jnp.zeros(8, jnp.int32)
+    # on the CPU the scatter forms stay
+    assert gb_ops._reduce_form("sum", codes, 100, None) == "segment"
+    assert gb_ops._reduce_form("sum", codes, 70_000, None) == "segment"
+    assert gb_ops._histogram_form(codes, 70_000) == "scatter_counts"
+    with _one_shard_tpu_choice("tpu"):
+        limit = gb_ops._MASKED_SCAN_MAX_GROUPS
+        assert limit == 1024
+        for agg in ("sum", "mean", "count"):
+            assert gb_ops._reduce_form(agg, codes, limit, None) == "masked_scan"
+            assert gb_ops._reduce_form(agg, codes, limit + 1, None) == "sorted_tiles"
+            assert gb_ops._reduce_form(agg, codes, gb_ops._RANGE_LIMIT, None) == "sorted_tiles"
+            assert gb_ops._reduce_form(agg, codes, gb_ops._RANGE_LIMIT + 1, None) == "segment"
+        for agg in ("min", "max", "prod", "any", "all"):
+            assert gb_ops._reduce_form(agg, codes, limit, None) == "masked_scan"
+            assert gb_ops._reduce_form(agg, codes, limit + 1, None) == "segment"
+        for agg in ("var", "std", "sem"):
+            assert gb_ops._reduce_form(agg, codes, 100, None) == "segment"
+        assert gb_ops._reduce_form("size", codes, limit + 1, np.ones(limit + 1)) == "host_sizes"
+        assert gb_ops._reduce_form("size", codes, limit + 1, None) == "sorted_tiles"
+        assert gb_ops._histogram_form(codes, 512) == "scatter_counts"  # the Pallas kernel's, on a TPU
+        assert gb_ops._histogram_form(codes, 513) == "sorted_tiles"
+    with _one_shard_tpu_choice("segment"):
+        assert gb_ops._reduce_form("sum", codes, 100, None) == "segment"
+    # a row-sharded mesh keeps the segment ops above the masked scan's limit
+    from modin_tpu.config import MeshShape
+    from modin_tpu.parallel.mesh import num_row_shards, reset_mesh
+
+    with _one_shard_tpu_choice("tpu"):
+        MeshShape.put((4, 1))
+        reset_mesh()
+        assert num_row_shards() == 4
+        assert gb_ops._reduce_form("sum", codes, 1024, None) == "masked_scan"
+        assert gb_ops._reduce_form("sum", codes, 1025, None) == "segment"
+        assert gb_ops._histogram_form(codes, 70_000) == "scatter_counts"
+
+
+def test_query_stats_record_holds_groupby_forms_and_spans():
+    """``df.groupby(k).sum()``: the request's record counts the form of the
+    histogram and of the reduction, and both run under a QUERY-COMPILER span
+    that says which form, of how many groups."""
+    import modin_tpu.observability as graftscope
+    from modin_tpu.ops.groupby import clear_factorize_cache
+    from modin_tpu.utils import get_current_execution
+    from modin_tpu.views import registry
+
+    if get_current_execution() != "TpuOnJax":
+        pytest.skip("device kernels")
+    pdf, _ = _many_groups_frame(2_000, "dense_range")
+    pdf = pdf[["k", "int64", "float64_nan"]]
+    for force, forms in (
+        ("tpu", {"sorted_tiles": 2}),
+        (None, {"scatter_counts": 1, "segment": 1}),
+    ):
+        with _one_shard_tpu_choice(force):
+            md = pd.DataFrame(pdf)
+            md._query_compiler.execute()
+            registry.reset()
+            clear_factorize_cache()
+            with graftscope.profile() as prof, graftscope.query_stats("forms") as stats:
+                got = assert_no_fallback(lambda: md.groupby("k").sum())
+                got._query_compiler.execute()
+        df_equals(got, pdf.groupby("k").sum())
+        assert stats.groupby_forms == forms
+        record = graftscope.recent_queries("forms")[-1]
+        assert record["groupby_forms"] == forms
+        spans = {sp.name: sp for sp in prof.spans if sp.name.startswith("groupby.")}
+        assert set(spans) == {"groupby.factorize", "groupby.reduce"}
+        assert all(sp.layer == "QUERY-COMPILER" for sp in spans.values())
+        reduce_form = "sorted_tiles" if force else "segment"
+        # (a span under which a program compiled also carries ``compile_s``)
+        assert spans["groupby.reduce"].attrs.items() >= {
+            "form": reduce_form, "agg": "sum", "num_groups": 2_000, "n_cols": 2,
+        }.items()
+        assert spans["groupby.factorize"].attrs.items() >= {
+            "form": "sorted_tiles" if force else "scatter_counts", "width": 2_000,
+        }.items()
+        programs = stats.launches_by_program
+        assert ("groupby_sorted_tiles_sum" in programs) == bool(force)
+        # a dense wide range's codes are not written out for the sorted tiles
+        assert ("groupby_range_ids" in programs) == (not force)
+        assert ("groupby_segment_agg" in programs) == (not force)
+
+
+@pytest.mark.parametrize(
+    "ask",
+    [
+        lambda g: g.median(),
+        lambda g: g.nunique(),
+        lambda g: g.first(),
+        lambda g: g.min(),
+        lambda g: g.var(),
+        lambda g: g.size(),
+        lambda g: g.cumsum(),
+        lambda g: g.transform("sum"),
+        lambda g: g.last(),
+    ],
+    ids=["median", "nunique", "first", "min", "var", "size", "cumsum", "transform", "last"],
+)
+def test_codes_not_written_out_serve_every_other_consumer(ask):
+    """After a many-groups sum the factorisation's memo holds ``RangeCodes``
+    (the key and its minimum, no array); what is not the sorted tiles writes
+    them out through ``codes_array`` and answers as before."""
+    from modin_tpu.ops import groupby as gb_ops
+    from modin_tpu.utils import get_current_execution
+    from modin_tpu.views import registry
+
+    if get_current_execution() != "TpuOnJax":
+        pytest.skip("device kernels")
+    pdf, _ = _many_groups_frame(2_000, "dense_range")
+    pdf = pdf[["k", "int64", "float64_nan"]]
+    with _one_shard_tpu_choice("tpu"):
+        md = pd.DataFrame(pdf)
+        registry.reset()
+        gb_ops.clear_factorize_cache()
+        df_equals(md.groupby("k").sum(), pdf.groupby("k").sum())
+        memo = [result[0] for _, _, result in gb_ops._FACTORIZE_CACHE]
+        assert [type(codes) for codes in memo] == [gb_ops.RangeCodes]
+        assert memo[0]._array is None and memo[0].shape == (len(pdf),)
+        df_equals(ask(md.groupby("k")), ask(pdf.groupby("k")))
