@@ -505,8 +505,9 @@ def note_launch(program: str) -> None:
 
 def note_groupby_form(form: str) -> None:
     """One choice of a groupby reduction's or histogram's device form
-    (``ops/groupby.py``, which checks ``ACCOUNTING_ON`` first): ``masked_scan``,
-    ``sorted_tiles``, ``segment``, ``pallas_bincount`` or ``scatter_counts``."""
+    (``ops/groupby.py``, which checks ``ACCOUNTING_ON`` first): ``limb_dot``,
+    ``masked_scan``, ``sorted_tiles``, ``segment``, ``pallas_bincount`` or
+    ``scatter_counts``."""
     stack = _spans.thread_requests()
     if stack:
         for qs in stack:

@@ -141,7 +141,7 @@ SPANS = (
     (
         "groupby.reduce",
         "one device groupby aggregation (ops/groupby.py groupby_reduce): the "
-        "form chosen for it (masked_scan / sorted_tiles / segment / "
+        "form chosen for it (limb_dot / masked_scan / sorted_tiles / segment / "
         "pallas_bincount / scatter_counts), agg, num_groups and n_cols in "
         "attributes; innermost QUERY-COMPILER span, so the compile ledger "
         "bills the reduction's programs to it",

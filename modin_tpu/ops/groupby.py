@@ -159,11 +159,12 @@ def _histogram_form(ids, width: int) -> str:
     a TPU (14.7 s at 1e8 rows), so there the Pallas one-hot kernel counts up to
     its ``MAX_GROUPS`` ids and the sorted tiles (no value column) a wider range
     of an unsharded key; the scatter is left with the CPU, where it is fine,
-    and with a wide range of a row-sharded key."""
+    and with a wide range of a row-sharded key.  (Under the test hook the
+    Pallas kernel is chosen off the chip too, and runs in interpret mode.)"""
     from modin_tpu.ops.pallas.groupby_kernels import MAX_GROUPS, bincount_supported
     from modin_tpu.parallel.mesh import num_row_shards
 
-    if bincount_supported(ids, width):
+    if bincount_supported(ids, width, _tpu_forms(ids)):
         return "pallas_bincount"
     if width > MAX_GROUPS and _tpu_forms(ids) and num_row_shards() == 1:
         return "sorted_tiles"
@@ -179,7 +180,7 @@ def _histogram(ids, width: int, form: str):
     if form == "pallas_bincount":
         from modin_tpu.ops.pallas.groupby_kernels import pallas_bincount
 
-        return pallas_bincount(codes_array(ids), width)
+        return pallas_bincount(codes_array(ids), width, interpret=not _on_tpu(ids))
     return _jit_scatter_counts(width)(codes_array(ids))
 
 
@@ -589,24 +590,42 @@ def _jit_pad_to(p_out: int):
     return named_jit(fn, "groupby_pad_to")
 
 
-# The three forms of a segment reduction, by group count (chip readings in
-# PERF.md): up to _MASKED_SCAN_MAX_GROUPS the masked scan's one-hot spans every
-# group at once (O(n*G) on the VPU, no scatter); above it, up to _RANGE_LIMIT,
-# sum/mean/count take the sorted-tiles form below (sort row chunks by code,
-# one-hot each run of sorted rows against the few consecutive codes it holds);
-# XLA's scatter-based segment ops, which serialise on a TPU (146 ns a row and
-# 64-bit column at 1e8 rows), are left with min/max/prod/any/all/var/std/sem
-# above the masked scan's limit, with row-sharded operands, and with the CPU,
-# where scatters are fine.
+# The four forms of a segment reduction (chip readings in PERF.md), read from
+# the aggregation, the group count, the platform and the shard count:
+# - limb_dot: sum/mean/count of up to _MASKED_SCAN_MAX_GROUPS groups on a
+#   one-shard TPU.  Values are cut into byte limbs, which bf16 holds exactly,
+#   and a block's limbs are contracted with its one-hot on the MXU, inside a
+#   Pallas kernel; integer sums are exact (wrapping as numpy's), float sums an
+#   exact fixed point rounded once.  A float column whose bit span passes the
+#   limbs, or that holds an infinity, takes the masked scan inside the same
+#   program.
+# - masked_scan: the one-hot spans every group at once and is reduced on the
+#   VPU, O(n*G) 64-bit selects and adds, no scatter: min/max/prod/any/all up to
+#   that limit, and sum/mean/count of a row-sharded operand.
+# - sorted_tiles: sum/mean/count above the limit, up to _RANGE_LIMIT, on a
+#   one-shard TPU (sort row chunks by code, one-hot each run of sorted rows
+#   against the few consecutive codes it holds).
+# - segment: XLA's scatter-based segment ops, which serialise on a TPU (146 ns
+#   a row and 64-bit column at 1e8 rows), are left with var/std/sem, with
+#   min/max/prod/any/all above the limit, with row-sharded operands above it,
+#   and with the CPU, where scatters are fine.
 _MASKED_SCAN_MAX_GROUPS = 1024
 _SCAN_CHUNK = 65536
+# limb_dot: rows cut into words at a time (a word temporary is 4 MB)
+_LIMB_CHUNK = 1 << 20
 # sorted tiles: rows sorted at a time, and consecutive codes the one-hot of a
 # block of sorted rows spans (rows a block: _tile_rows)
 _SORT_CHUNK = 1 << 22
 _TILE_IDS = 512
-# test hook: "tpu" (what a TPU would choose, on any platform) | "masked_scan"
-# (the same, older name) | "segment" | None
+# test hook: "tpu" (what a TPU would choose, on any platform; Pallas kernels
+# then run in interpret mode off the chip) | "masked_scan" (the same, but the
+# scan where limb_dot would be chosen: the sharded and fallback path) |
+# "segment" | None
 _FORCE_KERNEL = None
+
+
+def _on_tpu(arr) -> bool:
+    return next(iter(arr.devices())).platform == "tpu"
 
 
 def _tpu_forms(arr) -> bool:
@@ -614,7 +633,7 @@ def _tpu_forms(arr) -> bool:
     TPU (or the test hook says so)."""
     if _FORCE_KERNEL is not None:
         return _FORCE_KERNEL != "segment"
-    return next(iter(arr.devices())).platform == "tpu"
+    return _on_tpu(arr)
 
 
 @functools.lru_cache(maxsize=None)
@@ -772,7 +791,24 @@ def _jit_masked_scan_smc(
     adaptive: bool,
     has_sizes: bool,
 ):
-    """sum/mean/count masked-scan with a SHARED group-size histogram.
+    return named_jit(
+        _masked_scan_smc(agg, n_cols, num_segments, p_out, chunk, adaptive, has_sizes),
+        "groupby_masked_scan_smc",
+    )
+
+
+def _masked_scan_smc(
+    agg: str,
+    n_cols: int,
+    num_segments: int,
+    p_out: int,
+    chunk: int,
+    adaptive: bool,
+    has_sizes: bool,
+):
+    """sum/mean/count masked-scan with a SHARED group-size histogram (the
+    function ``_jit_masked_scan_smc`` jits; the limb-dot form traces it as the
+    branch of a column it cannot sum exactly).
 
     The main scan accumulates every column's nan-zeroed sum plus ONE sizes
     histogram (skipped when the factorization by-product arrives as an
@@ -848,7 +884,7 @@ def _jit_masked_scan_smc(
         init = []
         for i, c in enumerate(cols):
             if need_sum:
-                init.append(jnp.zeros(G, c.dtype))
+                init.append(jnp.zeros(G, _sum_dtype(c.dtype)))
             if inline_count[i]:
                 init.append(jnp.zeros(G, jnp.int64))
         if need_sizes and not has_sizes:
@@ -915,7 +951,320 @@ def _jit_masked_scan_smc(
                 out.append(finish(s / cnt.astype(s.dtype)))
         return tuple(out)
 
-    return named_jit(fn, "groupby_masked_scan_smc")
+    return fn
+
+
+def _sum_dtype(dtype) -> np.dtype:
+    """The dtype of a column's per-group sums: pandas' (and numpy's) 64-bit
+    integer of the column's signedness, the column's own float width."""
+    dtype = np.dtype(dtype)
+    if dtype.kind in "ib":
+        return np.dtype(np.int64)
+    return np.dtype(np.uint64) if dtype.kind == "u" else dtype
+
+
+def _limb_dot_takes(dtype) -> bool:
+    """Whether a column of ``dtype`` can be cut into limbs: integers and
+    bools of any width, float32 and float64."""
+    dtype = np.dtype(dtype)
+    return dtype.kind in "iub" or dtype in (np.float32, np.float64)
+
+
+def _float32_streams(x):
+    """A float chunk as float32 streams whose sum is the value, NaN read as
+    zero: one for float32, three for float64 (24 bits each: an IEEE double's
+    53, or the two halves a TPU keeps of it and a zero).  Returns the streams
+    and, a row, whether they add up to it exactly (not an infinity, nor past
+    float32's range at either end)."""
+    import jax.numpy as jnp
+
+    x0 = jnp.where(jnp.isnan(x), 0, x)
+    if x.dtype == jnp.float32:
+        return [x0], jnp.isfinite(x0)
+    # each difference is exact (its operands lie within a factor of two, or
+    # the stream is zero); only the last rounding can lose bits
+    high = x0.astype(jnp.float32)
+    rest = x0 - high.astype(x.dtype)
+    mid = rest.astype(jnp.float32)
+    rest = rest - mid.astype(x.dtype)
+    low = rest.astype(jnp.float32)
+    return [high, mid, low], jnp.isfinite(high) & (low.astype(x.dtype) == rest)
+
+
+def _fixed_point_to_float(limbs, unit, dtype):
+    """``sum_k limbs[k] * 256**k * 2**unit`` a group, rounded once (to nearest,
+    ties to even) to ``dtype``.  ``limbs`` is int64 ``[L, G]``, signed and not
+    carried; ``unit`` an int32 scalar.  (Loops over the limbs are ``lax.scan``s:
+    a few dozen steps on ``[G]`` vectors, and a tenth of the operations to
+    trace in every new process.)"""
+    import jax.lax as lax
+    import jax.numpy as jnp
+
+    groups = limbs.shape[1:]
+    # five limbs of room for the carry past the last: it stays under 2**40
+    limbs = jnp.concatenate([limbs, jnp.zeros((5,) + groups, jnp.int64)])
+
+    def carried(limbs):
+        """Digits 0..255 of the same number, and the (signed) carry left."""
+        def step(carry, limb):
+            v = limb + carry
+            return v >> 8, v & 255
+
+        carry, digits = lax.scan(step, jnp.zeros(groups, jnp.int64), limbs)
+        return digits, carry
+
+    negative = carried(limbs)[1] < 0
+    digits, _ = carried(jnp.where(negative, -limbs, limbs))
+
+    def leading(state, d):
+        """From the top digit down: the leading 57 to 64 bits, whether
+        anything set was dropped below them, and how many digits were."""
+        lead, dropped, sticky = state
+        take = lead < jnp.uint64(1 << 56)
+        return (
+            jnp.where(take, (lead << jnp.uint64(8)) | d.astype(jnp.uint64), lead),
+            dropped + (~take).astype(jnp.int32),
+            sticky | (~take & (d != 0)),
+        ), None
+
+    (lead, dropped, sticky), _ = lax.scan(
+        leading,
+        (jnp.zeros(groups, jnp.uint64), jnp.zeros(groups, jnp.int32), jnp.zeros(groups, bool)),
+        digits, reverse=True,
+    )
+    keep = 53 if np.dtype(dtype) == np.float64 else 24
+    shift = jnp.maximum(64 - lax.clz(lead).astype(jnp.int32) - keep, 0)
+    wide = shift.astype(jnp.uint64)
+    mant = lead >> wide
+    rest = lead & ((jnp.uint64(1) << wide) - jnp.uint64(1))
+    half = (jnp.uint64(1) << wide) >> jnp.uint64(1)
+    up = (shift > 0) & (
+        (rest > half) | ((rest == half) & (sticky | ((mant & jnp.uint64(1)) == 1)))
+    )
+    value = (mant + up.astype(jnp.uint64)).astype(jnp.int64).astype(dtype)
+    # times 2**exp, a power float32 holds at a time (exact: the partial
+    # products move towards the result from the side of the mantissa)
+    exp = shift + 8 * dropped + unit
+    for _ in range(4):
+        part = jnp.clip(exp, -126, 127)
+        value = value * lax.bitcast_convert_type((part + 127) << 23, jnp.float32).astype(dtype)
+        exp = exp - part
+    return jnp.where(negative, -value, value)
+
+
+# what a column with no set bit reads for its lowest
+_NO_BIT = 1 << 20
+
+
+def _float_bit_span(c, chunk: int):
+    """Whether the column's values can be summed as fixed point, and the
+    kernel's scalars if so: the exponent of the unit (the lowest bit set in
+    any value) and, a (stream, piece), whether a bit may fall there."""
+    import jax
+    import jax.lax as lax
+    import jax.numpy as jnp
+
+    from modin_tpu.ops.pallas import groupby_kernels as kernels
+
+    P = c.shape[0]
+    take = min(int(chunk), P)
+    n_streams = 1 if c.dtype == jnp.float32 else 3
+    top_word = np.iinfo(np.int32).max
+
+    def exponent(word):
+        """``e`` where a float32 of magnitude bits ``word`` is ``m * 2**e``,
+        its mantissa ``m`` an integer under 2**24."""
+        return jnp.maximum(word >> 23, 1) - 150
+
+    def step(i, state):
+        exact, unit, least, most = state
+        # (steps overlap at the end: every reduction here may read a row twice)
+        x = lax.dynamic_slice(c, (jnp.minimum(i * take, P - take),), (take,))
+        streams, adds_up = _float32_streams(x)
+        # magnitudes as integers, which order as the floats do
+        words = [lax.bitcast_convert_type(v, jnp.int32) & top_word for v in streams]
+        # a value's lowest set bit is in its last stream that is not zero
+        # (each stream lies under the last place of the one before it)
+        last = words[-1]
+        for word in reversed(words[:-1]):
+            last = jnp.where(last != 0, last, word)
+        frac = last & 0x7FFFFF
+        mant = jnp.where(last >> 23 == 0, frac, frac | 0x800000)
+        # (the place of the mantissa's lowest bit, through its float's exponent)
+        place = lax.bitcast_convert_type((mant & -mant).astype(jnp.float32), jnp.int32)
+        place = (place >> 23) - 127 + exponent(last)
+        return (
+            exact & jnp.all(adds_up),
+            jnp.minimum(unit, jnp.min(jnp.where(last != 0, place, _NO_BIT))),
+            jnp.minimum(least, jnp.stack([jnp.min(jnp.where(w != 0, w, top_word)) for w in words])),
+            jnp.maximum(most, jnp.stack([jnp.max(w) for w in words])),
+        )
+
+    with jax.named_scope("span_probe"):
+        exact, unit, least, most = lax.fori_loop(
+            0, -(-P // take), step,
+            (
+                jnp.bool_(True),
+                jnp.int32(_NO_BIT),
+                jnp.full(n_streams, top_word, jnp.int32),
+                jnp.zeros(n_streams, jnp.int32),
+            ),
+        )
+    unit = jnp.where(unit == _NO_BIT, 0, unit)
+    some = most != 0
+    # a stream's bits lie from its least value's last place to its largest's first
+    low, high = exponent(least), exponent(most) + 23
+    piece_bits = 8 * kernels.PIECE_ROWS
+    fits = jnp.all(~some | (high - unit < piece_bits * kernels.FLOAT_PIECES))
+    held = jnp.stack(
+        [some & (low < unit + piece_bits), some & (high >= unit + piece_bits)], axis=1
+    ).reshape(-1)
+    meta = jnp.zeros(kernels.META_SLOTS, jnp.int32)
+    meta = meta.at[0].set(unit).at[1:1 + held.shape[0]].set(held.astype(jnp.int32))
+    return exact & fits, meta.reshape(1, -1)
+
+
+@functools.lru_cache(maxsize=None)
+def _jit_limb_dot(
+    agg: str,
+    num_segments: int,
+    p_out: int,
+    has_sizes: bool,
+    chunk: int,
+    interpret: bool,
+):
+    """sum/mean/count of a few groups as an exact contraction on the MXU.
+
+    A column is walked ``chunk`` rows at a time; a chunk's values are cut into
+    32-bit words in XLA (a 64-bit column's two halves, a float column's
+    float32 streams) and the Pallas kernel ``limb_dot_sums`` cuts the words
+    into byte limbs, contracts them with the chunk's one-hot and returns the
+    ``[limb, group]`` integer sums, which are added up in int64.  Integers:
+    ``sum_k S[k] << 8k`` wraps as numpy's sums do, so the result is the scan's
+    bit for bit.  Floats: a first walk reads the column's bit span; where it
+    fits the limbs (256 bits: magnitudes within 1e60 of each other) and holds
+    no infinity, every value is an exact multiple of the span's lowest bit,
+    the limb sums are an exact fixed-point number and the group's sum is that
+    number rounded once; NaNs are zeroed and counted by a row of the same
+    contraction.  Any other float column takes the masked scan, under a
+    ``lax.cond`` (one shard only, as the scan's own NaN branch).
+    """
+    import jax
+    import jax.lax as lax
+    import jax.numpy as jnp
+
+    from modin_tpu.ops.pallas import groupby_kernels as kernels
+
+    n_groups = num_segments - 1
+    block = kernels.LIMB_BLOCK
+    lanes = kernels.LIMB_LANES
+    # a chunk's limb sums come back as int32: 255 a row at most
+    assert chunk * 255 < 1 << 31
+
+    def finish(r):
+        return _slice_pad(r, n_groups, p_out)
+
+    def walk(codes, column, words_of, layout, meta=None):
+        """int64 ``[limb rows, groups + 1]`` sums over the whole column."""
+        P = codes.shape[0]
+        take = min(int(chunk), P)
+        padded = -(-take // block) * block
+        steps = -(-P // take)
+
+        def step(i, acc):
+            start = jnp.minimum(i * take, P - take)
+            cc = lax.dynamic_slice(codes, (start,), (take,)).astype(jnp.int32)
+            if P % take:
+                # the last step reaches back over rows the one before has summed
+                done = (i * take - start).astype(jnp.int32)
+                cc = jnp.where(jnp.arange(take, dtype=jnp.int32) >= done, cc, n_groups)
+            words = words_of(lax.dynamic_slice(column, (start,), (take,)))
+            if padded > take:
+                cc = jnp.concatenate([cc, jnp.full(padded - take, n_groups, jnp.int32)])
+                words = [jnp.concatenate([w, jnp.zeros(padded - take, jnp.int32)]) for w in words]
+            shape = (padded // lanes, lanes)
+            with jax.named_scope("limb_dot"):
+                sums = kernels.limb_dot_sums(
+                    cc.reshape(shape), [w.reshape(shape) for w in words],
+                    meta, layout, num_segments, interpret,
+                )
+            return acc + sums[:, :num_segments].astype(jnp.int64)
+
+        rows = kernels.limb_rows(layout)
+        return lax.fori_loop(0, steps, step, jnp.zeros((rows, num_segments), jnp.int64))
+
+    def as_words(x):
+        return lax.bitcast_convert_type(x, jnp.int32)
+
+    def int_words(x):
+        if x.dtype.itemsize == 8:
+            return [as_words(x.astype(jnp.uint32)), as_words((x >> 32).astype(jnp.uint32))]
+        if jnp.issubdtype(x.dtype, jnp.signedinteger):
+            return [x.astype(jnp.int32)]
+        return [as_words(x.astype(jnp.uint32))]
+
+    def int_column(c, codes, sizes):
+        if agg == "count" and sizes is not None:
+            return sizes
+        wide = c.dtype.itemsize == 8
+        signed = jnp.issubdtype(c.dtype, jnp.signedinteger)
+        S = walk(codes, c, int_words, ("int", 2 if wide else 1))
+        cnt = sizes if sizes is not None else S[kernels.INT_ROW_ONES]
+        if agg == "count":
+            return cnt
+        total = jnp.zeros(num_segments, jnp.uint64)
+        for k in range(8 if wide else 4):
+            total = total + (S[k].astype(jnp.uint64) << jnp.uint64(8 * k))
+        if signed and not wide:
+            total = total - (S[kernels.INT_ROW_TOP].astype(jnp.uint64) << jnp.uint64(32))
+        total = total.astype(_sum_dtype(c.dtype))
+        return total if agg == "sum" else total / cnt.astype(total.dtype)
+
+    def float_column(c, codes, sizes):
+        def words_of(x):
+            streams, _ = _float32_streams(x)
+            # the first stream keeps the NaNs: the kernel counts the rows that hold none
+            streams[0] = jnp.where(jnp.isnan(x), jnp.float32(np.nan), streams[0])
+            return [as_words(v) for v in streams]
+
+        if agg == "count":
+            nans_kept = lambda x: [as_words(x.astype(jnp.float32))]  # noqa: E731
+            return finish(walk(codes, c, nans_kept, ("valid", 1))[0])
+        layout = ("float", 1 if c.dtype == jnp.float32 else 3)
+        piece, n_low = kernels.PIECE_ROWS, kernels.low_rows(layout)
+
+        def fixed_point(meta):
+            S = walk(codes, c, words_of, layout, meta)
+            # a stream's lower limbs, then (past the row counts) its upper ones
+            limbs = sum(
+                jnp.concatenate([S[piece * i:piece * (i + 1)], S[n_low + piece * i:][:piece]])
+                for i in range(layout[1])
+            )
+            total = _fixed_point_to_float(limbs, meta[0, 0], c.dtype)
+            if agg == "sum":
+                return finish(total)
+            return finish(total / S[n_low - piece].astype(total.dtype))
+
+        def scan(meta):
+            operands = ((c,), codes) + ((sizes,) if sizes is not None else ())
+            return scan_fn(*operands)[0]
+
+        fits, meta = _float_bit_span(c, chunk)
+        return lax.cond(fits, fixed_point, scan, meta)
+
+    scan_fn = _masked_scan_smc(agg, 1, num_segments, p_out, _SCAN_CHUNK, True, has_sizes)
+
+    def fn(cols: Tuple, codes, sizes_in=None):
+        out = []
+        for i, c in enumerate(cols):
+            with jax.named_scope(f"{agg}_col{i}"):
+                if jnp.issubdtype(c.dtype, jnp.floating):
+                    out.append(float_column(c, codes, sizes_in))
+                else:
+                    out.append(finish(int_column(c, codes, sizes_in)))
+        return tuple(out)
+
+    return named_jit(fn, "groupby_limb_dot")
 
 
 def _tile_rows(chunk: int, num_groups: int) -> int:
@@ -1184,7 +1533,7 @@ def groupby_reduce(
             out_width * max(ns, p_out) * 8,
             out_width * num_groups * 8,
         )
-    form = _reduce_form(agg, codes, num_groups, sizes)
+    form = _reduce_form(agg, codes, num_groups, sizes, value_cols)
     if _meters.ACCOUNTING_ON and form != "host_sizes":
         _meters.note_groupby_form(form)
     with _spans.span(
@@ -1198,18 +1547,22 @@ def groupby_reduce(
         single = num_row_shards() == 1
         # only the sorted tiles take codes that were not written out
         codes = _tiles_operand(codes) if form == "sorted_tiles" else codes_array(codes)
-        if form == "masked_scan":
+        # the factorisation's row counts as an operand: a denominator for free
+        has_sizes = sizes is not None and agg in ("mean", "count")
+        if form == "limb_dot":
+            fn = _jit_limb_dot(
+                agg, ns, p_out, has_sizes, _LIMB_CHUNK, not _on_tpu(codes)
+            )
+        elif form == "masked_scan":
             if agg not in ("sum", "mean", "count"):
                 fn = _jit_masked_scan_agg(
                     agg, len(value_cols), ns, int(ddof), p_out, _SCAN_CHUNK
                 )
                 return list(fn(tuple(value_cols), codes))
-            has_sizes = sizes is not None and agg in ("mean", "count")
             fn = _jit_masked_scan_smc(
                 agg, len(value_cols), ns, p_out, _SCAN_CHUNK, single, has_sizes
             )
         elif form == "sorted_tiles":
-            has_sizes = sizes is not None and agg in ("mean", "count")
             fn = _jit_sorted_tiles(
                 agg, len(value_cols), ns, p_out, has_sizes, _SORT_CHUNK
             )
@@ -1227,24 +1580,30 @@ def groupby_reduce(
         return list(fn(tuple(value_cols), codes))
 
 
-def _reduce_form(agg: str, codes: Any, num_groups: int, sizes: Any) -> str:
+def _reduce_form(
+    agg: str, codes: Any, num_groups: int, sizes: Any, value_cols: Any = ()
+) -> str:
     """The device form of one aggregation, read from the aggregation, the
-    group count, the platform and the shard count (no option): see the note
-    above ``_MASKED_SCAN_MAX_GROUPS``.  min/max/prod/any/all above the masked
-    scan's limit, var/std/sem (two-pass, centred), and everything on a
-    row-sharded mesh above that limit keep the scatter-based segment ops."""
+    group count, the platform, the shard count and the columns' dtypes (no
+    option): see the note above ``_MASKED_SCAN_MAX_GROUPS``.  min/max/prod/any/
+    all above the masked scan's limit, var/std/sem (two-pass, centred), and
+    everything on a row-sharded mesh above that limit keep the scatter-based
+    segment ops."""
     from modin_tpu.parallel.mesh import num_row_shards
 
     if agg == "size":
         return "host_sizes" if sizes is not None else _histogram_form(codes, num_groups)
     if _tpu_forms(codes) and agg not in ("var", "std", "sem"):
+        smc = agg in ("sum", "mean", "count") and num_row_shards() == 1
         if num_groups <= _MASKED_SCAN_MAX_GROUPS:
+            if (
+                smc
+                and _FORCE_KERNEL != "masked_scan"
+                and all(_limb_dot_takes(c.dtype) for c in value_cols)
+            ):
+                return "limb_dot"
             return "masked_scan"
-        if (
-            agg in ("sum", "mean", "count")
-            and num_groups <= _RANGE_LIMIT
-            and num_row_shards() == 1
-        ):
+        if smc and num_groups <= _RANGE_LIMIT:
             return "sorted_tiles"
     return "segment"
 

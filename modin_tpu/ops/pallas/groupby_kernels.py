@@ -10,6 +10,15 @@ arithmetic.
 Used on the TPU backend for group widths <= ``MAX_GROUPS``; everywhere else
 the XLA scatter path stays (CPU scatters are fine).  Interpret mode makes the
 kernel testable on CPU.
+
+``limb_dot``: per-group sums of one value column for a few groups, exact, on
+the MXU.  A value is cut into byte limbs (integers of at most 255, which bf16
+holds exactly), a block's limbs ``[rows, t]`` are contracted with its one-hot
+``[G, t]`` over the ``t`` data rows, and the f32 products are flushed into
+int32 sums before they can round (255 * 65 536 < 2**24).  What comes back is
+``[limb rows, G]`` integer sums, which ``ops/groupby.py`` recombines: integers
+by shifts (wrapping as numpy's sums do), floats as an exact fixed-point number
+rounded once.
 """
 
 from __future__ import annotations
@@ -167,12 +176,14 @@ def pallas_bincount(codes: Any, num_groups: int, interpret: bool = False) -> Any
     )(codes)
 
 
-def bincount_supported(codes: Any, num_groups: int) -> bool:
-    """Whether the pallas histogram should be used for this input."""
+def bincount_supported(codes: Any, num_groups: int, as_on_tpu: bool = False) -> bool:
+    """Whether the pallas histogram should be used for this input: few enough
+    groups, on a TPU (``as_on_tpu``: chosen as if it were, the test hook of
+    ``ops/groupby.py``; the kernel then runs in interpret mode)."""
     if num_groups > MAX_GROUPS or num_groups < 1:
         return False
     try:
-        platform = next(iter(codes.devices())).platform
+        platform = "tpu" if as_on_tpu else next(iter(codes.devices())).platform
     except Exception:  # graftlint: disable=EXC-HYGIENE -- device-platform probe; any failure means 'no pallas path'
         return False
     if _row_shards_of(codes) > 1:
@@ -182,3 +193,266 @@ def bincount_supported(codes: Any, num_groups: int) -> bool:
         if int(codes.shape[0]) % num_row_shards():
             return False
     return platform == "tpu"
+
+
+# --------------------------------------------------------------------- #
+# limb_dot: exact per-group sums as a limbs x one-hot contraction
+# --------------------------------------------------------------------- #
+
+# rows of the limb matrix a piece: one bf16 tile of sublanes
+PIECE_ROWS = 16
+# data rows a contraction (the lanes of one row of the kernel's operands) and
+# a grid step: the f32 sums of a step stay exact while a step holds at most
+# 65 536 rows (a limb is at most 255)
+LIMB_LANES = 4096
+LIMB_STEP_ROWS = 8
+LIMB_BLOCK = LIMB_LANES * LIMB_STEP_ROWS
+# limbs a float stream: two pieces, 256 bits of fixed point
+FLOAT_PIECES = 2
+# rows of an integer column's piece past its byte limbs: one that counts the
+# rows, one that counts the set top bits of the last word (negative int32s)
+INT_ROW_ONES = 8
+INT_ROW_TOP = 9
+#: slots of the float kernel's scalar operand: the exponent of the fixed
+#: point's unit, then one flag a (stream, piece): whether any bit falls there
+META_SLOTS = 8
+
+
+def limb_rows(layout: tuple) -> int:
+    """Rows of the limb matrix of ``layout`` (see :func:`limb_dot_sums`)."""
+    kind, n = layout
+    return PIECE_ROWS * (FLOAT_PIECES * n + 1) if kind == "float" else PIECE_ROWS
+
+
+def low_rows(layout: tuple) -> int:
+    """Rows of a float layout before its streams' upper limbs: the lower 16
+    limbs a stream, then the piece that counts the rows."""
+    kind, n = layout
+    return PIECE_ROWS * (n + 1) if kind == "float" else PIECE_ROWS
+
+
+@functools.lru_cache(maxsize=None)
+def _build_limb_dot(n_steps: int, g_pad: int, layout: tuple, interpret: bool):
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    kind, n_words = layout
+    rows = limb_rows(layout)
+    n_low = low_rows(layout)
+    t = LIMB_LANES
+    has_meta = kind == "float"
+
+    # (constants are int32 / float32 by name: with x64 on a literal traces as
+    # a weak 64-bit value, which Mosaic cannot lower)
+    i32 = jnp.int32
+
+    def sublane(n_rows):
+        return lax.broadcasted_iota(jnp.int32, (n_rows, t), 0)
+
+    def spread(row, n_rows=PIECE_ROWS):
+        return jnp.broadcast_to(row, (n_rows, t))
+
+    def to_bf16(x):
+        return x.astype(jnp.float32).astype(jnp.bfloat16)
+
+    def int_piece(words):
+        """Byte limbs of one or two 32-bit words a row (rows 0..3, 4..7), a
+        row of ones and a row of the last word's top bit."""
+        k = sublane(PIECE_ROWS)
+        src = spread(words[0])
+        if n_words == 2:
+            src = jnp.where(k < i32(4), src, spread(words[1]))
+        limb = lax.shift_right_logical(src, (k & i32(3)) * i32(8)) & i32(255)
+        top = lax.shift_right_logical(spread(words[-1]), i32(31))
+        extra = jnp.where(
+            k == i32(INT_ROW_ONES), i32(1), jnp.where(k == i32(INT_ROW_TOP), top, i32(0))
+        )
+        return to_bf16(jnp.where(k < i32(4 * n_words), limb, extra))
+
+    def float_parts(word):
+        """(mantissa << 8, exponent, sign mask, is a number) of a row of
+        float32 bit patterns: the value is ``mantissa * 2**exponent``, the mask
+        -1 or 0; a NaN (and an infinity, which never comes here with a sum
+        wanted) has mantissa 0."""
+        expf = lax.shift_right_logical(word, i32(23)) & i32(255)
+        frac = word & i32(0x7FFFFF)
+        finite = expf != i32(255)
+        mant = jnp.where(expf == i32(0), frac, frac | i32(0x800000))
+        mant = jnp.where(finite, mant, i32(0))
+        return (
+            lax.shift_left(mant, i32(8)),
+            jnp.maximum(expf, i32(1)) - i32(150),
+            lax.shift_right_arithmetic(word, i32(31)),
+            finite | (frac == i32(0)),
+        )
+
+    def float_piece(m8, shift, sign, piece):
+        """Signed byte limbs ``16 * piece ..`` of ``mantissa << shift``."""
+        sh = sublane(PIECE_ROWS) * i32(8) + i32(8 * PIECE_ROWS * piece + 8) - shift
+        # (a shift of 32 or more, or under none, is left to the select)
+        limb = lax.shift_right_logical(m8, sh) & i32(255)
+        within = lax.bitcast_convert_type(sh, jnp.uint32) < jnp.uint32(32)
+        return to_bf16((jnp.where(within, limb, i32(0)) ^ sign) - sign)
+
+    def kernel(*refs):
+        refs = list(refs)
+        meta_ref = refs.pop(0) if has_meta else None
+        codes_ref = refs.pop(0)
+        word_refs = [refs.pop(0) for _ in range(n_words)]
+        out_ref, acc_ref, *part_refs = refs
+
+        @pl.when(pl.program_id(0) == 0)
+        def _init():
+            out_ref[...] = jnp.zeros_like(out_ref)
+            if kind != "int":
+                part_refs[-1][...] = jnp.zeros_like(part_refs[-1])
+
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        def held(s, piece):
+            return meta_ref[0, 1 + FLOAT_PIECES * s + piece] != i32(0)
+
+        if kind == "float":
+            any_high = held(0, 1)
+            for s in range(1, n_words):
+                any_high = any_high | held(s, 1)
+
+        if kind != "int":
+            # a float word's parts, for the whole step at once (rows on the
+            # sublanes too); a stream with no bit set anywhere is left out
+            m8_ref, shift_ref, sign_ref, number_ref, limbs_refs = part_refs
+            for s, word_ref in enumerate(word_refs):
+                def parts(s=s, word_ref=word_ref):
+                    m8, exp, sign, number = float_parts(word_ref[...])
+                    if s == 0:
+                        number_ref[...] = jnp.where(number, i32(1), i32(0))
+                    if kind == "float":
+                        m8_ref[s], sign_ref[s] = m8, sign
+                        shift_ref[s] = exp - meta_ref[0, 0]
+
+                if kind == "float" and s > 0:
+                    pl.when(held(s, 0) | held(s, 1))(parts)
+                else:
+                    parts()
+
+        def onehot_of(codes, g):
+            ids = sublane(_LANES) + i32(g * _LANES)
+            return jnp.where(
+                spread(codes, _LANES) == ids, jnp.float32(1), jnp.float32(0)
+            ).astype(jnp.bfloat16)
+
+        def add_dot(first, last, limbs, onehot, g):
+            acc_ref[first:last, g * _LANES:(g + 1) * _LANES] += lax.dot_general(
+                limbs, onehot, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+
+        def contract(j, slot):
+            row = pl.ds(j, 1)
+            codes = codes_ref[row, :]
+            if kind == "int":
+                limbs = int_piece([r[row, :] for r in word_refs])
+                for g in range(g_pad // _LANES):
+                    add_dot(0, rows, limbs, onehot_of(codes, g), g)
+                return
+            # the limb matrix is written a piece at a time, the pieces no bit
+            # falls in staying zero; ``slot``: one matrix a row of a turn
+            k = sublane(PIECE_ROWS)
+            limbs_refs[slot, n_low - PIECE_ROWS:n_low, :] = to_bf16(
+                jnp.where(k == i32(0), spread(number_ref[row, :]), i32(0))
+            )
+            if kind == "float":
+                for s in range(n_words):
+                    for p in range(FLOAT_PIECES):
+                        first = p * n_low + s * PIECE_ROWS
+
+                        @pl.when(held(s, p))
+                        def _piece(s=s, p=p, first=first):
+                            limbs_refs[slot, first:first + PIECE_ROWS, :] = float_piece(
+                                spread(m8_ref[s, row, :]),
+                                spread(shift_ref[s, row, :]),
+                                spread(sign_ref[s, row, :]),
+                                p,
+                            )
+
+            def dots(last):
+                for g in range(g_pad // _LANES):
+                    add_dot(0, last, limbs_refs[slot, 0:last, :], onehot_of(codes, g), g)
+
+            if rows == n_low:
+                dots(rows)
+            else:
+                # the upper limbs: only a wide span of values reaches them
+                pl.when(any_high)(lambda: dots(rows))
+                pl.when(jnp.logical_not(any_high))(lambda: dots(n_low))
+
+        # two rows a turn of the loop: a row's limbs and one-hot are built
+        # while the row before it is on the MXU (all eight unrolled are 4%
+        # faster and take four times as long to trace in every new process)
+        def pair(jj, carry):
+            contract(jj * i32(2), 0)
+            contract(jj * i32(2) + i32(1), 1)
+            return carry
+
+        lax.fori_loop(i32(0), i32(LIMB_STEP_ROWS // 2), pair, i32(0))
+        out_ref[...] += acc_ref[...].astype(jnp.int32)
+
+    zero = np.int32(0)
+    vmem = {"memory_space": pltpu.VMEM}
+    parts_scratch = []
+    if kind != "int":
+        streams = pltpu.VMEM((n_words, LIMB_STEP_ROWS, t), jnp.int32)
+        parts_scratch = [
+            streams, streams, streams,
+            pltpu.VMEM((LIMB_STEP_ROWS, t), jnp.int32),
+            pltpu.VMEM((2, rows, t), jnp.bfloat16),
+        ]
+    data_spec = pl.BlockSpec((LIMB_STEP_ROWS, t), lambda i: (i, zero), **vmem)
+    in_specs = [data_spec] * (1 + n_words)
+    if has_meta:
+        meta_spec = pl.BlockSpec(
+            (1, META_SLOTS), lambda i: (zero, zero), memory_space=pltpu.SMEM
+        )
+        in_specs = [meta_spec] + in_specs
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((rows, g_pad), jnp.int32),
+        grid=(n_steps,),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((rows, g_pad), lambda i: (zero, zero), **vmem),
+        scratch_shapes=[pltpu.VMEM((rows, g_pad), jnp.float32)] + parts_scratch,
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="groupby_limb_dot_kernel",
+    )
+
+
+def limb_dot_sums(codes, words, meta, layout: tuple, num_segments: int, interpret: bool):
+    """Per-group integer sums of the limb rows of one stretch of rows.
+
+    ``codes`` (int32) and each of ``words`` (int32 bit patterns) have
+    ``[n_steps * LIMB_STEP_ROWS, LIMB_LANES]`` entries, one a data row, a code
+    of ``num_segments - 1`` or more counting for nothing that is kept.
+    ``layout`` says what a word is:
+
+    - ``("int", w)``: ``w`` words (1 or 2, low word first) of an integer; rows
+      ``0 .. 4w`` of the result are the sums of its bytes, row ``INT_ROW_ONES``
+      the row count, row ``INT_ROW_TOP`` the count of set top bits;
+    - ``("float", s)``: ``s`` float32 streams whose sum is the value; of the
+      signed bytes of ``value / 2**meta[0]`` stream ``i`` sums the lower 16 in
+      rows ``16 i .. 16 i + 16`` and the upper 16 in rows ``low_rows + 16 i
+      ..``; row ``16 s`` counts the rows whose first stream is no NaN.  ``meta``
+      (int32 ``[1, META_SLOTS]``) also flags the (stream, piece) pairs that
+      hold any bit: the others are neither computed nor contracted;
+    - ``("valid", 1)``: row 0 counts the rows whose float32 word is no NaN.
+
+    Returns int32 ``[limb_rows(layout), g_pad]``.
+    """
+    g_pad = -(-num_segments // _LANES) * _LANES
+    n_steps = codes.shape[0] // LIMB_STEP_ROWS
+    call = _build_limb_dot(n_steps, g_pad, layout, bool(interpret))
+    operands = ([meta] if layout[0] == "float" else []) + [codes, *words]
+    return call(*operands)

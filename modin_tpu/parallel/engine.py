@@ -97,6 +97,10 @@ def initialize_jax() -> None:
     # feature detection (SIGILL warnings), and CPU compiles are fast.
     if jax.default_backend() != "cpu":
         _place_compilation_cache()
+        # the kernel library is a second of imports: paid here, at engine
+        # start, and not inside the process's first groupby (whose programs
+        # hold Pallas kernels on an accelerator whatever the query)
+        import jax.experimental.pallas.tpu  # noqa: F401
 
 
 #: where compiled XLA executables persist when the caller does not say: one

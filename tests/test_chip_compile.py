@@ -82,6 +82,14 @@ def _lower(kernel: str, arg, shape):
             "size", 0, arg + 1, arg, False, groupby._SORT_CHUNK
         ).lower((), (codes, np.int64(1), np.int64(ROWS)))
     chunk = groupby._SCAN_CHUNK
+    if kernel == "limb_dot":  # arg: the aggregation; the last column a float64
+        cols = tuple(
+            shape((ROWS,), np.float64 if i == N_COLS - 1 else np.int64)
+            for i in range(N_COLS)
+        )
+        return groupby._jit_limb_dot(
+            arg, NUM_SEGMENTS, P_OUT, False, groupby._LIMB_CHUNK, False
+        ).lower(cols, codes)
     fn = {  # arg: the aggregation
         "masked_scan_smc": lambda: groupby._jit_masked_scan_smc(
             arg, N_COLS, NUM_SEGMENTS, P_OUT, chunk, True, False
@@ -104,6 +112,8 @@ def _lower(kernel: str, arg, shape):
         ("bincount", 512),
         ("masked_scan_smc", "sum"),
         ("masked_scan_smc", "mean"),
+        ("limb_dot", "sum"),
+        ("limb_dot", "mean"),
         ("masked_scan_agg", "min"),
         ("segment_agg", "sum"),
         ("segment_agg", "var"),
@@ -118,10 +128,12 @@ def test_kernel_compiles_for_v5e_at_1e8_rows(kernel, arg, shapes):
         mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes
     )
     assert resident < HBM_BYTES, (kernel, arg, mem)
-    if kernel == "bincount":
+    if kernel in ("bincount", "limb_dot"):
         # the Mosaic kernel is really in the program, not an XLA scatter
         assert "tpu_custom_call" in lowered.as_text()
-    elif kernel == "sorted_tiles_size":
+    if kernel == "bincount":
+        return
+    if kernel == "sorted_tiles_size":
         # chunks are sorted and nothing scatters
         text = compiled.as_text()
         assert " sort(" in text and " scatter(" not in text

@@ -937,9 +937,10 @@ def test_sorted_tiles_match_pandas_and_the_segment_form(agg, groups, shape, dtyp
     # every reduction took the new form; the histogram too where the key is an
     # integer range (a float key is factorised by a sort, without a histogram)
     assert got["tpu_forms"].get("sorted_tiles", 0) >= 1 and "segment" not in got["tpu_forms"]
-    # (the two levels of two keys are narrow ranges: the Pallas kernel's on a
-    # TPU, the scatter's here; their product's histogram is the wide one)
-    assert got["tpu_forms"].get("scatter_counts", 0) == (2 if shape == "two_keys" else 0)
+    # (the two levels of two keys are narrow ranges, the Pallas kernel's; their
+    # product's histogram is the wide one)
+    assert got["tpu_forms"].get("pallas_bincount", 0) == (2 if shape == "two_keys" else 0)
+    assert "scatter_counts" not in got["tpu_forms"]
     assert got["segment_forms"].get("segment", 0) >= 1
     tiles, segment = got["tpu"][dtype], got["segment"][dtype]
     assert tiles.index.equals(want.index) and tiles.dtype == want[dtype].dtype == segment.dtype
@@ -990,7 +991,7 @@ def test_groupby_form_reads_group_count_platform_and_shards():
         limit = gb_ops._MASKED_SCAN_MAX_GROUPS
         assert limit == 1024
         for agg in ("sum", "mean", "count"):
-            assert gb_ops._reduce_form(agg, codes, limit, None) == "masked_scan"
+            assert gb_ops._reduce_form(agg, codes, limit, None) == "limb_dot"
             assert gb_ops._reduce_form(agg, codes, limit + 1, None) == "sorted_tiles"
             assert gb_ops._reduce_form(agg, codes, gb_ops._RANGE_LIMIT, None) == "sorted_tiles"
             assert gb_ops._reduce_form(agg, codes, gb_ops._RANGE_LIMIT + 1, None) == "segment"
@@ -1001,8 +1002,17 @@ def test_groupby_form_reads_group_count_platform_and_shards():
             assert gb_ops._reduce_form(agg, codes, 100, None) == "segment"
         assert gb_ops._reduce_form("size", codes, limit + 1, np.ones(limit + 1)) == "host_sizes"
         assert gb_ops._reduce_form("size", codes, limit + 1, None) == "sorted_tiles"
-        assert gb_ops._histogram_form(codes, 512) == "scatter_counts"  # the Pallas kernel's, on a TPU
+        assert gb_ops._histogram_form(codes, 512) == "pallas_bincount"
         assert gb_ops._histogram_form(codes, 513) == "sorted_tiles"
+        # the limbs take integers, bools and the two float widths
+        for dtype in (np.int64, np.uint64, np.int32, np.uint8, bool, np.float32, np.float64):
+            assert gb_ops._reduce_form("sum", codes, 100, None, [jnp.zeros(8, dtype)]) == "limb_dot"
+        assert gb_ops._reduce_form("sum", codes, 100, None, [jnp.zeros(8, jnp.float16)]) == "masked_scan"
+    with _one_shard_tpu_choice("masked_scan"):
+        # the hook's other value: the scan where the limbs would be chosen
+        for agg in ("sum", "mean", "count", "min"):
+            assert gb_ops._reduce_form(agg, codes, 100, None) == "masked_scan"
+        assert gb_ops._reduce_form("sum", codes, 1025, None) == "sorted_tiles"
     with _one_shard_tpu_choice("segment"):
         assert gb_ops._reduce_form("sum", codes, 100, None) == "segment"
     # a row-sharded mesh keeps the segment ops above the masked scan's limit
@@ -1101,3 +1111,231 @@ def test_codes_not_written_out_serve_every_other_consumer(ask):
         assert [type(codes) for codes in memo] == [gb_ops.RangeCodes]
         assert memo[0]._array is None and memo[0].shape == (len(pdf),)
         df_equals(ask(md.groupby("k")), ask(pdf.groupby("k")))
+
+
+# ---------------------------------------------------------------------- #
+# limb_dot: sum / mean / count of a few groups as an exact contraction
+# ---------------------------------------------------------------------- #
+
+_LIMB_DTYPES = ["int64_wraps", "uint64", "int32", "bool", "float32", "float64_nan"]
+
+
+def _limb_column(dtype, n, rng):
+    if dtype == "int64_wraps":
+        return rng.integers(-(2**62), 2**62, n) | 1  # a dozen a group pass 2**63
+    if dtype == "uint64":
+        return rng.integers(0, 2**64, n, dtype=np.uint64)
+    if dtype == "int32":
+        return rng.integers(-(2**31), 2**31, n).astype(np.int32)
+    if dtype == "bool":
+        return rng.random(n) < 0.5
+    if dtype == "float32":
+        return rng.uniform(-1, 1, n).astype(np.float32)
+    return np.where(rng.random(n) < 0.1, np.nan, np.round(rng.uniform(0, 100, n), 6))
+
+
+def _reduce_with(force, agg, column, codes_np, groups, sizes=None, limb_chunk=None):
+    """``groupby_reduce`` of one column on a one-shard mesh under the test
+    hook ``force``: the answer, on the host, and the form it took."""
+    import jax.numpy as jnp
+
+    from modin_tpu.ops import groupby as gb_ops
+    from modin_tpu.ops.structural import pad_len
+
+    with _one_shard_tpu_choice(force):
+        chunk_was = gb_ops._LIMB_CHUNK
+        gb_ops._LIMB_CHUNK = limb_chunk or chunk_was
+        try:
+            cols, codes = [jnp.asarray(column)], jnp.asarray(codes_np)
+            form = gb_ops._reduce_form(agg, codes, groups, sizes, cols)
+            out = gb_ops.groupby_reduce(agg, cols, codes, groups, len(codes_np), sizes=sizes)
+            # (padded to the shard multiple like every form's result)
+            assert out[0].shape == (pad_len(groups),)
+        finally:
+            gb_ops._LIMB_CHUNK = chunk_was
+    return np.asarray(out[0])[:groups], form
+
+
+@pytest.mark.parametrize("with_sizes", [False, True], ids=["no_sizes", "sizes"])
+@pytest.mark.parametrize("dtype", _LIMB_DTYPES)
+@pytest.mark.parametrize("agg", ["sum", "mean", "count"])
+def test_limb_dot_matches_pandas_and_the_scan(agg, dtype, with_sizes):
+    """The limbs' sums are pandas': integers to the bit (a sum past 2**63
+    wraps as numpy's does), floats to a rounding; and the scan's."""
+    rng = np.random.default_rng([_LIMB_DTYPES.index(dtype), 5])
+    n, groups = 9_000, 13
+    codes_np = rng.integers(0, groups, n).astype(np.int32)
+    column = _limb_column(dtype, n, rng)
+    if dtype == "bool" and agg != "count":
+        column = column.astype(np.int64)  # as the query compiler hands it over
+    sizes = np.bincount(codes_np, minlength=groups).astype(np.int64) if with_sizes else None
+    got, form = _reduce_with("tpu", agg, column, codes_np, groups, sizes)
+    assert form == "limb_dot"
+    want = getattr(pandas.Series(column).groupby(codes_np), agg)()
+    assert got.dtype == want.dtype
+    if dtype == "int32":
+        # (the scan, which summed narrow integers in their own width and
+        # failed, now widens them like pandas)
+        assert got.dtype == (np.float64 if agg == "mean" else np.int64)
+    scan, scan_form = _reduce_with("masked_scan", agg, column, codes_np, groups, sizes)
+    assert scan_form == "masked_scan" and scan.dtype == got.dtype
+    if got.dtype.kind in "iu":
+        np.testing.assert_array_equal(got, want.to_numpy())
+        np.testing.assert_array_equal(got, scan)
+    elif column.dtype.kind in "iub":
+        # an integer mean divides the (wrapped) sum: the scan's to the bit,
+        # pandas' where nothing wrapped
+        np.testing.assert_array_equal(got, scan)
+        if dtype not in ("int64_wraps", "uint64"):
+            np.testing.assert_allclose(got, want.to_numpy(), rtol=1e-15)
+    else:
+        # (sums of +-1 near zero: the others' float32 additions show absolutely)
+        tol = {"rtol": 2e-6, "atol": 2e-5} if dtype == "float32" else {"rtol": 1e-13}
+        np.testing.assert_allclose(got, want.to_numpy(), **tol)
+        np.testing.assert_allclose(got, scan, **tol)
+
+
+def _fsum_by_group(column, codes_np, groups):
+    import math
+
+    return np.array(
+        [
+            math.fsum(v for v in column[codes_np == g].tolist() if not math.isnan(v))
+            for g in range(groups)
+        ]
+    ).astype(column.dtype)
+
+
+_FSUM_CASES = ["signs_that_cancel", "magnitudes_1e-12_to_1e12", "negative_zeros", "denormals", "float32"]
+
+
+@pytest.mark.parametrize("case", _FSUM_CASES)
+def test_limb_dot_float_sum_is_the_exact_sum_rounded_once(case):
+    """A float column's sum is ``math.fsum`` of the group, to the bit."""
+    rng = np.random.default_rng(_FSUM_CASES.index(case))
+    n, groups = 20_000, 7
+    codes_np = rng.integers(0, groups, n).astype(np.int32)
+    if case == "signs_that_cancel":
+        half = rng.uniform(1, 2, n // 2) * 1e9
+        column = np.concatenate([half, -half + rng.uniform(-1, 1, n // 2) * 1e-7])
+        codes_np[n // 2:] = codes_np[: n // 2]
+    elif case == "magnitudes_1e-12_to_1e12":
+        column = rng.uniform(-1, 1, n) * 10.0 ** rng.integers(-12, 13, n)
+    elif case == "negative_zeros":
+        column = np.where(rng.random(n) < 0.5, -0.0, rng.uniform(-1, 1, n))
+        column[codes_np == 3] = -0.0
+    elif case == "denormals":
+        column = rng.integers(-1000, 1000, n) * 5e-324
+    else:
+        column = (rng.uniform(-1, 1, n) * 10.0 ** rng.integers(-6, 7, n)).astype(np.float32)
+    got, form = _reduce_with("tpu", "sum", column, codes_np, groups)
+    assert form == "limb_dot" and got.dtype == column.dtype
+    scan, _ = _reduce_with("masked_scan", "sum", column, codes_np, groups)
+    if case == "denormals":
+        # past float32's range at the low end the column takes the scan, and
+        # XLA's CPU code flushes denormals as it always did
+        np.testing.assert_array_equal(got, scan)
+        return
+    np.testing.assert_array_equal(got, _fsum_by_group(column, codes_np, groups))
+    # the scan's additions round one by one: close, and not the same
+    assert not np.array_equal(scan, got) or case == "negative_zeros"
+    scale = np.abs(column).max() * (n / groups)
+    np.testing.assert_allclose(got, scan, rtol=0, atol=scale * (1e-5 if case == "float32" else 1e-13))
+
+
+@pytest.mark.parametrize("agg", ["sum", "mean"])
+@pytest.mark.parametrize("case", ["an_infinity", "a_span_past_the_limbs", "past_float32_range"])
+def test_limb_dot_leaves_a_column_it_cannot_cut_to_the_scan(case, agg):
+    """An infinity, or magnitudes further apart than the limbs' 256 bits: the
+    same program answers with the scan's sums, to the bit."""
+    rng = np.random.default_rng(11)
+    n, groups = 20_000, 5
+    codes_np = rng.integers(0, groups, n).astype(np.int32)
+    column = rng.uniform(-1, 1, n)
+    if case == "an_infinity":
+        column[rng.integers(0, n, 20)] = np.inf
+        column[codes_np == 2] = np.abs(column[codes_np == 2])  # no inf - inf there
+    elif case == "a_span_past_the_limbs":
+        column = (column * 10.0 ** rng.integers(-35, 36, n)).astype(np.float32)
+    else:
+        column = column * 10.0 ** rng.integers(-60, 60, n)
+    got, form = _reduce_with("tpu", agg, column, codes_np, groups)
+    scan, _ = _reduce_with("masked_scan", agg, column, codes_np, groups)
+    assert form == "limb_dot"
+    np.testing.assert_array_equal(got, scan)
+    if case != "an_infinity":
+        # (and the scan's order of additions shows: these are not the exact sums)
+        sums, _ = _reduce_with("tpu", "sum", column, codes_np, groups)
+        assert not np.array_equal(sums, _fsum_by_group(column, codes_np, groups))
+
+
+@pytest.mark.parametrize("dtype", ["int64_wraps", "float64_nan"])
+@pytest.mark.parametrize(
+    "n,limb_chunk",
+    [(777, None), (8_192 * 3 + 77, None), (8_192 * 5 + 1_001, 8_192 * 2), (8_192 * 4, 8_192 * 2)],
+    ids=["under_a_block", "blocks_and_a_rest", "chunks_and_a_rest", "whole_chunks"],
+)
+def test_limb_dot_rows_no_multiple_of_block_or_chunk(n, limb_chunk, dtype):
+    """Rows past the last block are padded into the dropped bucket; the last
+    chunk reaches back over rows the one before has summed, and masks them."""
+    rng = np.random.default_rng(n)
+    groups = 9
+    codes_np = rng.integers(0, groups + 1, n).astype(np.int32)  # some rows dropped
+    column = _limb_column(dtype, n, rng)
+    kept = codes_np < groups
+    for agg in ("sum", "count"):
+        got, form = _reduce_with("tpu", agg, column, codes_np, groups, limb_chunk=limb_chunk)
+        assert form == "limb_dot"
+        want = getattr(pandas.Series(column[kept]).groupby(codes_np[kept]), agg)().to_numpy()
+        if dtype == "float64_nan" and agg == "sum":
+            np.testing.assert_array_equal(got, _fsum_by_group(column[kept], codes_np[kept], groups))
+        else:
+            np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("dtype", ["int64_wraps", "float64_nan"])
+@pytest.mark.parametrize("groups", [1, 100, 128, 129, 1024])
+def test_limb_dot_group_counts_around_the_lane_tiles(groups, dtype):
+    """The one-hot is built a tile of 128 groups at a time, the dropped
+    bucket among them: 1, 100, 128 (the bucket opens a tile), 129, 1024."""
+    rng = np.random.default_rng(groups)
+    n = 12_000
+    codes_np = np.concatenate([np.arange(groups), rng.integers(0, groups + 1, n - groups)])
+    codes_np = codes_np.astype(np.int32)
+    column = _limb_column(dtype, n, rng)
+    kept = codes_np < groups
+    sizes = np.bincount(codes_np[kept], minlength=groups).astype(np.int64)
+    got, form = _reduce_with("tpu", "mean", column, codes_np, groups, sizes)
+    assert form == "limb_dot"
+    scan, _ = _reduce_with("masked_scan", "mean", column, codes_np, groups, sizes)
+    if dtype == "int64_wraps":
+        np.testing.assert_array_equal(got, scan)
+    else:
+        exact = _fsum_by_group(column[kept], codes_np[kept], groups)
+        valid = pandas.Series(column[kept]).groupby(codes_np[kept]).count().to_numpy()
+        with np.errstate(invalid="ignore"):
+            np.testing.assert_array_equal(got, exact / valid)
+        np.testing.assert_allclose(got, scan, rtol=1e-13)
+
+
+@pytest.mark.parametrize("agg", ["sum", "mean", "count"])
+def test_groupby_limb_dot_through_the_api(agg):
+    """``df.groupby(k).agg()`` with the TPU's choice forced: every dtype of the
+    shared frame, the Pallas histogram and the limbs, no fallback."""
+    import modin_tpu.observability as graftscope
+    from modin_tpu.ops.groupby import clear_factorize_cache
+    from modin_tpu.utils import get_current_execution
+    from modin_tpu.views import registry
+
+    if get_current_execution() != "TpuOnJax":
+        pytest.skip("device kernels")
+    pdf = pandas.DataFrame(GB_DATA)[["int_key", "val_int", "val_float", "val_bool"]]
+    with _one_shard_tpu_choice("tpu"):
+        md = pd.DataFrame(pdf)
+        registry.reset()
+        clear_factorize_cache()
+        with graftscope.query_stats("limb-dot") as stats:
+            got = assert_no_fallback(lambda: getattr(md.groupby("int_key"), agg)())
+            got._query_compiler.execute()
+    df_equals(got, getattr(pdf.groupby("int_key"), agg)())
+    assert stats.groupby_forms == {"pallas_bincount": 1, "limb_dot": 1}

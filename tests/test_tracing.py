@@ -752,6 +752,33 @@ class TestRequestRecord:
                 "join", "window", "stats", "datetime", "spmd", "shuffle", "router",
             ), program
 
+    def test_q4_on_one_tpu_chip_takes_the_pallas_histogram_and_the_limbs(self, cell_frames):
+        """The H2O q4 request with the TPU's choice of forms forced (a
+        one-shard mesh, the kernels in interpret mode): the record says which
+        forms ran, how often, and names their programs."""
+        _require_tpu_on_jax()
+        import pandas
+
+        from tests.test_groupby import _one_shard_tpu_choice
+
+        config = "h2o-groupby-g1-1e8-1e2"
+        question = _bench_module("questions", config, "q4_mean_by_id4.py")
+        host = cell_frames(config).modin.to_pandas()
+        with _one_shard_tpu_choice("tpu"):
+            frame = pd.DataFrame(host)
+            frame._query_compiler.execute()
+            with graftscope.query_stats("q4-forms") as stats:
+                answer = _first_run(lambda df: question.run(pd, df), frame)
+            got = answer.modin.to_pandas()
+        assert stats.groupby_forms == {"pallas_bincount": 1, "limb_dot": 3}
+        assert graftscope.recent_queries("q4-forms")[-1]["groupby_forms"] == stats.groupby_forms
+        assert stats.launches_by_program["groupby_limb_dot"] == 3
+        assert stats.launches_by_program["groupby_pallas_bincount"] == 1
+        assert "groupby_masked_scan_smc" not in stats.launches_by_program
+        want = question.run(pandas, host)
+        assert got.index.equals(want.index) and list(got.columns) == list(want.columns)
+        np.testing.assert_allclose(got.to_numpy(), want.to_numpy(), rtol=1e-13)
+
 
 class TestNamedPrograms:
     def test_program_carries_its_name_into_the_lowering(self):
