@@ -68,7 +68,6 @@ LOCKS: Tuple[Tuple[str, str, str], ...] = (
     ("ops.fused_cache", "lock", "fused-program LRU cache linkage"),
     ("plan.storm", "lock", "recompile-storm signature table"),
     ("plan.scan_cache", "lock", "scan-node parse cache (parses happen outside it)"),
-    ("plan.optimizer", "lock", "graftopt PERF_HISTORY priors resolve-once cache"),
     ("views.registry", "rlock", "THE derived-artifact cache (invalidation re-enters via drop hooks)"),
     # -- ingest (graftfeed) -------------------------------------------- #
     ("ingest.feeds", "lock", "the named-feed table: create/get/drop"),

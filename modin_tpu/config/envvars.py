@@ -10,7 +10,6 @@ sharded-jax.Array storage format on the JAX engine.
 from __future__ import annotations
 
 import os
-import secrets
 import warnings
 from textwrap import dedent
 from typing import Any, Optional
@@ -44,13 +43,6 @@ class EnvironmentVariable(Parameter, type=str, abstract=True):
         return help
 
 
-class IsDebug(EnvironmentVariable, type=bool):
-    """Force the serial in-process Python engine (debugging aid)."""
-
-    varname = "MODIN_TPU_DEBUG"
-    default = False
-
-
 class Engine(EnvironmentVariable, type=str):
     """Task-execution engine: Jax (device), Python (serial, testing), Native (no-op)."""
 
@@ -61,8 +53,6 @@ class Engine(EnvironmentVariable, type=str):
 
     @classmethod
     def _get_default(cls) -> str:
-        if IsDebug.get():
-            return "Python"
         try:
             import jax  # noqa: F401
 
@@ -295,49 +285,6 @@ class RangePartitioning(EnvironmentVariable, type=bool):
     default = False
 
 
-class LazyExecution(EnvironmentVariable, type=str):
-    """Deferred-execution mode: Auto (rely on async dispatch), On, Off."""
-
-    varname = "MODIN_TPU_LAZY_EXECUTION"
-    choices = ("Auto", "On", "Off")
-    default = "Auto"
-
-
-class DynamicPartitioning(EnvironmentVariable, type=bool):
-    """Fuse small partitions into axis-level computations dynamically."""
-
-    varname = "MODIN_TPU_DYNAMIC_PARTITIONING"
-    default = False
-
-
-class MinRowPartitionSize(EnvironmentVariable, type=int):
-    """Minimum rows per row shard (avoid tiny shards that waste device tiles)."""
-
-    varname = "MODIN_TPU_MIN_ROW_PARTITION_SIZE"
-    default = 32
-
-    @classmethod
-    def put(cls, value: int) -> None:
-        if value <= 0:
-            raise ValueError(f"Min row partition size should be > 0, passed value {value}")
-        super().put(value)
-
-
-class MinColumnPartitionSize(EnvironmentVariable, type=int):
-    """Minimum columns per column shard."""
-
-    varname = "MODIN_TPU_MIN_COLUMN_PARTITION_SIZE"
-    default = 8
-
-    @classmethod
-    def put(cls, value: int) -> None:
-        if value <= 0:
-            raise ValueError(
-                f"Min column partition size should be > 0, passed value {value}"
-            )
-        super().put(value)
-
-
 class TestDatasetSize(EnvironmentVariable, type=str):
     """Dataset size profile for the benchmark suite."""
 
@@ -412,13 +359,6 @@ class NativePandasTransferThreshold(EnvironmentVariable, type=int):
 
     varname = "MODIN_TPU_NATIVE_PANDAS_TRANSFER_THRESHOLD"
     default = 10_000_000
-
-
-class DevicePutChunkBytes(EnvironmentVariable, type=int):
-    """Chunk size (bytes) for host->device streaming of huge columns."""
-
-    varname = "MODIN_TPU_DEVICE_PUT_CHUNK_BYTES"
-    default = 1 << 30
 
 
 class Float64Policy(EnvironmentVariable, type=str):
@@ -864,9 +804,10 @@ class OptMode(EnvironmentVariable, type=str):
 
     Auto (default): each plan materialization runs one joint ``choose()``
     pass over the optimized plan — a cost model seeded from the kernel
-    router's calibration table, the graftcost substrate peaks, and
-    PERF_HISTORY priors annotates every node with its execution-strategy
-    legs (device/host, local/sharded, fused/staged, resident/windowed),
+    router's calibration table and the graftcost substrate peaks (the
+    optimizer's DEFAULT_PRIORS where neither covers a node) annotates
+    every node with its execution-strategy legs (device/host,
+    local/sharded, fused/staged, resident/windowed),
     the rewrite engine gates rules on modeled cost, and lowering re-plans
     the remaining segment mid-query when measured walls, ledger pressure,
     or compile-storm level diverge from the estimates.  Off: the five
@@ -1016,45 +957,6 @@ class CostCapture(EnvironmentVariable, type=str):
     varname = "MODIN_TPU_COST_CAPTURE"
     default = "Auto"
     choices = ("Auto", "On", "Full", "Off")
-
-
-class PerfGateTolerance(EnvironmentVariable, type=float):
-    """Regression tolerance for the perf-history gate
-    (scripts/perf_history.py): a new bench run whose op wall exceeds the
-    best recorded same-(op, substrate, rows) wall by more than this factor
-    fails the gate.  1.5 absorbs CPU-substrate scheduler noise while still
-    rejecting a 2x regression outright."""
-
-    varname = "MODIN_TPU_PERF_GATE_TOLERANCE"
-    default = 1.5
-
-    @classmethod
-    def put(cls, value: float) -> None:
-        if value < 1.0:
-            raise ValueError(
-                f"Perf gate tolerance should be >= 1.0, passed value {value}"
-            )
-        super().put(value)
-
-
-class PerfGateNoiseFloorS(EnvironmentVariable, type=float):
-    """Absolute noise floor (seconds) for the perf-history gate: a wall
-    within this many seconds of the best recorded wall never fails the
-    gate, regardless of the ratio.  Sub-millisecond op walls on a shared
-    CPU substrate are timer-jitter-dominated — a 0.8ms-vs-1.4ms delta is
-    scheduler noise, not a regression — so the ratio tolerance only
-    applies once the absolute delta clears this floor."""
-
-    varname = "MODIN_TPU_PERF_GATE_NOISE_FLOOR_S"
-    default = 0.005
-
-    @classmethod
-    def put(cls, value: float) -> None:
-        if value < 0.0:
-            raise ValueError(
-                f"Perf gate noise floor should be >= 0, passed value {value}"
-            )
-        super().put(value)
 
 
 class ServingEnabled(EnvironmentVariable, type=bool):
@@ -1722,24 +1624,6 @@ class DocModule(EnvironmentVariable, type=ExactStr):
 
     varname = "MODIN_TPU_DOC_MODULE"
     default = "pandas"
-
-
-class ReadSqlEngine(EnvironmentVariable, type=str):
-    """Engine to use when reading SQL tables."""
-
-    varname = "MODIN_TPU_READ_SQL_ENGINE"
-    choices = ("Pandas", "Connectorx")
-    default = "Pandas"
-
-
-class StateId(EnvironmentVariable, type=ExactStr):
-    """Unique id of this session (used for log directories)."""
-
-    varname = "MODIN_TPU_STATE_ID"
-
-    @classmethod
-    def _get_default(cls) -> str:
-        return secrets.token_hex(8)
 
 
 def _register_builtin_backends() -> None:

@@ -19,8 +19,8 @@ every TYPE must be a known meter kind, and histogram bucket counts must be
 cumulative and monotonic.
 
 :func:`meter_rollup` compresses a snapshot into the small headline dict
-bench.py attaches to every streamed section line (dispatches, compiles,
-bytes parsed, cache hits, spills).
+scripts/metrics_smoke.py checks against its baseline (dispatches,
+compiles, bytes parsed, cache hits, spills).
 """
 
 from __future__ import annotations
@@ -178,12 +178,12 @@ def parse_prometheus(text: str) -> Dict[str, dict]:
 
 
 def meter_rollup(snapshot: Optional[dict] = None) -> dict:
-    """Headline counters from a snapshot (bench.py's per-section line).
+    """Headline counters from a snapshot (scripts/metrics_smoke.py).
 
     ``{dispatches, compiles, compile_s, bytes_parsed, io_reads, spills,
     cache_hits: {fused, sorted_rep, plan_scan}, api_calls}`` — everything
-    defaults to 0 so section lines are schema-stable whether or not the
-    section touched a given subsystem.
+    defaults to 0 so the dict is schema-stable whether or not the
+    workload touched a given subsystem.
 
     ``bytes_parsed`` sums ``io.read.bytes``, which bills the SOURCE file
     size per physical read (best-effort, FileDispatcher): it measures how

@@ -390,9 +390,9 @@ class MeterRegistry:
     def reset(self) -> None:
         with self._lock:
             self._meters.clear()
-            # the kind-resolution cache too: per-section reset cycles
-            # (bench.py) with rotating interpolated names would otherwise
-            # grow it without bound
+            # the kind-resolution cache too: repeated reset cycles with
+            # rotating interpolated names would otherwise grow it without
+            # bound
             self._kinds.clear()
             self._dropped = 0
             self._dropped_names.clear()

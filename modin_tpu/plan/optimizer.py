@@ -16,7 +16,7 @@ rewriting is profitable *mid-query* — this module is both halves:
   node with a :class:`NodeStrategy` — estimated rows/bytes/seconds from
   the calibrated coefficients (kernel-router table via
   :func:`~modin_tpu.ops.router.calibration_peek`, graftcost substrate
-  peaks, PERF_HISTORY priors) plus the jointly-consistent strategy legs.
+  peaks, :data:`DEFAULT_PRIORS`) plus the jointly-consistent strategy legs.
 - the existing routers stay the per-leg cost providers AND the live
   deciders: each ``decide_*`` offers its verdict through the
   ``router._opt_consult`` hook, and the optimizer overrides it only where
@@ -37,19 +37,16 @@ rewriting is profitable *mid-query* — this module is both halves:
 
 The deterministic row floors (``*_MIN_ROWS``) and forced modes always
 win: the consult hook is only offered verdicts whose reason is a genuine
-cost-model/auto outcome, so tests and bench legs that pin a side, and
+cost-model/auto outcome, so tests and smoke legs that pin a side, and
 tiny unit-test frames, never observe the optimizer at all.
 """
 
 from __future__ import annotations
 
-import json
-import os
 import threading
 import time
 from typing import Any, Dict, List, Optional, Set, Tuple
 
-from modin_tpu.concurrency import named_lock
 from modin_tpu.logging.metrics import emit_metric
 from modin_tpu.observability import spans as graftscope
 from modin_tpu.ops import calibration as calstore
@@ -81,9 +78,9 @@ REPLAN_NOISE_FLOOR_S = 0.005
 #: and the correction must still be able to flip the affected crossovers
 MAX_CORRECTION = 1e6
 
-#: fallback coefficients when neither calibration, substrate peaks, nor
-#: PERF_HISTORY priors cover a node family (conservative CPU-substrate
-#: figures; any measured source immediately supersedes them)
+#: fallback coefficients when neither calibration nor substrate peaks
+#: cover a node family (conservative CPU-substrate figures; any measured
+#: source immediately supersedes them)
 DEFAULT_PRIORS: Dict[str, float] = {
     "parse_bytes_per_s": 120e6,
     "mem_bytes_per_s": 2e9,
@@ -95,11 +92,8 @@ OPT_ON: bool = True
 _alloc_count = 0
 _tls = threading.local()
 
-_priors_lock = named_lock("plan.optimizer")
-#: None = not yet resolved; False = no history available; dict = priors.
-#: set_priors installs a forced table (tests, the adversarial bench leg).
-_priors: Any = None
-_priors_forced = False
+#: the table :func:`set_priors` forced, else None (DEFAULT_PRIORS)
+_priors: Optional[Dict[str, Any]] = None
 
 
 def opt_alloc_count() -> int:
@@ -179,96 +173,17 @@ def _on_opt_mode(param: Any) -> None:
 
 
 def set_priors(priors: Optional[Dict[str, Any]]) -> None:
-    """Force the PERF_HISTORY priors (tests, the adversarial bench leg)
-    or reset to lazy resolution (None)."""
-    global _priors, _priors_forced
-    with _priors_lock:
-        _priors = priors if priors is not None else None
-        _priors_forced = priors is not None
-
-
-def default_history_path() -> Optional[str]:
-    """The repo-root ``PERF_HISTORY.json`` when running from a checkout
-    (bench / CI); installed packages have no ledger and return None."""
-    here = os.path.dirname(os.path.dirname(os.path.dirname(__file__)))
-    path = os.path.join(here, "PERF_HISTORY.json")
-    return path if os.path.exists(path) else None
-
-
-def priors_from_history(path: Optional[str] = None) -> Optional[dict]:
-    """Cost-model priors seeded from the PERF_HISTORY ledger.
-
-    Recorded per-op walls become per-row coefficients (the op's own scale
-    key selects the row count it was measured at, exactly as the
-    regression gate compares them); later runs supersede earlier ones, so
-    the model measurably tracks its own workload across rounds.  Derived
-    crossover seeds:
-
-    - ``reduce_s_per_row`` / ``sortred_s_per_row`` / ``groupby_s_per_row``
-      from the headline ``sum`` / ``median`` / ``gb_sum`` walls;
-    - ``sort_s_per_row`` from the graftsort ``gs_*`` family;
-    - ``scan_s_per_row`` from the graftstream ``oocore_stream`` wall.
-
-    Returns None when no ledger is readable (the model runs on
-    :data:`DEFAULT_PRIORS`).
-    """
-    from modin_tpu.observability import perf_history as ph
-
-    if path is None:
-        path = default_history_path()
-    if path is None:
-        return None
-    try:
-        with open(path) as f:
-            ledger = json.load(f)
-    except (OSError, ValueError):
-        return None
-    runs = ledger.get("runs") if isinstance(ledger, dict) else None
-    if not isinstance(runs, list):
-        return None
-    s_per_row: Dict[str, float] = {}
-    for run in runs:
-        if not isinstance(run, dict):
-            continue
-        scale = run.get("scale")
-        scale = scale if isinstance(scale, dict) else {}
-        for op, entry in (run.get("ops") or {}).items():
-            wall = (entry or {}).get("modin_tpu_s")
-            if not isinstance(wall, (int, float)) or wall <= 0:
-                continue
-            field = ph._op_scale_field(op)
-            rows = scale.get(field) if field else None
-            if rows is None:
-                rows = scale.get("rows", run.get("rows"))
-            if isinstance(rows, (int, float)) and rows > 0:
-                s_per_row[op] = float(wall) / float(rows)
-    if not s_per_row:
-        return None
-    priors: Dict[str, Any] = dict(DEFAULT_PRIORS)
-    priors["s_per_row"] = s_per_row
-    for key, candidates in (
-        ("reduce_s_per_row", ("sum", "mean")),
-        ("sortred_s_per_row", ("median", "nunique", "mode1")),
-        ("groupby_s_per_row", ("gb_sum", "gb_mean", "groupby_sum")),
-        ("sort_s_per_row", ("gs_median", "gs_sort", "sort_values")),
-        ("scan_s_per_row", ("oocore_stream", "oocore_serial")),
-    ):
-        for op in candidates:
-            if op in s_per_row:
-                priors[key] = s_per_row[op]
-                break
-    priors["source"] = path
-    return priors
+    """Force the coefficient table every later plan is estimated with, or
+    (None) go back to :data:`DEFAULT_PRIORS`.  The fault-injection hook of
+    tests/test_optimizer.py and scripts/optimizer_smoke.py: the
+    ``*_s_per_row`` keys ``_estimate_nodes`` / ``_reduce_cost`` read exist
+    only so that a forced table can poison an estimate."""
+    global _priors
+    _priors = priors
 
 
 def _resolve_priors() -> Dict[str, Any]:
-    global _priors
-    with _priors_lock:
-        if _priors is not None:
-            return _priors if _priors is not False else dict(DEFAULT_PRIORS)
-        resolved = priors_from_history()
-        _priors = resolved if resolved is not None else False
-        return _priors if _priors is not False else dict(DEFAULT_PRIORS)
+    return _priors or dict(DEFAULT_PRIORS)
 
 
 # ---------------------------------------------------------------------- #
@@ -363,7 +278,6 @@ def _estimate_nodes(
     )
     parse_bw = float(priors.get("parse_bytes_per_s") or 120e6)
     bytes_per_row = float(priors.get("bytes_per_row") or 64.0)
-    s_per_row = priors.get("s_per_row") or {}
 
     est: Dict[int, dict] = {}
     for node in walk(root):
@@ -412,9 +326,7 @@ def _estimate_nodes(
             if nbytes is not None:
                 own_s = nbytes / mem_bw
         elif isinstance(node, Reduce):
-            own_s = _reduce_cost(
-                node, rows, nbytes, table, priors, mem_bw, s_per_row
-            )
+            own_s = _reduce_cost(node, rows, nbytes, table, priors, mem_bw)
             rows, nbytes = 1, 8
         elif isinstance(node, GroupbyAgg):
             coeff = priors.get("groupby_s_per_row")
@@ -450,7 +362,6 @@ def _reduce_cost(
     table: Optional[Dict[str, float]],
     priors: Dict[str, Any],
     mem_bw: float,
-    s_per_row: Dict[str, float],
 ) -> float:
     """One reduction's own estimated seconds (the cheaper of the kernel
     router's predicted sides when the family is sort-shaped and a

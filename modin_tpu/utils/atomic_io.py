@@ -1,8 +1,8 @@
 """Crash-safe file writes: ONE temp-file + fsync + atomic-rename helper.
 
 Every on-disk artifact the package folds across process lifetimes —
-router calibration tables, cached substrate peaks, PERF_HISTORY.json,
-flight-recorder dumps, graftwal checkpoints — used to hand-roll its own
+router calibration tables, cached substrate peaks, flight-recorder
+dumps, graftwal checkpoints — used to hand-roll its own
 write path, and most of them were plain ``open(path, "w")`` writes: a
 crash (or ENOSPC) mid-write leaves truncated JSON that poisons every
 future run that loads it.  The fix is the classic three-step dance, done

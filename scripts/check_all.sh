@@ -2,7 +2,7 @@
 # One-command multi-execution verification (VERDICT r4 item 6; mirrors the
 # reference CI's one-run-per-engine matrix, .github/workflows/ci.yml:369-399):
 #
-#   ./scripts/check_all.sh            # all twenty-one gates, fail on any red
+#   ./scripts/check_all.sh            # all nineteen gates, fail on any red
 #   FAST=1 ./scripts/check_all.sh     # -x (stop at first failure) per gate
 #
 # Gates:
@@ -17,10 +17,6 @@
 #       mid-query DeviceLost must complete bit-exact with recovery.*
 #       metrics > 0, and a RESOURCE_EXHAUSTED burst must be absorbed by
 #       evict-then-retry without any pandas fallback
-#   0d. bench smoke: a reduced-scale `python bench.py` must exit 0 under a
-#       hard timeout with one valid JSON line per section and a parseable
-#       aggregate — a bench that cannot finish can never ship again
-#       (round-5's rc=124-with-empty-output failure mode)
 #   0e. graftplan smoke: read_csv(...).query(...)[cols].agg(...) under
 #       MODIN_TPU_PLAN=Auto must be bit-exact vs eager and pandas, take
 #       <= 2 compile-ledger dispatches for the device leg, and provably
@@ -35,12 +31,6 @@
 #       DeviceLost + OOM bursts with tight deadlines — zero hangs (global
 #       watchdog), every query bit-exact or a typed QueryRejected/
 #       DeadlineExceeded, deadline overshoot bounded, serving.* metrics > 0
-#   0h. perf-history smoke: PERF_HISTORY.json must re-seed byte-identically
-#       from the BENCH_r0*.json round files, PERF.md's per-op tables must
-#       regenerate byte-identically from the ledger, an honest reduced-scale
-#       bench run must fold through the regression gate green (with git-SHA/
-#       substrate/version provenance on every streamed line), and a 2x wall
-#       inflation of the same run must be rejected
 #   0i. graftmesh spmd smoke: traced sharded sort + merge-join over the
 #       all_to_all shuffle on the 8-device mesh must be bit-exact vs
 #       pandas, the compiled kernel's HLO must carry an all-to-all op
@@ -119,11 +109,9 @@ run_gate() {
 run_gate "graftlint"       python -m modin_tpu.lint modin_tpu/
 run_gate "graftscope"      python scripts/trace_smoke.py
 run_gate "graftguard"      python scripts/chaos_smoke.py
-run_gate "bench_smoke"     python scripts/bench_smoke.py
 run_gate "graftplan"       python scripts/plan_smoke.py
 run_gate "graftmeter"      python scripts/metrics_smoke.py
 run_gate "graftgate"       python scripts/serving_smoke.py
-run_gate "perf_history"    python scripts/perf_history_smoke.py
 run_gate "graftmesh"       python scripts/spmd_smoke.py
 run_gate "graftstream"     python scripts/oocore_smoke.py
 run_gate "graftview"       python scripts/views_smoke.py
@@ -142,4 +130,4 @@ if [ "${#fails[@]}" -ne 0 ]; then
   echo "RED gates: ${fails[*]}"
   exit 1
 fi
-echo "ALL TWENTY-ONE GATES GREEN"
+echo "ALL NINETEEN GATES GREEN"

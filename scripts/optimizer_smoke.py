@@ -141,7 +141,6 @@ def main() -> int:
             "sortred_s_per_row": 1e-12,
             "parse_bytes_per_s": 1e15,
             "mem_bytes_per_s": 1e15,
-            "s_per_row": {},
         }
     )
     try:

