@@ -156,11 +156,13 @@ def _jit_scatter_counts(width: int):
 def _histogram_form(ids, width: int) -> str:
     """The device form of a histogram of ``ids`` in [0, width), read from the
     width, the platform and the shard count.  An XLA scatter-add serialises on
-    a TPU (14.7 s at 1e8 rows), so there the Pallas one-hot kernel counts up to
-    its ``MAX_GROUPS`` ids and the sorted tiles (no value column) a wider range
-    of an unsharded key; the scatter is left with the CPU, where it is fine,
-    and with a wide range of a row-sharded key.  (Under the test hook the
-    Pallas kernel is chosen off the chip too, and runs in interpret mode.)"""
+    a TPU (14.7 s at 1e8 rows), so there the Pallas kernel counts up to its
+    ``MAX_GROUPS`` ids on the MXU (the one-hots of an id's two digits,
+    contracted over the rows: exact, and under ``shard_map`` + ``psum`` over a
+    row-sharded key) and the sorted tiles (no value column) a wider range of
+    an unsharded key; the scatter is left with the CPU, where it is fine, and
+    with a wide range of a row-sharded key.  (Under the test hook the Pallas
+    kernel is chosen off the chip too, and runs in interpret mode.)"""
     from modin_tpu.ops.pallas.groupby_kernels import MAX_GROUPS, bincount_supported
     from modin_tpu.parallel.mesh import num_row_shards
 

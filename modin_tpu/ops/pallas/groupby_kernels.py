@@ -3,9 +3,12 @@
 ``bincount``: the histogram that backs factorize's direct-range coding and the
 ``size``/``count`` aggregations.  XLA lowers ``zeros().at[codes].add(1)`` to a
 scatter-add, which serializes badly on TPU (measured ~1s for 1e7 rows); this
-kernel instead streams code blocks through VMEM and accumulates a one-hot
-compare on the VPU — O(n*G) elementwise work with no scatter, exact int32
-arithmetic.
+kernel instead streams blocks of codes through VMEM and counts them as an
+exact contraction on the MXU: an id is cut into two digits, each digit's
+one-hot ``[digit ids, t]`` is built on the VPU as bf16, and the two are
+contracted over the ``t`` data rows into ``[high, low]`` f32 counts, flushed
+into int32 every grid step.  No scatter, no reduction on the VPU, and the
+codes are read where they lie (as rows of 128: no padded or re-tiled copy).
 
 Used on the TPU backend for group widths <= ``MAX_GROUPS``; everywhere else
 the XLA scatter path stays (CPU scatters are fine).  Interpret mode makes the
@@ -30,53 +33,120 @@ import numpy as np
 
 from modin_tpu.ops._program import named_jit
 
-# block of codes processed per grid step: BR sublanes x 128 lanes
-_BR = 32
 _LANES = 128
-MAX_GROUPS = 512  # one-hot block is BR*128*ceil(G/128)*128 ints in VMEM
+# rows of a bf16 matmul operand a tile of sublanes (and of the limb matrix a
+# piece, below)
+PIECE_ROWS = 16
+# the histogram's view of the codes: rows of 128 (a reshape that moves no
+# byte), HIST_STEP_ROWS of them a grid step and HIST_TURN_ROWS a turn of the
+# kernel's loop (a turn waits once for the MXU's results, about 0.1 us: at
+# 1e8 rows and 100 ids 2 rows a turn take 44 ms, 8 take 12.7, 32 take 5.2,
+# 128 take 3.6 and 512 take 3.2); an id is two digits, the low one
+# HIST_LOW_IDS wide
+HIST_STEP_ROWS = 2048
+HIST_TURN_ROWS = 128
+HIST_LOW_IDS = 16
+# widest range the histogram takes (the wider ones go to the sorted tiles).
+# VMEM is no limit here: at 512 ids a turn's one-hots are [128, 32 + 16, 128]
+# bf16 (1.5 MB, twice that as the int32 they are packed from) beside two
+# buffers of 2048 x 128 codes (2 MB) and [32, 16] counts.  The high digit's
+# one-hot grows with the range: 3.7 ms up to 256 ids, 5.6 at 512 (1e8 rows)
+MAX_GROUPS = 512
 
 
 @functools.lru_cache(maxsize=None)
-def _build_bincount(n_blocks: int, g_padded: int, interpret: bool):
+def _build_bincount(n_rows: int, hi_rows: int, interpret: bool):
+    """The histogram of ``[n_rows, 128]`` int32 (or uint32) codes as an exact
+    contraction on the MXU: int32 ``[hi_rows, HIST_LOW_IDS]``, the count of id
+    ``g`` at ``[g // HIST_LOW_IDS, g % HIST_LOW_IDS]``.
+
+    A histogram factors where a sum does not: with ``g = hi * 16 + lo``,
+    ``counts[hi, lo] = sum_t onehot(hi_t)[hi] * onehot(lo_t)[lo]``, one
+    contraction over the data rows ``t`` of two narrow one-hots (16 + 16
+    sublanes a data row for up to 256 ids, 32 + 16 for 512) in place of one
+    of 128 sublanes a block of 128 ids against a row of ones: the compares
+    that build a one-hot are the VPU's cost, the MXU's is hidden under them.
+    Products are 0 or 1 and a step holds fewer than 2**24 rows, so the f32
+    partial counts are exact; they are flushed into the int32 output every
+    step.  A code outside ``[0, hi_rows * 16)`` has no high digit here and
+    counts for nothing.
+    """
     import jax
     import jax.numpy as jnp
+    from jax import lax
     from jax.experimental import pallas as pl
-
     from jax.experimental.pallas import tpu as pltpu
 
-    def kernel(codes_ref, out_ref):
+    low_ids, turn, step_rows = HIST_LOW_IDS, HIST_TURN_ROWS, HIST_STEP_ROWS
+    assert hi_rows % PIECE_ROWS == 0 and step_rows % turn == 0
+    assert step_rows * _LANES < 1 << 24
+    # (constants are numpy int32 by name: with x64 on a Python literal traces
+    # as a weak 64-bit value, which Mosaic cannot lower.  ``lax``'s operations
+    # where ``jax.numpy``'s would do, and one loop body: a new process traces
+    # the kernel before its first request, and ``jnp`` wrappers, traced cold,
+    # cost that request 0.1 s)
+    i32 = np.int32
+
+    def onehot(digit, n):
+        """bf16 ``[turn, n, 128]`` of a ``[turn, 128]`` digit: its ids on the
+        sublanes, the data rows on the lanes."""
+        shape = (turn, n, _LANES)
+        hit = lax.eq(
+            lax.broadcast_in_dim(digit, shape, (0, 2)),
+            lax.broadcasted_iota(jnp.int32, shape, 1),
+        )
+        # bf16's 1.0 is 0x3F80: two 32-bit rows pack into one of 16-bit
+        # halves in one operation, where a float32 one-hot converted to
+        # bf16 takes five
+        word = lax.select(hit, lax.full(shape, i32(0x3F80)), lax.full(shape, i32(0)))
+        return lax.bitcast_convert_type(lax.convert_element_type(word, jnp.int16), jnp.bfloat16)
+
+    def kernel(codes_ref, out_ref, acc_ref):
         i = pl.program_id(0)
 
         @pl.when(i == 0)
         def _init():
-            out_ref[:] = jnp.zeros_like(out_ref)
+            out_ref[...] = jnp.zeros_like(out_ref)
 
-        codes_block = codes_ref[:]  # [_BR, _LANES] int32
-        group_ids = jax.lax.broadcasted_iota(
-            jnp.int32, (1, 1, g_padded), dimension=2
-        )
-        onehot = (codes_block[:, :, None] == group_ids).astype(jnp.int32)
-        # pin the accumulation dtype: with x64 enabled jnp.sum follows numpy
-        # and widens int32 sums to int64, which TPU pallas cannot lower
-        partial = jnp.sum(onehot, axis=(0, 1), dtype=jnp.int32)  # [g_padded]
-        out_ref[0, :] += partial
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        # the last step's block reaches past the codes: its turns stop with
+        # them, and the rows of its last turn past them count for nothing
+        rows_here = lax.min(i32(step_rows), i32(n_rows) - i * i32(step_rows))
 
-    block_spec_kwargs = {"memory_space": pltpu.VMEM}
+        def contract(j, carry):
+            first = pl.multiple_of(j * i32(turn), turn)
+            row = first + lax.broadcasted_iota(jnp.int32, (turn, _LANES), 0)
+            codes = lax.bitcast_convert_type(codes_ref[pl.ds(first, turn), :], jnp.int32)
+            codes = lax.select(row < rows_here, codes, lax.full(codes.shape, i32(-1)))
+            # one matmul a row of 128 codes (the batch), contracted over the
+            # lanes; the MXU's results are waited for once a turn
+            counts = lax.dot_general(
+                onehot(lax.shift_right_arithmetic(codes, i32(low_ids.bit_length() - 1)), hi_rows),
+                onehot(lax.bitwise_and(codes, i32(low_ids - 1)), low_ids),
+                (((2,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32,
+            )
+            acc_ref[...] += lax.reduce_sum(counts, (0,))
+            return carry
+
+        lax.fori_loop(i32(0), lax.div(rows_here + i32(turn - 1), i32(turn)), contract, i32(0))
+        out_ref[...] += acc_ref[...].astype(jnp.int32)
+
     # index maps must yield int32: with x64 enabled a literal 0 traces as a
     # weak int64 and Mosaic refuses the (i32, i64) index tuple
     zero = np.int32(0)
+    vmem = {"memory_space": pltpu.VMEM}
     return pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((1, g_padded), jnp.int32),
-        grid=(n_blocks,),
-        in_specs=[
-            pl.BlockSpec((_BR, _LANES), lambda i: (i, zero), **block_spec_kwargs)
-        ],
-        out_specs=pl.BlockSpec(
-            (1, g_padded), lambda i: (zero, zero), **block_spec_kwargs
-        ),
+        out_shape=jax.ShapeDtypeStruct((hi_rows, low_ids), jnp.int32),
+        grid=(-(-n_rows // step_rows),),
+        in_specs=[pl.BlockSpec((step_rows, _LANES), lambda i: (i, zero), **vmem)],
+        out_specs=pl.BlockSpec((hi_rows, low_ids), lambda i: (zero, zero), **vmem),
+        scratch_shapes=[pltpu.VMEM((hi_rows, low_ids), jnp.float32)],
+        # every step adds into the one output block
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
-        name="groupby_bincount_kernel",
+        name="groupby_onehot_dot_kernel",
     )
 
 
@@ -94,21 +164,25 @@ def _bincount_fn(p_len: int, num_groups: int, interpret: bool, mesh: Any = None)
 
     n_shards = 1 if mesh is None else int(mesh.shape["rows"])
     local_len = p_len // n_shards
-    # slots for every real group + the overflow bucket, padded to lanes
-    g_padded = max(-(-(num_groups + 1) // _LANES) * _LANES, _LANES)
-    block_elems = _BR * _LANES
-    n_blocks = -(-local_len // block_elems)
-    padded_len = n_blocks * block_elems
-    call = _build_bincount(n_blocks, g_padded, interpret)
+    # high digits for every real group (the overflow id needs no slot: what
+    # the kernel holds past ``num_groups`` is sliced off, the rest never counted)
+    hi_rows = -(-num_groups // (HIST_LOW_IDS * PIECE_ROWS)) * PIECE_ROWS
+    # the kernel takes whole rows of 128: 1e8 codes are such
+    padded_len = -(-local_len // _LANES) * _LANES
+    n_rows = padded_len // _LANES
+    call = _build_bincount(n_rows, hi_rows, interpret)
 
     def local(codes):
-        c = codes.astype(jnp.int32)
+        # 32-bit codes go in as they are and a 64-bit code's low word as the
+        # split leaves it, unsigned (the kernel reads either as int32): a
+        # conversion here would be one more copy of the codes
+        c = codes if codes.dtype.itemsize == 4 else codes.astype(jnp.uint32)
         if padded_len > local_len:
             # overflow bucket: padded tail must not count toward any group
             c = jnp.concatenate(
-                [c, jnp.full(padded_len - local_len, num_groups, jnp.int32)]
+                [c, jnp.full(padded_len - local_len, num_groups, c.dtype)]
             )
-        return call(c.reshape(n_blocks * _BR, _LANES))
+        return call(c.reshape(n_rows, _LANES))
 
     if mesh is None:
         counts_of = local
@@ -129,7 +203,7 @@ def _bincount_fn(p_len: int, num_groups: int, interpret: bool, mesh: Any = None)
         )
 
     def fn(codes):
-        return counts_of(codes)[0, :num_groups].astype(jnp.int64)
+        return counts_of(codes).reshape(-1)[:num_groups].astype(jnp.int64)
 
     return fn
 
@@ -199,8 +273,6 @@ def bincount_supported(codes: Any, num_groups: int, as_on_tpu: bool = False) -> 
 # limb_dot: exact per-group sums as a limbs x one-hot contraction
 # --------------------------------------------------------------------- #
 
-# rows of the limb matrix a piece: one bf16 tile of sublanes
-PIECE_ROWS = 16
 # data rows a contraction (the lanes of one row of the kernel's operands) and
 # a grid step: the f32 sums of a step stay exact while a step holds at most
 # 65 536 rows (a limb is at most 255)

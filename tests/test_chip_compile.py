@@ -132,6 +132,10 @@ def test_kernel_compiles_for_v5e_at_1e8_rows(kernel, arg, shapes):
         # the Mosaic kernel is really in the program, not an XLA scatter
         assert "tpu_custom_call" in lowered.as_text()
     if kernel == "bincount":
+        # the kernel reads the codes through a reshape that moves no byte: the
+        # program holds one int32 copy of the (int64) codes at most, and not a
+        # padded or re-tiled second one beside it
+        assert mem.temp_size_in_bytes < ROWS * 4 + (8 << 20), mem
         return
     if kernel == "sorted_tiles_size":
         # chunks are sorted and nothing scatters
@@ -165,3 +169,32 @@ def test_sharded_bincount_compiles_for_four_v5e_chips(topo, shapes):
     mem = compiled.memory_analysis()
     # each chip holds a quarter of the codes, not the whole vector
     assert mem.argument_size_in_bytes < ROWS * 8 // 4 + (1 << 20)
+
+
+def test_bincount_reduces_its_one_hot_on_the_mxu():
+    """Needs no chip: the histogram kernel's body holds a ``dot_general``, so
+    an edit that puts the reduction back on the VPU fails here and not in a
+    benchmark."""
+    import jax
+    import jax.numpy as jnp
+
+    from modin_tpu.ops.pallas.groupby_kernels import pallas_bincount
+
+    def eqns_of(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for param in eqn.params.values():
+                for sub in param if isinstance(param, (tuple, list)) else (param,):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        yield from eqns_of(sub)
+
+    codes = jnp.zeros(5_000, jnp.int32)
+    traced = jax.make_jaxpr(lambda c: pallas_bincount(c, 100, interpret=True))(codes)
+    kernels = [e for e in eqns_of(traced.jaxpr) if e.primitive.name == "pallas_call"]
+    assert len(kernels) == 1
+    inside = list(eqns_of(kernels[0].params["jaxpr"]))
+    assert "dot_general" in {e.primitive.name for e in inside}
+    # what the VPU still adds up is the MXU's float32 results, never a one-hot
+    summed = [e.invars[0].aval.dtype for e in inside if e.primitive.name == "reduce_sum"]
+    assert all(dtype == jnp.float32 for dtype in summed), summed

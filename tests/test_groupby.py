@@ -218,21 +218,68 @@ def test_groupby_masked_scan_kernel_matches(agg, monkeypatch):
     )
 
 
-def test_pallas_bincount_matches_scatter(monkeypatch):
-    """The pallas histogram must agree with the XLA scatter path (interpret
-    mode exercises the kernel on CPU)."""
+def _hist_step() -> int:
+    """Codes a grid step of the histogram kernel."""
+    from modin_tpu.ops.pallas import groupby_kernels as kernels
+
+    return kernels.HIST_STEP_ROWS * 128
+
+
+# lengths: short of one turn of the kernel's loop, one code short of a whole
+# step, a whole number of steps, several steps and a ragged last one
+_HIST_LENGTHS = {
+    "short": lambda step: 777,
+    "step_less_one": lambda step: step - 1,
+    "two_steps": lambda step: 2 * step,
+    "steps_and_a_bit": lambda step: 2 * step + 12_345,
+}
+
+
+@pytest.mark.parametrize("length", list(_HIST_LENGTHS))
+@pytest.mark.parametrize("width", [1, 100, 127, 128, 129, 256, 257, 512])
+def test_pallas_bincount_matches_scatter(width, length):
+    """The pallas histogram must agree with ``np.bincount`` and the XLA
+    scatter path (interpret mode exercises the kernel on CPU): one and two
+    tiles of high digits (up to 256 ids, then 512), both sides of that edge
+    and of 128; codes at and past ``width`` (pads, NaN keys, the overflow
+    bucket) count for nothing."""
     import jax.numpy as jnp
 
     from modin_tpu.ops.pallas.groupby_kernels import pallas_bincount
     from modin_tpu.ops.groupby import _jit_scatter_counts
 
-    rng = np.random.default_rng(1)
-    for n, width in [(777, 3), (50_000, 100), (12_345, 512)]:
-        ids_np = rng.integers(0, width + 1, n)
-        ids = jnp.asarray(ids_np)
-        got = np.asarray(pallas_bincount(ids, width, interpret=True))
-        want = np.asarray(_jit_scatter_counts(width)(ids))
-        np.testing.assert_array_equal(got, want)
+    n = _HIST_LENGTHS[length](_hist_step())
+    rng = np.random.default_rng(width * 1000 + n % 997)
+    ids_np = rng.integers(0, width + 1, n).astype(np.int32)
+    # codes past the overflow id: the next one, one in the next tile of high
+    # digits, two past every digit the kernel holds
+    ids_np[rng.integers(0, n, 40)] = rng.choice([width + 1, width + 128, 640, 70_000], 40)
+    ids = jnp.asarray(ids_np)
+    got = np.asarray(pallas_bincount(ids, width, interpret=True))
+    assert got.dtype == np.int64 and got.shape == (width,)
+    np.testing.assert_array_equal(got, np.bincount(ids_np, minlength=width)[:width])
+    if length == "short":
+        kept = jnp.asarray(np.minimum(ids_np, width))
+        np.testing.assert_array_equal(got, np.asarray(_jit_scatter_counts(width)(kept)))
+
+
+@pytest.mark.parametrize("dtype", [np.int32, np.int64])
+@pytest.mark.parametrize("width,group", [(1, 0), (100, 99), (300, 128), (512, 511)])
+def test_pallas_bincount_one_group_over_several_steps(width, group, dtype):
+    """Every row in one group, across more than one grid step: a step's f32
+    counts are flushed into the int32 output and add up over the steps; an
+    int64 code vector counts as an int32 one."""
+    import jax.numpy as jnp
+
+    from modin_tpu.ops.pallas.groupby_kernels import pallas_bincount
+
+    n = 2 * _hist_step() + 4_321
+    ids = jnp.full(n, group, dtype)
+    assert ids.dtype == dtype
+    got = np.asarray(pallas_bincount(ids, width, interpret=True))
+    want = np.zeros(width, np.int64)
+    want[group] = n
+    np.testing.assert_array_equal(got, want)
 
 
 def test_pallas_bincount_row_sharded_operand():
