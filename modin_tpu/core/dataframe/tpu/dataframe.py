@@ -10,7 +10,12 @@ grid of pandas-block partitions on worker processes, a frame is:
   "rows" axis — row-partitioning is the sharding spec, SURVEY.md §7) or a
   **HostColumn** (numpy/extension array for object/string dtypes — the
   device/host split that replaces the reference's default-to-pandas partition
-  fallback).
+  fallback).  A ``category`` column is ingested as a HostColumn and becomes
+  a DeviceColumn (``is_category``) the first time a device path reads it (a
+  groupby key): its integer codes on the device at the width pandas holds
+  them (-1 = missing), its ``CategoricalDtype`` on the host.  The frame keeps
+  the resident column from then on, and an answer's category key column is
+  one from the start.
 
 Device columns are **padded** to a multiple of the mesh row-shard count with
 the logical length tracked per column: XLA requires even shards for
@@ -91,6 +96,16 @@ class DeviceColumn:
     ``length`` is the logical row count (data.shape[0] is padded up to a
     multiple of the shard count; pad rows are never read).
 
+    ``pandas_dtype`` is the logical dtype: an ``np.dtype``, or — a **category
+    column** (``is_category``) — the frame's own ``CategoricalDtype``, shared
+    and never copied.  A category column's buffer (``data``, ``host_cache``)
+    holds its integer codes as pandas holds them (int8 up to 127 categories,
+    int16, int32; -1 = missing); that is its one representation.  Row-moving
+    operations and a groupby key take the codes like any integer column;
+    everything that reads *values* has to look at ``is_category`` (or at
+    ``pandas_dtype.kind``, ``"O"`` here) and decline, and the pandas default
+    then answers through ``to_pandas_array`` (one ``Categorical.from_codes``).
+
     ``host_cache`` keeps the original (unpadded) host numpy array for columns
     that came from the host unchanged: it makes device round-trips bit-exact
     even where the accelerator emulates the dtype (TPU f64 is double-float:
@@ -125,7 +140,11 @@ class DeviceColumn:
         # lazy columns materialize on .data access — fusion-aware consumers
         # read .raw instead to keep chains deferred.
         self._data = data
-        self.pandas_dtype = np.dtype(pandas_dtype)
+        self.pandas_dtype = (
+            pandas_dtype
+            if isinstance(pandas_dtype, pandas.CategoricalDtype)
+            else np.dtype(pandas_dtype)
+        )
         self.length = int(length) if length is not None else int(data.shape[0])
         self.host_cache = host_cache
         self._ledger_key = None
@@ -179,6 +198,11 @@ class DeviceColumn:
         return is_lazy(self._data)
 
     @property
+    def is_category(self) -> bool:
+        """The buffer holds a ``CategoricalDtype``'s codes, not values."""
+        return isinstance(self.pandas_dtype, pandas.CategoricalDtype)
+
+    @property
     def is_spilled(self) -> bool:
         """Device buffer dropped; host_cache is the (exact) only copy."""
         return self._data is None
@@ -229,10 +253,10 @@ class DeviceColumn:
         self._invalidate_sorted()
         cache = self.host_cache
         if cache is None:
-            # to_numpy round-trips the logical dtype exactly (and under
+            # the fetch round-trips the logical dtype exactly (and under
             # Downcast the f32 device value widens losslessly), so the
             # host copy reproduces the device buffer bit-for-bit
-            cache = self.to_numpy()
+            cache = self.buffer_to_numpy()
         from modin_tpu.core.memory import device_ledger
 
         freed = device_ledger.deregister(self)
@@ -423,7 +447,7 @@ class DeviceColumn:
         """Pin the exact host copy (lineage depth cut-point): one fetch now
         makes this column depth-0 recoverable forever after."""
         if self.host_cache is None:
-            self.adopt_host_cache(self.to_numpy())
+            self.adopt_host_cache(self.buffer_to_numpy())
 
     @classmethod
     def from_numpy(cls, values: np.ndarray, sharding: Any = None) -> "DeviceColumn":
@@ -434,7 +458,33 @@ class DeviceColumn:
             host_cache=values,
         )
 
+    @classmethod
+    def from_categorical(cls, cat: pandas.Categorical) -> "DeviceColumn":
+        """A category column: pandas' own codes array uploaded as it is (no
+        cast, no copy on the host), the dtype object shared."""
+        codes = np.asarray(cat.codes)
+        return cls(
+            _device_put_values(codes), cat.dtype, length=len(codes), host_cache=codes
+        )
+
     def to_numpy(self) -> np.ndarray:
+        """The column's values on the host (a category column's decoded, as
+        ``HostColumn.to_numpy`` gives them)."""
+        if self.is_category:
+            return np.asarray(self.to_pandas_array())
+        return self.buffer_to_numpy()
+
+    def to_pandas_array(self) -> Any:
+        """What ``to_pandas`` puts in the frame: the values, or for a category
+        column the ``Categorical`` of the fetched codes (the one decode)."""
+        values = self.buffer_to_numpy()
+        if self.is_category:
+            return pandas.Categorical.from_codes(values, dtype=self.pandas_dtype)
+        return values
+
+    def buffer_to_numpy(self) -> np.ndarray:
+        """The buffer's logical rows on the host, exactly: values, or a
+        category column's codes."""
         from modin_tpu.parallel.engine import JaxWrapper
 
         cache = self.host_cache  # single read: eviction may race us
@@ -452,6 +502,8 @@ class DeviceColumn:
                 raise
             # the column was re-seated from lineage: one fetch retry
             values = np.asarray(JaxWrapper.materialize(self.data))[: self.length]
+        if self.is_category:
+            return values
         if self.pandas_dtype.kind in "mM":
             values = values.view(self.pandas_dtype)
         elif values.dtype != self.pandas_dtype:
@@ -476,13 +528,15 @@ class DeviceColumn:
 
 
 class HostColumn:
-    """One column kept on host (object/string/categorical/extension dtypes).
+    """One column kept on host (object/string/extension dtypes, and a
+    ``category`` column until a device path first reads its codes).
 
     ``_dict_cache`` lazily holds the column's dictionary encoding — (codes
     DeviceColumn, SORTED categories) — or False once found unencodable (see
-    ops/dictionary.py).  ``_cat_cache`` is the separate cache for the
-    categorical-dtype encoding, whose categories keep CATEGORY order — the
-    two orderings must never be served to each other's consumers.  Columns
+    ops/dictionary.py).  ``_cat_cache`` holds a categorical column's resident
+    form once made (``ops/dictionary.py`` ``resident_category_column``: a
+    category :class:`DeviceColumn` of pandas' own codes, in CATEGORY order —
+    never served to the sorted dictionary's consumers), or False.  Columns
     are replaced, never mutated in place, so the caches cannot go stale.
     """
 
@@ -490,6 +544,7 @@ class HostColumn:
     # pin cached results to the exact live column objects via weakrefs
     __slots__ = ("data", "_dict_cache", "_cat_cache", "__weakref__")
     is_device = False
+    is_category = False  # a DeviceColumn's flag: codes in the device buffer
 
     def __init__(self, data: Any):
         # data: 1-D numpy array or pandas ExtensionArray (unpadded)
@@ -516,6 +571,25 @@ class HostColumn:
 
 
 Column = Union[DeviceColumn, HostColumn]
+
+
+def _same_code_tables(cols: List[DeviceColumn]) -> bool:
+    """Whether device columns' buffers mean the same thing row for row: no
+    category column among them, or all of them with the same categories in the
+    same order (equal unordered ``CategoricalDtype``s may number them
+    differently)."""
+    if not any(c.is_category for c in cols):
+        return True
+    first = cols[0].pandas_dtype
+    return all(
+        c.is_category
+        and c.pandas_dtype.ordered == first.ordered
+        and (
+            c.pandas_dtype.categories is first.categories
+            or c.pandas_dtype.categories.equals(first.categories)
+        )
+        for c in cols
+    )
 
 
 class TpuDataframe(BaseDataframe, ClassLogger, modin_layer="CORE-FRAME"):
@@ -580,7 +654,7 @@ class TpuDataframe(BaseDataframe, ClassLogger, modin_layer="CORE-FRAME"):
         data = {}
         for i, col in enumerate(self._columns):
             if col.is_device:
-                data[i] = col.to_numpy()
+                data[i] = col.to_pandas_array()
             else:
                 arr = col.to_pandas_array()
                 if _is_object_dtype(getattr(arr, "dtype", None)):
@@ -638,7 +712,9 @@ class TpuDataframe(BaseDataframe, ClassLogger, modin_layer="CORE-FRAME"):
 
     @property
     def all_device(self) -> bool:
-        return all(col.is_device for col in self._columns)
+        """Every column holds values on the device (no host column, and no
+        category column, whose buffer holds codes)."""
+        return all(col.is_device and not col.is_category for col in self._columns)
 
     def copy(self) -> "TpuDataframe":
         return TpuDataframe(
@@ -836,6 +912,7 @@ class TpuDataframe(BaseDataframe, ClassLogger, modin_layer="CORE-FRAME"):
         device_ok = [
             all(f._columns[ci].is_device for f in frames)
             and len({f._columns[ci].data.dtype for f in frames}) == 1
+            and _same_code_tables([f._columns[ci] for f in frames])
             for ci in range(self.num_cols)
         ]
         new_columns: List[Column] = [None] * self.num_cols
@@ -865,6 +942,23 @@ class TpuDataframe(BaseDataframe, ClassLogger, modin_layer="CORE-FRAME"):
                 new_columns[ci] = new_col
         for ci in range(self.num_cols):
             if device_ok[ci]:
+                continue
+            if any(f._columns[ci].is_category for f in frames):
+                # category columns whose code tables differ: pandas recodes
+                # (or leaves object) through one decode of each
+                merged = pandas.concat(
+                    [
+                        pandas.Series(f._columns[ci].to_pandas_array())
+                        for f in frames
+                    ],
+                    ignore_index=True,
+                ).array
+                if isinstance(merged.dtype, pandas.CategoricalDtype):
+                    new_columns[ci] = DeviceColumn.from_categorical(merged)
+                else:
+                    if isinstance(merged, pandas.arrays.NumpyExtensionArray):
+                        merged = np.asarray(merged)
+                    new_columns[ci] = HostColumn(merged)
                 continue
             values = np.concatenate(
                 [np.asarray(f._columns[ci].to_numpy()) for f in frames]

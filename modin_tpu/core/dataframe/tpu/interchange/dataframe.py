@@ -199,9 +199,9 @@ class TpuDataFrameXchg:
 
     def _make_column(self, position: int):
         col = self._frame._columns[position]
-        if col.is_device:
+        if col.is_device and not col.is_category:
             return TpuColumnXchg(col, self._allow_copy)
-        # host (string/categorical/extension) columns: pandas' own protocol
+        # host (string/extension) and category columns: pandas' own protocol
         # column handles variable-width layouts; one column, not the frame
         label = self._frame.columns[position]
         return (

@@ -57,7 +57,8 @@ class _IoReplay:
                 )
             cache = (
                 epoch,
-                [c.to_numpy() if c.is_device else None for c in columns],
+                # the buffers' own rows (a category column's codes)
+                [c.buffer_to_numpy() if c.is_device else None for c in columns],
             )
             self._cache = cache
             recovery.note_io_replayer(self)  # purged at end of pass

@@ -22,7 +22,7 @@ strategy (SURVEY.md §7 stage 2).
 from __future__ import annotations
 
 import re
-from typing import Any, Hashable, List, Optional
+from typing import Any, Hashable, List, NamedTuple, Optional
 
 import numpy as np
 import pandas
@@ -46,12 +46,29 @@ from modin_tpu.utils import MODIN_UNNAMED_SERIES_LABEL
 _SHUFFLE_APPLY_MIN_ROWS = 1 << 19
 
 
+from modin_tpu.observability import spans as _spans
 from modin_tpu.parallel.engine import materialize as _engine_materialize
 from modin_tpu.parallel.engine import upload as _engine_upload
 from modin_tpu.plan import explain as graftplan_explain
 from modin_tpu.plan import runtime as graftplan
 from modin_tpu import streaming as graftstream
 from modin_tpu import views as graftview
+
+
+class _GroupbyParts(NamedTuple):
+    """The kernels' answer to a device groupby before it is given the caller's
+    shape (``TpuQueryCompiler._groupby_parts`` / ``_assemble_groupby``)."""
+
+    datas: list  # a device array a value column: G rows in key order, padded
+    out_dtypes: list
+    value_labels: pandas.Index
+    value_decoders: list  # (categories, dtype) where a result is a dictionary code
+    codes: Any  # factorize_keys' row codes
+    n_groups: int
+    group_keys: list  # a host array a key level, in key order
+    key_labels: list
+    key_decoders: list  # None | ("cat", CategoricalDtype) | dictionary categories
+    key_dtypes: list  # the key columns' own dtypes
 
 
 def _decide_windowed(op: str, frames: tuple) -> bool:
@@ -533,7 +550,7 @@ class TpuQueryCompiler(BaseQueryCompiler):
         """All columns as concrete device arrays (batch-materializing any
         deferred expressions in one jit), or None if any column is host-only."""
         cols = self._modin_frame._columns
-        if all(c.is_device for c in cols):
+        if all(c.is_device and not c.is_category for c in cols):
             self._modin_frame.materialize_device()
             return [c.data for c in cols]
         return None
@@ -543,7 +560,7 @@ class TpuQueryCompiler(BaseQueryCompiler):
         fusion-aware variant of _device_cols for elementwise/reduction paths
         that extend the lazy chain instead of forcing it."""
         cols = self._modin_frame._columns
-        if all(c.is_device for c in cols):
+        if all(c.is_device and not c.is_category for c in cols):
             return [c.raw for c in cols]
         return None
 
@@ -765,6 +782,8 @@ class TpuQueryCompiler(BaseQueryCompiler):
         device_positions = []
         device_arrays = []
         for i, col in enumerate(frame._columns):
+            if col.is_category:
+                return None  # codes are not values: the pandas default answers
             if col.is_device:
                 if require_kinds is not None and col.pandas_dtype.kind not in require_kinds:
                     return None
@@ -1761,32 +1780,41 @@ class TpuQueryCompiler(BaseQueryCompiler):
         applicable so the caller can fall back."""
         frame = self._modin_frame
         col = frame.get_column(0) if frame.num_cols == 1 else None
-        if col is None or col.is_device or not len(frame):
+        if col is None or not len(frame):
             return None
         if isinstance(col.pandas_dtype, pandas.CategoricalDtype):
-            from modin_tpu.ops.dictionary import encode_categorical_column
+            # the resident integer codes (-1 = missing) serve as they are
+            from modin_tpu.ops.dictionary import resident_category_column
 
-            enc = encode_categorical_column(col)
+            resident = resident_category_column(col)
+            if resident is None:
+                return None
+            frame._columns[0] = resident
+            codes, cats = resident.data, list(col.pandas_dtype.categories)
+        elif col.is_device:
+            return None
         else:
             from modin_tpu.ops.dictionary import encode_host_column
 
             enc = encode_host_column(col)
-        if enc is None or not (0 < len(enc.categories) <= 256):
+            if enc is None:
+                return None
+            # float codes, NaN = missing
+            codes, cats = enc.codes.data, list(enc.categories)
+        if not (0 < len(cats) <= 256):
             return None
         out_dtype = np.dtype(bool) if dtype is None else np.dtype(dtype)
         if out_dtype.kind not in "biuf":
             return None
         import jax.numpy as jnp
 
-        codes = enc.codes.data
         labels: list = []
         cols: list = []
-        cats = list(enc.categories)
         start = 1 if drop_first else 0
         for k, cat in enumerate(cats):
             if k < start:
                 continue
-            data = codes == float(k)
+            data = codes == k
             if out_dtype != np.dtype(bool):
                 data = data.astype(jnp.dtype(out_dtype.name))
             cols.append(DeviceColumn(data, out_dtype, length=len(frame)))
@@ -1794,7 +1822,11 @@ class TpuQueryCompiler(BaseQueryCompiler):
                 f"{prefix}{prefix_sep}{cat}" if prefix is not None else cat
             )
         if dummy_na:
-            data = jnp.isnan(codes)
+            data = (
+                jnp.isnan(codes)
+                if jnp.issubdtype(codes.dtype, jnp.floating)
+                else codes < 0
+            )
             if out_dtype != np.dtype(bool):
                 data = data.astype(jnp.dtype(out_dtype.name))
             cols.append(DeviceColumn(data, out_dtype, length=len(frame)))
@@ -2706,7 +2738,7 @@ class TpuQueryCompiler(BaseQueryCompiler):
             and thresh is None
             and not kwargs.get("ignore_index", False)
             and len(frame) > 0
-            and all(c.is_device for c in frame._columns)
+            and all(c.is_device and not c.is_category for c in frame._columns)
         ):
             if subset is not None:
                 from pandas.api.types import is_list_like
@@ -2943,6 +2975,8 @@ class TpuQueryCompiler(BaseQueryCompiler):
         # extension dtypes keep the pandas fallback
         for fr in (lframe, rframe):
             for c in fr._columns:
+                if c.is_category:
+                    return None
                 if not c.is_device and not (
                     pandas.api.types.is_object_dtype(c.pandas_dtype)
                     or isinstance(c.pandas_dtype, pandas.StringDtype)
@@ -4303,14 +4337,17 @@ class TpuQueryCompiler(BaseQueryCompiler):
         """agg(["sum", "mean"]) / agg({"col": "sum"}) on device: one
         factorization (memoized), one segment kernel per aggregation, columns
         combined like pandas (MultiIndex (col, agg) for lists, flat for
-        dicts).  The factorize cache makes the per-agg passes cheap."""
-        if not groupby_kwargs.get("as_index", True):
-            return None  # key-column reinsertion differs per layout
+        dicts).  The factorize cache makes the per-agg passes cheap.
+
+        ``sort=False`` and ``as_index=False`` (the H2O script's phrasing) are
+        honoured once for the combined answer by :meth:`_assemble_groupby`:
+        the aggregations' key-sorted rows are gathered into order of first
+        appearance, and the key columns go in front, resident, under a
+        ``RangeIndex`` (a list ``agg`` pads their labels to ``(key, "")``)."""
 
         def run_one(func, sel):
-            return self._try_device_groupby(
-                by, func, axis, groupby_kwargs, agg_args, agg_kwargs, drop,
-                series_groupby, sel,
+            return self._groupby_parts(
+                by, func, axis, groupby_kwargs, agg_args, agg_kwargs, drop, sel,
             )
 
         if (
@@ -4326,59 +4363,82 @@ class TpuQueryCompiler(BaseQueryCompiler):
                 if part is None:
                     return None  # bail before running the remaining kernels
                 parts.append(part)
-            frames = [p._modin_frame for p in parts]
-            base_labels = frames[0].columns
+            base_labels = parts[0].value_labels
             if isinstance(base_labels, pandas.MultiIndex):
                 return None  # pandas flattens to a deeper MultiIndex
-            if not all(f.columns.equals(base_labels) for f in frames[1:]):
+            if not all(p.value_labels.equals(base_labels) for p in parts[1:]):
                 return None
-            new_cols, labels = [], []
             if series_groupby:
                 # a series groupby yields flat agg-named columns
-                for frame, fname in zip(frames, agg_func):
-                    new_cols.append(frame._columns[0])
-                    labels.append(fname)
-                new_labels = pandas.Index(labels)
+                picks = [(p, 0) for p in parts]
+                new_labels = pandas.Index(list(agg_func))
             else:
-                for pos, label in enumerate(base_labels):
-                    for frame, fname in zip(frames, agg_func):
-                        new_cols.append(frame._columns[pos])
-                        labels.append((label, fname))
-                new_labels = pandas.MultiIndex.from_tuples(labels)
-            result_frame = TpuDataframe(
-                new_cols, new_labels, frames[0]._index, nrows=len(frames[0])
-            )
-            return type(self)(result_frame)
-
-        if (
+                picks = [
+                    (p, pos) for pos in range(len(base_labels)) for p in parts
+                ]
+                new_labels = pandas.MultiIndex.from_tuples(
+                    [
+                        (label, fname)
+                        for label in base_labels
+                        for fname in agg_func
+                    ]
+                )
+        elif (
             isinstance(agg_func, dict)
             and agg_func
             and not series_groupby
             and selection is None
             and all(isinstance(f, str) for f in agg_func.values())
         ):
-            parts = []
+            picks = []
             for col, f in agg_func.items():
                 part = run_one(f, [col])
                 if part is None:
                     return None
-                parts.append(part)
-            frames = [p._modin_frame for p in parts]
-            if not all(f.num_cols == 1 for f in frames):
-                return None
-            new_cols = [f._columns[0] for f in frames]
+                if len(part.datas) != 1:
+                    return None
+                picks.append((part, 0))
             new_labels = pandas.Index(list(agg_func))
-            result_frame = TpuDataframe(
-                new_cols, new_labels, frames[0]._index, nrows=len(frames[0])
-            )
-            return type(self)(result_frame)
-        return None
+        else:
+            return None
+        combined = picks[0][0]._replace(
+            datas=[p.datas[i] for p, i in picks],
+            out_dtypes=[p.out_dtypes[i] for p, i in picks],
+            value_decoders=[p.value_decoders[i] for p, i in picks],
+            value_labels=new_labels,
+        )
+        return self._assemble_groupby(combined, groupby_kwargs)
 
     @device_path("groupby")
     def _try_device_groupby(
         self, by, agg_func, axis, groupby_kwargs, agg_args, agg_kwargs, drop,
         series_groupby, selection,
     ) -> Optional["TpuQueryCompiler"]:
+        """One named aggregation on the device, or None (pandas answers).
+
+        Keys: numeric device columns, resident ``category`` columns (their
+        codes are the key, ``factorize_keys``' ``code_widths``), and host
+        string / object columns through their dictionary codes.  ``dropna``
+        and ``observed=True`` are the factorisation's; ``sort=False`` and
+        ``as_index=False`` are honoured by :meth:`_assemble_groupby`."""
+        parts = self._groupby_parts(
+            by, agg_func, axis, groupby_kwargs, agg_args, agg_kwargs, drop,
+            selection,
+        )
+        if parts is None:
+            return None
+        qc = self._assemble_groupby(parts, groupby_kwargs)
+        if qc is not None and (series_groupby or agg_func == "size"):
+            qc._shape_hint = "column"
+        return qc
+
+    def _groupby_parts(
+        self, by, agg_func, axis, groupby_kwargs, agg_args, agg_kwargs, drop,
+        selection,
+    ) -> Optional["_GroupbyParts"]:
+        """The kernels' answer to one named aggregation: a device column a
+        value column, rows in key order (``factorize_keys``' codes), with the
+        factorisation beside it — or None where the device declines."""
         from modin_tpu.ops import groupby as gb_ops
 
         if axis != 0 or agg_args:
@@ -4388,8 +4448,6 @@ class TpuQueryCompiler(BaseQueryCompiler):
         ):
             return None
         if groupby_kwargs.get("level") is not None:
-            return None
-        if not groupby_kwargs.get("sort", True):
             return None
         if not groupby_kwargs.get("as_index", True) and agg_func == "size":
             return None
@@ -4468,36 +4526,46 @@ class TpuQueryCompiler(BaseQueryCompiler):
         # back to labels when building the result index
         key_data_cols = []
         key_decoders: List[Any] = []
-        cat_encodings: List[Any] = []
-        for c in key_cols:
+        code_widths: List[Optional[int]] = []
+        for ki, c in enumerate(key_cols):
+            if isinstance(c.pandas_dtype, pandas.CategoricalDtype):
+                # a category key: its codes are the key and their range comes
+                # from the dtype.  A host column becomes resident here, once
+                # (pandas' own codes uploaded as they are), and the frame
+                # holds the resident column from now on: no codes travel in a
+                # later request
+                from modin_tpu.ops.dictionary import resident_category_column
+
+                resident = resident_category_column(c)
+                if resident is None:
+                    return None
+                if resident is not c:
+                    if external_key is not None:
+                        eframe._columns[0] = resident
+                    else:
+                        frame._columns[key_positions[ki]] = resident
+                key_data_cols.append(resident)
+                key_decoders.append(("cat", c.pandas_dtype))
+                code_widths.append(len(c.pandas_dtype.categories))
+                continue
+            code_widths.append(None)
             if c.is_device and c.pandas_dtype.kind in "biuf":
                 key_data_cols.append(c)
                 key_decoders.append(None)
                 continue
             if not c.is_device:
-                if isinstance(c.pandas_dtype, pandas.CategoricalDtype):
-                    from modin_tpu.ops.dictionary import (
-                        encode_categorical_column,
-                    )
+                from modin_tpu.ops.dictionary import encode_host_column
 
-                    enc = encode_categorical_column(c)
-                    if enc is not None:
-                        key_data_cols.append(enc.codes)
-                        key_decoders.append(("cat", c.pandas_dtype))
-                        cat_encodings.append(enc)
-                        continue
-                else:
-                    from modin_tpu.ops.dictionary import encode_host_column
-
-                    enc = encode_host_column(c)
-                    if enc is not None:
-                        key_data_cols.append(enc.codes)
-                        key_decoders.append(enc.categories)
-                        continue
+                enc = encode_host_column(c)
+                if enc is not None:
+                    key_data_cols.append(enc.codes)
+                    key_decoders.append(enc.categories)
+                    continue
             return None
         if len(frame) == 0:
             return None
-        if cat_encodings and not groupby_kwargs.get("observed", True):
+        category_keys = any(w is not None for w in code_widths)
+        if category_keys and not groupby_kwargs.get("observed", True):
             # observed=False keeps UNOBSERVED categories in the result; the
             # factorize only sees observed codes.  Take the device path only
             # when there is nothing unobserved (single categorical key and a
@@ -4566,16 +4634,17 @@ class TpuQueryCompiler(BaseQueryCompiler):
         frame.materialize_device()
         try:
             codes, n_groups, group_keys, sizes = gb_ops.factorize_keys_cached(
-                [c.data for c in key_data_cols], len(frame), dropna=dropna
+                [c.data for c in key_data_cols], len(frame), dropna=dropna,
+                code_widths=code_widths if category_keys else None,
             )
         except gb_ops._TooManyGroups:
             return None
         if n_groups == 0:
             return None
-        if cat_encodings and not groupby_kwargs.get("observed", True):
-            enc = cat_encodings[0]
-            nan_groups = 1 if (not dropna and enc.has_nan) else 0
-            if n_groups - nan_groups < len(enc.categories):
+        if category_keys and not groupby_kwargs.get("observed", True):
+            keys0 = np.asarray(group_keys[0], dtype=np.float64)
+            observed_cats = int(np.sum(~(np.isnan(keys0) | (keys0 < 0))))
+            if observed_cats < len(key_cols[0].pandas_dtype.categories):
                 return None  # unobserved categories: pandas keeps them
 
         # bool value columns aggregate as ints for sum/mean/... like pandas
@@ -4628,43 +4697,131 @@ class TpuQueryCompiler(BaseQueryCompiler):
                 else:
                     out_dtypes.append(np.dtype(d.dtype))
 
-        # build result index from group keys (dict-encoded levels translate
-        # their code values back to labels; categorical levels rebuild their
-        # dtype so the result gets a CategoricalIndex like pandas)
+        if agg_func == "size":
+            value_decoders = [None]
+        return _GroupbyParts(
+            datas=list(datas),
+            out_dtypes=out_dtypes,
+            value_labels=pandas.Index(value_labels),
+            value_decoders=[
+                dec if dec is not None and agg_func in ("min", "max", "first", "last") else None
+                for dec in value_decoders
+            ],
+            codes=codes,
+            n_groups=n_groups,
+            group_keys=group_keys,
+            key_labels=key_labels,
+            key_decoders=key_decoders,
+            key_dtypes=[c.pandas_dtype for c in key_cols],
+        )
+
+    def _assemble_groupby(
+        self, parts: "_GroupbyParts", groupby_kwargs: dict
+    ) -> Optional["TpuQueryCompiler"]:
+        """The frame of a device groupby's answer, as the caller phrased it.
+
+        ``sort=False``: the kernels' key-sorted rows are gathered into order of
+        first appearance (``ops/groupby.py`` ``groupby_first_seen``: G rows a
+        column, on the device).  ``as_index=False`` with keys the device holds
+        (numeric columns and resident ``category`` columns): the key columns go
+        in front, in ``by``'s order, each resident and of the key's own dtype
+        (a category key: the group's codes + the table's ``CategoricalDtype``),
+        under a ``RangeIndex``; no label is built on the host.  Any other
+        ``as_index=False`` goes through ``reset_index`` of the labelled answer
+        as before."""
+        from modin_tpu.ops import groupby as gb_ops
         from modin_tpu.ops.dictionary import decode_codes
 
-        decoded_keys = []
-        for vals, dec in zip(group_keys, key_decoders):
-            if dec is None:
-                decoded_keys.append(vals)
-            elif isinstance(dec, tuple) and dec[0] == "cat":
-                vals = np.asarray(vals, dtype=np.float64)
-                int_codes = np.where(np.isnan(vals), -1, vals).astype(np.int64)
-                decoded_keys.append(
-                    pandas.Categorical.from_codes(int_codes, dtype=dec[1])
-                )
-            else:
-                decoded_keys.append(decode_codes(vals, dec))
-        if len(key_labels) == 1:
-            result_index = pandas.Index(decoded_keys[0], name=key_labels[0])
-        else:
-            result_index = pandas.MultiIndex.from_arrays(
-                decoded_keys, names=key_labels
+        n_groups = parts.n_groups
+        value_labels = parts.value_labels
+        as_index = groupby_kwargs.get("as_index", True)
+        datas = list(parts.datas)
+        # a list agg's (column, agg) labels: key labels are padded to tuples
+        pad = (
+            ("",) * (value_labels.nlevels - 1)
+            if isinstance(value_labels, pandas.MultiIndex)
+            else None
+        )
+        key_labels = [
+            lab if pad is None else (lab,) + pad for lab in parts.key_labels
+        ]
+        if not as_index and any(lab in value_labels for lab in key_labels):
+            return None  # a key that is a value column too: pandas' own rules
+        # numeric keys, and resident category keys (their group keys are codes)
+        keys_as_columns = (
+            not as_index
+            and all(
+                dec is None
+                or (dec[0] == "cat" and np.asarray(keys).dtype.kind == "i")
+                for dec, keys in zip(parts.key_decoders, parts.group_keys)
             )
+            and all(lab is not None for lab in parts.key_labels)
+        )
+        order = None
+        if not groupby_kwargs.get("sort", True) and n_groups > 1:
+            order = gb_ops.groupby_first_seen(parts.codes, n_groups)
 
-        new_cols: list = []
-        for j, (d, dt) in enumerate(zip(datas, out_dtypes)):
-            dec = (
-                value_decoders[j]
-                if agg_func != "size" and j < len(value_decoders)
-                else None
-            )
-            if dec is not None and agg_func in ("min", "max", "first", "last"):
+        key_cols: list = []
+        if keys_as_columns:
+            with _spans.span(
+                "qc.groupby.assemble", layer="GROUPBY-ASSEMBLE",
+                n_keys=len(key_labels), num_groups=n_groups,
+            ):
+                single = len(parts.group_keys) == 1
+                keys = [
+                    gb_ops.group_keys_device(g, single, order)
+                    for g in parts.group_keys
+                ]
+                # what is not in the order yet: uploaded key tables, the values
+                moved = iter(
+                    gb_ops.groupby_take_groups(
+                        [d for d, in_order in keys if not in_order] + datas, order
+                    )
+                    if order is not None
+                    else datas
+                )
+                key_datas = [d if in_order else next(moved) for d, in_order in keys]
+                datas = list(moved)
+                key_cols = [
+                    DeviceColumn(d, dt, length=n_groups)
+                    for d, dt in zip(key_datas, parts.key_dtypes)
+                ]
+                result_index = pandas.RangeIndex(n_groups)
+        else:
+            if order is not None:
+                datas = gb_ops.groupby_take_groups(datas, order)
+            # build result index from group keys (dict-encoded levels translate
+            # their code values back to labels; categorical levels rebuild their
+            # dtype so the result gets a CategoricalIndex like pandas)
+            decoded_keys = []
+            for vals, dec in zip(parts.group_keys, parts.key_decoders):
+                if dec is None:
+                    decoded_keys.append(vals)
+                elif isinstance(dec, tuple) and dec[0] == "cat":
+                    vals = np.asarray(vals, dtype=np.float64)
+                    int_codes = np.where(np.isnan(vals), -1, vals).astype(np.int64)
+                    decoded_keys.append(
+                        pandas.Categorical.from_codes(int_codes, dtype=dec[1])
+                    )
+                else:
+                    decoded_keys.append(decode_codes(vals, dec))
+            if len(parts.key_labels) == 1:
+                result_index = pandas.Index(decoded_keys[0], name=parts.key_labels[0])
+            else:
+                result_index = pandas.MultiIndex.from_arrays(
+                    decoded_keys, names=parts.key_labels
+                )
+            if order is not None:
+                result_index = result_index.take(
+                    np.asarray(_engine_materialize(order))[:n_groups]
+                )
+
+        new_cols: list = list(key_cols)
+        for d, dt, dec in zip(datas, parts.out_dtypes, parts.value_decoders):
+            if dec is not None:
                 # dict value column: the per-group result is a CODE — decode
                 # to labels (host, n_groups values) with the source dtype
                 cats, src_dtype = dec
-                import jax as _jax
-
                 decoded = decode_codes(
                     np.asarray(_engine_materialize(d))[:n_groups], cats
                 )
@@ -4673,15 +4830,16 @@ class TpuQueryCompiler(BaseQueryCompiler):
                 new_cols.append(HostColumn(decoded))
             else:
                 new_cols.append(DeviceColumn(d, dt, length=n_groups))
-        result_frame = TpuDataframe(
-            new_cols, pandas.Index(value_labels), result_index, nrows=n_groups
-        )
+        labels = value_labels
+        if key_cols:
+            labels = pandas.Index(key_labels + list(value_labels), tupleize_cols=False)
+            if pad is not None:
+                labels = pandas.MultiIndex.from_tuples(list(labels))
+        result_frame = TpuDataframe(new_cols, labels, result_index, nrows=n_groups)
         qc = type(self)(result_frame)
-        if not groupby_kwargs.get("as_index", True):
+        if not as_index and not key_cols:
             # keys become regular columns with a RangeIndex
             qc = qc.reset_index(drop=False)
-        if series_groupby or agg_func == "size":
-            qc._shape_hint = "column"
         return qc
 
     # ------------------------------- sort ----------------------------- #

@@ -153,6 +153,20 @@ SPANS = (
         "in attributes",
     ),
     (
+        "groupby.first_seen",
+        "the order of first appearance of a sort=False groupby "
+        "(ops/groupby.py groupby_first_seen: a prefix of the codes sorted, "
+        "grown until it shows every group; form, num_groups and rows in "
+        "attributes) and the gather of the answer's G rows into it (form "
+        "take); layer GROUPBY-ASSEMBLE",
+    ),
+    (
+        "qc.groupby.assemble",
+        "the key columns of an as_index=False device groupby put in front of "
+        "the answer, resident (query compiler _assemble_groupby: n_keys and "
+        "num_groups in attributes); layer GROUPBY-ASSEMBLE",
+    ),
+    (
         "opt.choose",
         "one graftopt joint strategy pass over an optimized plan: every "
         "node annotated with estimated rows/bytes/seconds and its chosen "

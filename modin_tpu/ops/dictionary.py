@@ -5,7 +5,9 @@ for EQUALITY/ORDER-shaped ops (groupby keys, merge keys, sort keys, isin,
 nunique, value_counts, drop_duplicates) through a lazy, cached factorization:
 
 - ``categories``: the column's distinct values, **sorted** (host-side, small)
-- ``codes``: per-row positions into categories, as a padded sharded device
+- ``codes`` (of a host string/object column; a ``category`` column's codes
+  are pandas' own integers, see ``resident_category_column``): per-row
+  positions into categories, as a padded sharded device
   array of **float64 with NaN for missing** — NOT int32 with a -1 sentinel.
   Sorted categories make codes order-isomorphic to the strings, and NaN
   codes make every existing numeric-key kernel's missing-data semantics
@@ -91,31 +93,31 @@ def encodable(col: Any) -> bool:
     return encode_host_column(col) is not None
 
 
-def encode_categorical_column(col: Any) -> Optional[DictEncoding]:
-    """Encoding for a host CATEGORICAL column: pandas already stores codes,
-    so this is a cast + device_put (cached).  Categories keep their CATEGORY
-    order (not lexicographic) — pandas sorts categorical groups by category
-    order, which is exactly ascending-code order.  Cached under
-    ``_cat_cache``, NEVER ``_dict_cache``: consumers of the sorted-category
-    encoding (isin/nunique/value_counts/sort) must not receive this
-    category-ordered one."""
+def resident_category_column(col: Any) -> Optional[Any]:
+    """The resident form of a CATEGORICAL column: a category ``DeviceColumn``
+    holding pandas' own codes (int8 / int16 / int32, -1 = missing: no cast, no
+    second copy on the host) with the column's ``CategoricalDtype`` shared.
+    The codes are the encoding, in CATEGORY order — pandas sorts categorical
+    groups by category order, which is ascending-code order.
+
+    A column that is resident already is returned as it is.  A host
+    categorical is uploaded once and remembered under ``_cat_cache`` (NEVER
+    ``_dict_cache``: consumers of the sorted-category encoding must not
+    receive this category-ordered one), so every frame that shares the host
+    column finds the same resident one; the caller puts it in its frame's
+    place.  None: not a categorical."""
+    if getattr(col, "is_category", False):
+        return col
     cached = getattr(col, "_cat_cache", None)
     if cached is not None:
         return cached if cached is not False else None
     from modin_tpu.core.dataframe.tpu.dataframe import DeviceColumn
 
-    try:
-        cat = col.data
-        codes = np.asarray(cat.codes)
-        categories = np.asarray(cat.categories)
-    except Exception:  # graftlint: disable=EXC-HYGIENE -- host pandas Categorical probe; any failure means 'not encodable'
+    cat = col.data
+    if not isinstance(cat, pandas.Categorical):
         col._cat_cache = False
         return None
-    fcodes = codes.astype(np.float64)
-    has_nan = bool((codes == -1).any())
-    if has_nan:
-        fcodes[codes == -1] = np.nan
-    result = DictEncoding(DeviceColumn.from_numpy(fcodes), categories, has_nan)
+    result = DeviceColumn.from_categorical(cat)
     col._cat_cache = result
     return result
 

@@ -12,6 +12,11 @@ Key factorization strategies:
                                                 when every id of the range
                                                 occurs the offsets are the
                                                 codes, else one remap gather
+- a ``category`` column's codes               -> the codes themselves: their
+                                                range is [0, len(categories))
+                                                from the dtype, so no min/max
+                                                pass and no fetch; -1 (missing)
+                                                is the dropped or the NaN group
 - anything else                              -> jnp.unique (device sort, one
                                                 host sync for the group count)
 
@@ -91,6 +96,44 @@ def _jit_range_ids(n: int, width: int):
         return jnp.where(valid, jnp.clip(k - kmin, 0, width), width).astype(jnp.int32)
 
     return named_jit(fn, "groupby_range_ids")
+
+
+@functools.lru_cache(maxsize=None)
+def _jit_category_ids(n: int, width: int, nan_group: bool):
+    """A category column's codes (int8 / int16 / int32 as pandas holds them,
+    -1 = missing) as the ids of a key of ``width`` categories: int32, never
+    wider; a missing key takes the slot ``width`` where the NaN group is kept
+    (``nan_group``), and it and the pad rows the overflow id otherwise."""
+    import jax
+    import jax.numpy as jnp
+
+    overflow = width + 1 if nan_group else width
+
+    def fn(c):
+        c = c.astype(jnp.int32)
+        keep = jnp.arange(c.shape[0]) < n
+        if nan_group:
+            c = jnp.where(c < 0, width, c)
+        else:
+            keep &= c >= 0
+        return jnp.where(keep, c, overflow)
+
+    return named_jit(fn, "groupby_category_ids")
+
+
+def _category_level(k: Any, n: int, width: int, nan_slot: bool):
+    """``(ids, slots, uniques)`` of a category key of ``width`` categories:
+    the slots are the categories in their order, then one for the missing keys
+    where ``nan_slot`` (code -1 among ``uniques``; without it a missing key
+    takes the overflow id, like a pad row).  No pass over the key finds the
+    range, and no histogram: the caller counts the ids (alone, or composed
+    with the other keys') to learn which groups are present."""
+    slots = width + 1 if nan_slot else width
+    ids = _jit_category_ids(n, width, nan_slot)(k)
+    uniques = np.arange(slots, dtype=np.dtype(str(k.dtype)))
+    if nan_slot:
+        uniques[width] = -1
+    return ids, slots, uniques
 
 
 class RangeCodes:
@@ -265,14 +308,18 @@ def clear_factorize_cache() -> None:
 
 
 def factorize_keys_cached(
-    key_cols: List[Any], n: int, dropna: bool = True
+    key_cols: List[Any],
+    n: int,
+    dropna: bool = True,
+    code_widths: Optional[Tuple[Optional[int], ...]] = None,
 ) -> Tuple[Any, int, List[np.ndarray], Any]:
     """Memoized :func:`factorize_keys` (same-identity key columns hit)."""
-    cache_key = (tuple(id(k) for k in key_cols), int(n), bool(dropna))
+    code_widths = tuple(code_widths) if code_widths is not None else None
+    cache_key = (tuple(id(k) for k in key_cols), int(n), bool(dropna), code_widths)
     for entry_key, _refs, result in _FACTORIZE_CACHE:
         if entry_key == cache_key:
             return result
-    result = factorize_keys(key_cols, n, dropna)
+    result = factorize_keys(key_cols, n, dropna, code_widths)
     _FACTORIZE_CACHE.append((cache_key, list(key_cols), result))
     if len(_FACTORIZE_CACHE) > _FACTORIZE_CACHE_MAX:
         _FACTORIZE_CACHE.pop(0)
@@ -280,9 +327,26 @@ def factorize_keys_cached(
 
 
 def factorize_keys(
-    key_cols: List[Any], n: int, dropna: bool = True
+    key_cols: List[Any],
+    n: int,
+    dropna: bool = True,
+    code_widths: Optional[Tuple[Optional[int], ...]] = None,
+    _nan_slots: bool = False,
 ) -> Tuple[Any, int, List[np.ndarray], Any]:
     """Device factorization of one or more padded key columns (logical len n).
+
+    ``code_widths[i]``, where given and not None, says that key ``i`` is a
+    category column's codes: integers in [-1, width), -1 = missing.  Such a
+    key is a dense integer key whose range the dtype gives: no
+    ``groupby_key_minmax`` launch and no fetch for it, the codes are never
+    widened past int32, and its group keys come back as **codes** (-1 for the
+    NaN group, kept last when ``dropna`` is False) for the caller to wrap in
+    the dtype.  Unobserved categories are not groups (the histogram's
+    ``present``, as for any integer key).  The missing keys are first left out
+    of the ids, and the histogram's total says whether there were any: only
+    then, and only where ``dropna`` is False, are the ids made again with a
+    NaN slot a category level (``_nan_slots``), so a key without missing
+    values, of which every category occurs, never pays a remap.
 
     Returns (codes, num_groups, group_key_arrays_host, sizes): ``codes`` maps
     each row to [0, num_groups), with pads (and NaN keys when dropna) mapped
@@ -297,9 +361,23 @@ def factorize_keys(
     import jax
     import jax.numpy as jnp
 
+    if code_widths is None:
+        code_widths = (None,) * len(key_cols)
     if len(key_cols) == 1:
         k = key_cols[0]
         kdt = k.dtype
+        if code_widths[0] is not None:
+            ids, slots, uniques = _category_level(k, n, int(code_widths[0]), _nan_slots)
+            counts = _count_ids(ids, slots) if slots else np.zeros(0, np.int64)
+            if not dropna and not _nan_slots and int(counts.sum()) < n:
+                return factorize_keys(key_cols, n, dropna, code_widths, True)
+            if counts.all():
+                return ids, slots, [uniques], counts
+            present = np.nonzero(counts)[0]
+            remap = np.full(slots + 1, len(present), dtype=np.int64)
+            remap[present] = np.arange(len(present))
+            codes = _jit_remap(len(present))(ids, _engine_upload(remap))
+            return codes, len(present), [uniques[present]], counts[present]
         if jnp.issubdtype(kdt, jnp.integer) or kdt == jnp.bool_:
             k64 = k.astype(jnp.int64)
             kmin, kmax = (int(v) for v in _engine_materialize(_jit_key_minmax(n)(k64)))
@@ -359,16 +437,31 @@ def factorize_keys(
     level_codes = []
     level_uniques = []
     n_groups_each = []
-    for k in key_cols:
-        codes_i, n_i, uniques_i, _sizes_i = factorize_keys([k], n, dropna=dropna)
+    for k, width in zip(key_cols, code_widths):
+        if width is not None:
+            # a category level: its slots are known, and the composite's
+            # histogram says which of them occur
+            codes_i, n_i, uniques_i = _category_level(k, n, int(width), _nan_slots)
+        else:
+            codes_i, n_i, (uniques_i,), _sizes_i = factorize_keys([k], n, dropna=dropna)
         level_codes.append(codes_array(codes_i))
-        level_uniques.append(uniques_i[0])
+        level_uniques.append(uniques_i)
         n_groups_each.append(n_i)
-    total = int(np.prod(n_groups_each))
+    total = int(np.prod(n_groups_each, dtype=object))
     if total > _RANGE_LIMIT * 4:
         raise _TooManyGroups()
+    if total == 0:
+        empty = [np.asarray(u)[:0] for u in level_uniques]
+        return jnp.zeros_like(level_codes[0]), 0, empty, np.zeros(0, np.int64)
     composite = _jit_composite(tuple(n_groups_each), n, total)(tuple(level_codes))
     counts = _count_ids(composite, total)
+    if (
+        not dropna
+        and not _nan_slots
+        and any(w is not None for w in code_widths)
+        and int(counts.sum()) < n
+    ):
+        return factorize_keys(key_cols, n, dropna, code_widths, True)
     present = np.nonzero(counts)[0]
     if len(present) == total:
         # every combination of level codes occurs: identity remap, as above
@@ -619,6 +712,9 @@ _LIMB_CHUNK = 1 << 20
 # block of sorted rows spans (rows a block: _tile_rows)
 _SORT_CHUNK = 1 << 22
 _TILE_IDS = 512
+# group counts up to which the factorisation's row counts are uploaded as the
+# denominator of mean / count (past it the kernel counts)
+_SIZES_OPERAND_MAX_GROUPS = 1 << 16
 # test hook: "tpu" (what a TPU would choose, on any platform; Pallas kernels
 # then run in interpret mode off the chip) | "masked_scan" (the same, but the
 # scan where limb_dot would be chosen: the sharded and fallback path) |
@@ -1491,6 +1587,267 @@ def groupby_first_position(codes: Any, num_groups: int) -> Any:
     return _jit_first_position(num_groups + 1)(codes_array(codes))[:num_groups]
 
 
+# Order of first appearance (``sort=False``): the groups by the least row
+# position of each, found with neither a scatter nor an O(n * G) scan, in one
+# of two forms read from the group count, the platform and the shard count:
+# - first_seen_prefix: a prefix of the codes is sorted by (code, position), the
+#   head of each run is a group's first row within the prefix, and a second
+#   sort of those heads by position lists the groups in the order they appear.
+#   The prefix is sized from the group count so that an evenly spread key
+#   shows every group in it (G * (ln G + 16) rows miss a group with
+#   probability e^-16 a group); the count of heads found says whether it did,
+#   and the prefix grows eightfold until it does (a key whose groups come in
+#   blocks ends at the whole column).  Two sorts of the prefix: nothing beside
+#   a request while the prefix is a few hundred thousand rows (up to some 1e5
+#   groups), 0.41 s of a 1.5 s request at 1e6 groups (a prefix of 2**25 rows).
+# - first_seen_tiles: where that prefix would pass a chunk of the sorted tiles,
+#   on a one-shard TPU.  The tiles' own walk (``_jit_sorted_tiles``: a chunk of
+#   rows sorted by code with the row positions travelling, a block's one-hot
+#   against its consecutive codes) takes a *min* of the positions where the
+#   sums take a sum, placed into the ``[G]`` accumulator a contiguous slice at
+#   a time; the walk stops with the first chunk after which every group has a
+#   position (no host sync), and one sort of the G positions gives the order.
+_FIRST_SEEN_MIN_ROWS = 1 << 16
+_FIRST_SEEN_GROWTH = 8
+
+
+def _first_seen_rows(num_groups: int, physical: int) -> int:
+    """Rows of the first prefix: a power of two, at most the column."""
+    want = num_groups * (np.log(max(num_groups, 2)) + 16.0)
+    rows = max(_FIRST_SEEN_MIN_ROWS, 1 << int(np.ceil(np.log2(want))))
+    return min(rows, physical)
+
+
+def _first_seen_form(codes: Any, num_groups: int) -> str:
+    from modin_tpu.parallel.mesh import num_row_shards
+
+    if (
+        _first_seen_rows(num_groups, int(codes.shape[0])) > _SORT_CHUNK
+        and _tpu_forms(codes)
+        and num_row_shards() == 1
+        and num_groups <= _RANGE_LIMIT
+    ):
+        return "first_seen_tiles"
+    return "first_seen_prefix"
+
+
+@functools.lru_cache(maxsize=None)
+def _jit_first_seen(num_groups: int, take: int, p_out: int):
+    import jax
+    import jax.lax as lax
+    import jax.numpy as jnp
+
+    def fn(codes):
+        # ``codes``: the array, or (key, kmin, n) of a dense range (RangeCodes)
+        if isinstance(codes, tuple):
+            key, kmin, n = codes
+            cc = jnp.clip(lax.slice(key, (0,), (take,)).astype(jnp.int64) - kmin, 0, num_groups)
+            cc = jnp.where(jnp.arange(take) < n, cc, num_groups)
+        else:
+            cc = lax.slice(codes, (0,), (take,))
+        cc = cc.astype(jnp.int32)
+        s, pos = lax.sort((cc, jnp.arange(take, dtype=jnp.int32)), num_keys=2)
+        head = jnp.concatenate([jnp.ones(1, bool), s[1:] != s[:-1]]) & (s < num_groups)
+        found = jnp.sum(head, dtype=jnp.int32)
+        at = jnp.where(head, pos, np.iinfo(np.int32).max)
+        _, order = lax.sort((at, s), num_keys=1)
+        return _slice_pad(order, num_groups, p_out), found
+
+    return named_jit(fn, "groupby_first_seen")
+
+
+@functools.lru_cache(maxsize=None)
+def _jit_first_seen_tiles(num_groups: int, p_out: int, chunk: int):
+    """The least row position of each group by the sorted tiles' walk, and the
+    groups sorted by it.  As ``_jit_sorted_tiles`` (its comments hold here): a
+    chunk's rows are sorted by code, cut into blocks of ``_tile_rows``, and a
+    block's one-hot against ``first + arange(_TILE_IDS)`` reduces it, here with
+    a min over the row positions; blocks that reach past their first tile take
+    further tiles.  The walk is a ``while_loop`` that ends once every group has
+    been seen, so an evenly spread key costs ``G ln G`` rows of it, and a key
+    in blocks the whole column."""
+    import jax
+    import jax.lax as lax
+    import jax.numpy as jnp
+
+    n_groups = num_groups
+    T = _TILE_IDS
+    acc_len = n_groups + 1 + T
+    unseen = np.iinfo(np.int32).max
+
+    def fn(codes):
+        key, kmin, n = codes if isinstance(codes, tuple) else (None, None, None)
+        P = (codes if key is None else key).shape[0]
+        take = min(int(chunk), P)
+        steps = -(-P // take)
+        W = _tile_rows(take, n_groups)
+        cp = -(-take // W) * W
+        B = cp // W
+        tid = jnp.arange(T, dtype=jnp.int32)
+
+        def tile(s, pos, first):
+            oh = (s - first[:, None])[:, :, None] == tid[None, None, :]
+            return jnp.min(jnp.where(oh, pos[:, :, None], unseen), axis=1)
+
+        def min_at(acc, offset, part):
+            return lax.dynamic_update_slice(
+                acc, jnp.minimum(lax.dynamic_slice(acc, (offset,), (T,)), part), (offset,)
+            )
+
+        def step(state):
+            i, acc, _ = state
+            start = jnp.minimum(i * take, P - take)
+            pos = start + jnp.arange(take, dtype=jnp.int32)
+            live = pos >= i * take
+            with jax.named_scope("chunk_sort"):
+                if key is None:
+                    cc = lax.dynamic_slice(codes, (start,), (take,))
+                else:
+                    live &= pos < n
+                    kc = lax.dynamic_slice(key, (start,), (take,)).astype(jnp.int64)
+                    cc = jnp.clip(kc - kmin, 0, n_groups)
+                cc = jnp.where(live, cc.astype(jnp.int32), n_groups)
+                if cp > take:
+                    cc = jnp.concatenate([cc, jnp.full(cp - take, n_groups, jnp.int32)])
+                    pos = jnp.concatenate([pos, jnp.full(cp - take, unseen, jnp.int32)])
+                s, pos = lax.sort((cc, pos), num_keys=1, is_stable=False)
+                s, pos = s.reshape(B, W), pos.reshape(B, W)
+            first, last = s[:, 0], s[:, -1]
+            with jax.named_scope("tile_reduce"):
+                parts = tile(s, pos, first)
+            with jax.named_scope("tile_place"):
+                acc = lax.fori_loop(
+                    0, B, lambda b, acc: min_at(acc, first[b], parts[b]), acc, unroll=4
+                )
+            spills = last - first >= T
+            n_spills = jnp.sum(spills, dtype=jnp.int32)
+            turn = jnp.argsort(~spills, stable=True).astype(jnp.int32)
+
+            def further(state):
+                k, done, acc = state
+                b = turn[k]
+                sb = lax.dynamic_slice(s, (b, jnp.int32(0)), (1, W))
+                pb = lax.dynamic_slice(pos, (b, jnp.int32(0)), (1, W))
+                nxt = jnp.min(jnp.where(sb >= done, sb, n_groups))
+                acc = min_at(acc, nxt, tile(sb, pb, nxt[None])[0])
+                over = nxt + T > last[b]
+                after = turn[jnp.minimum(k + 1, B - 1)]
+                return (
+                    jnp.where(over, k + 1, k),
+                    jnp.where(over, first[after] + T, nxt + T),
+                    acc,
+                )
+
+            with jax.named_scope("tile_spill"):
+                acc = lax.while_loop(
+                    lambda state: state[0] < n_spills,
+                    further,
+                    (jnp.int32(0), first[turn[0]] + T, acc),
+                )[2]
+            seen = jnp.sum(acc[:n_groups] != unseen, dtype=jnp.int32)
+            return i + 1, acc, seen
+
+        _, acc, _ = lax.while_loop(
+            lambda state: (state[0] < steps) & (state[2] < n_groups),
+            step,
+            (jnp.int32(0), jnp.full(acc_len, unseen, jnp.int32), jnp.int32(0)),
+        )
+        _, order = lax.sort(
+            (acc[:n_groups], jnp.arange(n_groups, dtype=jnp.int32)), num_keys=1
+        )
+        return _slice_pad(order, n_groups, p_out)
+
+    return named_jit(fn, "groupby_first_seen_tiles")
+
+
+def groupby_first_seen(codes: Any, num_groups: int) -> Any:
+    """The groups in order of first appearance: a device int32 array (padded
+    to the shard multiple, logical length ``num_groups``) whose entry ``j`` is
+    the code of the ``j``-th group to appear.  Exact.  The form is read from
+    the group count, the platform and the shard count (see above): the prefix
+    form costs one host sync a prefix tried (the count of groups it showed),
+    the tiles form none."""
+    from modin_tpu.ops.structural import pad_len
+
+    physical = int(codes.shape[0])
+    form = _first_seen_form(codes, num_groups)
+    operand = _tiles_operand(codes)
+    p_out = pad_len(num_groups)
+    if _meters.ACCOUNTING_ON:
+        _meters.note_groupby_form(form)
+    take = _first_seen_rows(num_groups, physical)
+    with _spans.span(
+        "groupby.first_seen", layer="GROUPBY-ASSEMBLE", form=form,
+        num_groups=num_groups, rows=take,
+    ):
+        if form == "first_seen_tiles":
+            return _jit_first_seen_tiles(num_groups, p_out, _SORT_CHUNK)(operand)
+        while True:
+            order, found = _jit_first_seen(num_groups, take, p_out)(operand)
+            if int(_engine_materialize(found)) == num_groups or take == physical:
+                return order
+            take = min(take * _FIRST_SEEN_GROWTH, physical)
+
+
+@functools.lru_cache(maxsize=None)
+def _jit_first_seen_take(n_cols: int):
+    import jax
+    import jax.numpy as jnp
+
+    def fn(cols: Tuple, order):
+        return tuple(jnp.take(c, order, mode="clip") for c in cols)
+
+    return named_jit(fn, "groupby_first_seen_take")
+
+
+def groupby_take_groups(cols: List[Any], order: Any) -> List[Any]:
+    """Rows of per-group result columns (each padded like ``order``) gathered
+    into the order :func:`groupby_first_seen` found: G rows a column."""
+    if not cols:
+        return []
+    with _spans.span(
+        "groupby.first_seen", layer="GROUPBY-ASSEMBLE", form="take", n_cols=len(cols)
+    ):
+        return list(_jit_first_seen_take(len(cols))(tuple(cols), order))
+
+
+@functools.lru_cache(maxsize=None)
+def _jit_group_key_range(p_out: int, dtype: str):
+    import jax
+    import jax.numpy as jnp
+
+    def fn(first, order=None):
+        steps = jnp.arange(p_out, dtype=jnp.int64) if order is None else order
+        return (first + steps).astype(dtype)
+
+    return named_jit(fn, "groupby_key_range")
+
+
+def group_keys_device(keys: np.ndarray, single_key: bool, order: Any = None) -> Tuple[Any, bool]:
+    """The group keys of one level (host, as ``factorize_keys`` returns them) as
+    a device column padded to the shard multiple, and whether it is in
+    ``order`` (:func:`groupby_first_seen`'s) already.  The keys of a single
+    integer key whose every value of the range is a group (sorted, distinct,
+    last - first = count - 1) are written on the device from the first, and
+    then in ``order`` at once (group ``j``'s key is ``first + j``: no gather);
+    any other level is a small table and is uploaded in key order."""
+    from modin_tpu.ops.structural import pad_host, pad_len
+
+    keys = np.asarray(keys)
+    g = len(keys)
+    if (
+        single_key
+        and keys.dtype.kind in "iu"
+        and g > _MASKED_SCAN_MAX_GROUPS
+        and int(keys[-1]) - int(keys[0]) == g - 1
+        and int(keys[-1]) <= np.iinfo(np.int64).max
+    ):
+        fn = _jit_group_key_range(pad_len(g), str(keys.dtype))
+        first = np.int64(keys[0])
+        return (fn(first) if order is None else fn(first, order)), True
+    return _engine_upload(pad_host(keys, g)), order is None
+
+
 def groupby_reduce(
     agg: str,
     value_cols: List[Any],
@@ -1549,8 +1906,14 @@ def groupby_reduce(
         single = num_row_shards() == 1
         # only the sorted tiles take codes that were not written out
         codes = _tiles_operand(codes) if form == "sorted_tiles" else codes_array(codes)
-        # the factorisation's row counts as an operand: a denominator for free
-        has_sizes = sizes is not None and agg in ("mean", "count")
+        # the factorisation's row counts as an operand: a denominator for free,
+        # while the table is small (8 MB a request at 1e6 groups is not free,
+        # and the tiles count a float column's valid rows themselves anyway)
+        has_sizes = (
+            sizes is not None
+            and agg in ("mean", "count")
+            and len(sizes) <= _SIZES_OPERAND_MAX_GROUPS
+        )
         if form == "limb_dot":
             fn = _jit_limb_dot(
                 agg, ns, p_out, has_sizes, _LIMB_CHUNK, not _on_tpu(codes)

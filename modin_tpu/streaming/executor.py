@@ -279,7 +279,11 @@ def _filter_bucketed(child: Any, mask_qc: Any) -> Any:
     count = int(mask.sum())
     columns = []
     for col in frame._columns:
-        if getattr(col, "is_device", False):
+        if getattr(col, "is_category", False):
+            columns.append(
+                type(col).from_categorical(col.to_pandas_array()[mask])
+            )
+        elif getattr(col, "is_device", False):
             cache = col.host_cache
             values = np.asarray(cache) if cache is not None else col.to_numpy()
             columns.append(_windows.bucketed_column(values[mask], count))
@@ -326,8 +330,8 @@ def _quantize_reduce(child: Any, method: str, skipna: bool):
         return exact
     columns = []
     for col in frame._columns:
-        if not getattr(col, "is_device", False):
-            return exact  # host/object columns have no neutral pad
+        if not getattr(col, "is_device", False) or col.is_category:
+            return exact  # host/object/category columns have no neutral pad
         values = _windows.host_values(col)
         kind = values.dtype.kind
         if method in ("min", "max"):
@@ -383,7 +387,7 @@ def _quantize_groupby(child: Any, by: Any, dropna: bool):
     sentinels: dict = {}
     columns = []
     for label, col in zip(labels, frame._columns):
-        if not getattr(col, "is_device", False):
+        if not getattr(col, "is_device", False) or col.is_category:
             return exact
         values = _windows.host_values(col)
         kind = values.dtype.kind

@@ -353,6 +353,8 @@ def external_merge_qc(qc: Any, right: Any, kwargs: dict) -> Optional[Any]:
     )
     for fr in (lframe, rframe):
         for c in fr._columns:
+            if getattr(c, "is_category", False):
+                return None  # as the resident merge: pandas answers
             if getattr(c, "is_device", False):
                 if c.is_lazy:
                     return None

@@ -264,7 +264,9 @@ def host_values(col: Any):
     cache = col.host_cache
     if cache is not None:
         return np.asarray(cache)
-    return col.to_numpy()
+    # the buffer's rows as the device holds them (a category column's codes,
+    # like its host copy), so that callers can re-wrap them under the dtype
+    return col.buffer_to_numpy()
 
 
 def frame_nbytes(frame: Any) -> int:

@@ -148,6 +148,53 @@ def test_kernel_compiles_for_v5e_at_1e8_rows(kernel, arg, shapes):
         assert mem.argument_size_in_bytes >= ROWS * 8 * (N_COLS + 1)
 
 
+@pytest.mark.parametrize(
+    "program, arg",
+    [
+        ("category_ids", "int8"),  # id1 / id2 of the H2O script: 100 categories
+        ("category_ids", "int32"),  # id3: 1e6 categories
+        # the order's sorts compile slowly: 18 s this one; 21 s at 1e4 groups,
+        # 41 s at 1e6 (a prefix of 2**25 rows) and 47 s over the whole column
+        # (the last prefix a skewed key reaches) were compiled by hand for PR 36
+        ("first_seen", 100),
+        ("first_seen_tiles", 1_000_000),
+        ("first_seen_take", 1_000_000),
+    ],
+)
+def test_script_phrasing_programs_compile_for_v5e_at_1e8_rows(program, arg, shapes):
+    """The programs a category key and ``sort=False`` add (PR 36): no scatter in
+    the order of first appearance, and the codes never widened past int32."""
+    import numpy as np
+
+    from modin_tpu.ops import groupby
+
+    if program == "category_ids":
+        width = 100 if arg == "int8" else 1_000_000
+        lowered = groupby._jit_category_ids(ROWS, width, False).lower(shapes((ROWS,), arg))
+    elif program == "first_seen_take":
+        cols = (shapes((arg,), np.int32), shapes((arg,), np.int64), shapes((arg,), np.float64))
+        lowered = groupby._jit_first_seen_take(3).lower(cols, shapes((arg,), np.int32))
+    elif program == "first_seen_tiles":
+        take = groupby._SORT_CHUNK
+        lowered = groupby._jit_first_seen_tiles(arg, arg, take).lower(shapes((ROWS,), np.int32))
+    else:
+        take = groupby._first_seen_rows(arg, ROWS)
+        assert take & (take - 1) == 0
+        lowered = groupby._jit_first_seen(arg, take, arg).lower(shapes((ROWS,), np.int32))
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    resident = mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes
+    text = compiled.as_text()
+    assert " scatter(" not in text
+    if program == "category_ids":
+        assert mem.output_size_in_bytes < ROWS * 4 + (1 << 20) and "s64[" not in text
+    elif program.startswith("first_seen") and program != "first_seen_take":
+        assert " sort(" in text
+        # the prefix is what is sorted, not the column
+        assert resident < ROWS * 4 + 6 * 4 * take + (64 << 20), (take, mem)
+    assert resident < HBM_BYTES
+
+
 def test_sharded_bincount_compiles_for_four_v5e_chips(topo, shapes):
     """Mosaic kernels cannot be partitioned automatically: over a row-sharded
     operand the bincount must sit in a ``shard_map`` (first four-chip run of
