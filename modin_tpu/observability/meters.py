@@ -514,6 +514,19 @@ def note_groupby_form(form: str) -> None:
             qs._on_groupby_form(form)
 
 
+def note_elementwise_form(form: str, times: int) -> None:
+    """``times`` columns of an elementwise node built in a form that adapts
+    to its operands (``ops/elementwise.py``, which checks ``ACCOUNTING_ON``
+    first): ``divmod_guarded``, the int64 ``mod`` / ``floordiv`` that divides
+    in 32 bits where every operand fits.  Which branch the device then took
+    is in the trace (``jit_plan_*/conditional.*``), not here: learning it
+    would take a sync."""
+    stack = _spans.thread_requests()
+    if stack:
+        for qs in stack:
+            qs._on_elementwise_form(form, times)
+
+
 def note_host_sync(nbytes: int) -> None:
     """One blocking device->host fetch of ``nbytes`` on this thread."""
     stack = _spans.thread_requests()
@@ -591,6 +604,7 @@ class QueryStats:
         "launches",
         "launches_by_program",
         "groupby_forms",
+        "elementwise_forms",
         "host_syncs",
         "d2h_bytes",
         "h2d_bytes",
@@ -675,6 +689,8 @@ class QueryStats:
         # which device form each groupby reduction / histogram took, noted
         # where it is chosen: a scatter form on a TPU is a minute, not seconds
         self.groupby_forms: Dict[str, int] = {}
+        # columns of elementwise nodes built in an operand-adaptive form
+        self.elementwise_forms: Dict[str, int] = {}
         self.host_syncs = 0
         self.d2h_bytes = 0
         self.h2d_bytes = 0
@@ -798,6 +814,13 @@ class QueryStats:
             if not self._closed:
                 self.groupby_forms[form] = self.groupby_forms.get(form, 0) + 1
 
+    def _on_elementwise_form(self, form: str, times: int) -> None:
+        with self._lock:
+            if not self._closed:
+                self.elementwise_forms[form] = (
+                    self.elementwise_forms.get(form, 0) + times
+                )
+
     def _on_host_sync(self, nbytes: int) -> None:
         with self._lock:
             if not self._closed:
@@ -872,6 +895,7 @@ class QueryStats:
             "launches": self.launches,
             "launches_by_program": dict(self.launches_by_program),
             "groupby_forms": dict(self.groupby_forms),
+            "elementwise_forms": dict(self.elementwise_forms),
             "host_syncs": self.host_syncs,
             "d2h_bytes": self.d2h_bytes,
             "h2d_bytes": self.h2d_bytes,

@@ -64,3 +64,20 @@ def named_jit(fn: Callable, name: str, **jit_kwargs: Any) -> NamedProgram:
 
     program.__name__ = program.__qualname__ = name
     return NamedProgram(name, jax.jit(program, **jit_kwargs))
+
+
+def traced_jit(fn: Callable, name: str) -> Any:
+    """``jax.jit`` of a helper that other programs call while they are traced.
+
+    Not a program and never a launch: the point is jax's own cache of the
+    helper's trace by argument aval, so that ``jax.eval_shape`` of a node and
+    the trace of a fused plan each see one ``jit`` call where the body's
+    equations would be, and the columns of a frame share one lowered function.
+    """
+    import jax
+
+    def helper(*args: Any, **kwargs: Any) -> Any:
+        return fn(*args, **kwargs)
+
+    helper.__name__ = helper.__qualname__ = name
+    return jax.jit(helper)

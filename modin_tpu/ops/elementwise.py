@@ -21,7 +21,11 @@ import functools
 from typing import Any, Callable, List, Optional, Tuple
 
 import numpy as np
-from modin_tpu.ops._program import named_jit
+from modin_tpu.observability import meters as _meters
+from modin_tpu.ops._program import named_jit, traced_jit
+
+#: the registry's names that take :func:`_divmod`
+_DIVMOD_OPS = frozenset({"mod", "rmod", "floordiv", "rfloordiv"})
 
 
 def _trim(x, p_out):
@@ -34,22 +38,109 @@ def _trim(x, p_out):
     return jax.lax.with_sharding_constraint(x[:p_out], row_sharding())
 
 
-def _floordiv(x, y):
+_INT32_MIN = -(2**31)
+# A float32 estimate of a quotient is scaled by this before it is truncated:
+# the two conversions, the reciprocal and the two products each round by
+# 2**-24 or a few of them, and their sum stays well under 2**-20 (a v5e reads
+# 4.2 x 2**-24 at worst: PERF.md, PR 37), so the estimate never passes the true
+# quotient and a remainder never goes negative.
+_NEVER_OVER = 1.0 - 2.0**-20
+
+
+def _narrow_divmod(x, y):
+    """Exact floor quotient and remainder of int32 ``x`` by int32 ``y`` with
+    no integer divide (a TPU has none; XLA expands one into a long division).
+
+    Needs ``|x|, |y| <= 2**31 - 1`` and ``y != 0``.  Two float32 estimates
+    that never overshoot leave a quotient of at most 1 (the second round
+    starts under ``1 + 2**12 / |y|``), so the two conditional subtractions
+    have one to spare; then the signs of floor division.
+    """
     import jax.numpy as jnp
 
-    if jnp.issubdtype(jnp.result_type(x, y), jnp.integer):
-        safe = jnp.where(y == 0, 1, y)
-        return jnp.where(y == 0, 0, x // safe)
-    return x // y
+    ax, ay = abs(x), abs(y)
+    inv = (1.0 / ay.astype(jnp.float32)) * jnp.float32(_NEVER_OVER)
+    q = (ax.astype(jnp.float32) * inv).astype(jnp.int32)
+    r = ax - q * ay
+    est = (r.astype(jnp.float32) * inv).astype(jnp.int32)
+    q, r = q + est, r - est * ay
+    for _ in range(2):
+        over = r >= ay
+        q, r = q + over, jnp.where(over, r - ay, r)
+    differ = (x < 0) != (y < 0)
+    borrow = differ & (r != 0)
+    q = jnp.where(differ, -q, q) - borrow
+    r = jnp.where(borrow, ay - r, r)
+    return q, jnp.where(y < 0, -r, r)
 
 
-def _mod(x, y):
+def _fits_narrow(v):
+    """Every element of int64 ``v`` is its own low word sign-extended, and
+    not ``-2**31`` (whose magnitude and whose quotient by -1 pass int32)."""
     import jax.numpy as jnp
 
-    if jnp.issubdtype(jnp.result_type(x, y), jnp.integer):
-        safe = jnp.where(y == 0, 1, y)
-        return jnp.where(y == 0, 0, x % safe)
-    return x % y
+    low = v.astype(jnp.int32)
+    return jnp.all((low.astype(jnp.int64) == v) & (low != _INT32_MIN))
+
+
+def _plain_divmod(x, y, want: str):
+    return x % y if want == "mod" else x // y
+
+
+def _zero_safe_divmod(x, y, want: str):
+    """Integer ``x % y`` / ``x // y``, 0 where ``y`` is 0 (module docstring)."""
+    import jax.numpy as jnp
+
+    zero = y == 0
+    return jnp.where(zero, 0, _plain_divmod(x, jnp.where(zero, 1, y), want))
+
+
+def _guarded_divmod(x, y, want: str):
+    """int64 ``x % y`` (``want="mod"``) or ``x // y`` (``"floordiv"``), 0
+    where ``y`` is 0: the program looks at its own operands and divides in 32
+    bits where every one of them fits, in 64 as before where one does not.
+
+    pandas' integers are int64 whatever they hold, and a 64-bit remainder
+    costs a TPU some 1800 VPU operations a row; :func:`_narrow_divmod` some
+    40.  Both branches write the answer, zero rule included, so the
+    conditional's output is the program's and not a temporary beside it.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    x, y = x.astype(jnp.int64), y.astype(jnp.int64)
+
+    def narrow(x, y):
+        y = y.astype(jnp.int32)
+        zero = y == 0
+        q, r = _narrow_divmod(x.astype(jnp.int32), jnp.where(zero, 1, y))
+        return jnp.where(zero, 0, r if want == "mod" else q).astype(jnp.int64)
+
+    wide = functools.partial(_zero_safe_divmod, want=want)
+    return jax.lax.cond(_fits_narrow(x) & _fits_narrow(y), narrow, wide, x, y)
+
+
+@functools.lru_cache(maxsize=None)
+def _jit_guarded_divmod(want: str):
+    # jitted so that eval_shape of a node and the trace of a fused plan see
+    # one call, cached by aval, where the body's hundred equations would be
+    return traced_jit(
+        functools.partial(_guarded_divmod, want=want), f"guarded_{want}"
+    )
+
+
+def _divmod(x, y, want: str):
+    """The registry's ``mod`` / ``floordiv`` (and, operands swapped, their
+    reflections): integers answer 0 for a zero divisor, see the module's
+    docstring; an int64 result takes :func:`_guarded_divmod`."""
+    import jax.numpy as jnp
+
+    res_dtype = jnp.result_type(x, y)
+    if res_dtype == np.dtype(np.int64):
+        return _jit_guarded_divmod(want)(x, y)
+    if jnp.issubdtype(res_dtype, jnp.integer):
+        return _zero_safe_divmod(x, y, want)
+    return _plain_divmod(x, y, want)
 
 
 def _truediv(x, y):
@@ -73,10 +164,10 @@ def _build_ops() -> dict:
         "rmul": lambda x, y: y * x,
         "truediv": _truediv,
         "rtruediv": lambda x, y: _truediv(y, x) if not np.isscalar(y) else _truediv(jnp.asarray(y), x),
-        "floordiv": _floordiv,
-        "rfloordiv": lambda x, y: _floordiv(y, x),
-        "mod": _mod,
-        "rmod": lambda x, y: _mod(y, x),
+        "floordiv": lambda x, y: _divmod(x, y, "floordiv"),
+        "rfloordiv": lambda x, y: _divmod(y, x, "floordiv"),
+        "mod": lambda x, y: _divmod(x, y, "mod"),
+        "rmod": lambda x, y: _divmod(y, x, "mod"),
         "pow": lambda x, y: x ** y,
         "rpow": lambda x, y: y ** x,
         "eq": lambda x, y: x == y,
@@ -185,8 +276,14 @@ def binary_op_columns(op_name: str, cols: List[Any], other: Any) -> List[Any]:
 
     _ensure_ops()
     if isinstance(other, (list, tuple)):
-        return [lazy_op(op_name, c, o) for c, o in zip(cols, other)]
-    return [lazy_op(op_name, c, other) for c in cols]
+        out = [lazy_op(op_name, c, o) for c, o in zip(cols, other)]
+    else:
+        out = [lazy_op(op_name, c, other) for c in cols]
+    if _meters.ACCOUNTING_ON and op_name in _DIVMOD_OPS:
+        guarded = sum(e.dtype == np.int64 for e in out)
+        if guarded:
+            _meters.note_elementwise_form("divmod_guarded", guarded)
+    return out
 
 
 def unary_op_columns(op_name: str, cols: List[Any]) -> List[Any]:

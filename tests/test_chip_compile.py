@@ -195,6 +195,50 @@ def test_script_phrasing_programs_compile_for_v5e_at_1e8_rows(program, arg, shap
     assert resident < HBM_BYTES
 
 
+@pytest.mark.parametrize(
+    "op, temp_bound",
+    [
+        ("mod", 1.5e9),
+        # the 64-bit floor division alone keeps 3.1 GB of word halves alive,
+        # guarded or not (the unguarded program read 3.103 GB here)
+        ("floordiv", 3.3e9),
+    ],
+)
+def test_guarded_divmod_compiles_for_v5e_at_the_asv_frame(op, temp_bound, shapes):
+    """``df.mod(2)`` of the asv frame (ten int64 columns of 5e7 rows, the
+    divisor a runtime scalar) as the fused plan builds it: one conditional a
+    column, no 32-bit integer divide for the compiler to expand (an s32
+    ``rem`` compiles for 21 s here and its run time is unknown), and
+    temporaries that stay a fraction of the 4 GB answer.  They are the split
+    halves of one column (0.4 GB), the conditional's output (0.4 GB) and the
+    wide branch's own intermediates (0.4 GB), shared by the ten columns:
+    1.2 GB for ``mod``, where the unguarded program, which may overwrite the
+    halves in place, holds 0.6 GB."""
+    import re
+
+    import jax
+    import numpy as np
+
+    from modin_tpu.ops.elementwise import get_op
+
+    rows, n_cols = 50_000_000, 10
+
+    def plan(scalars, *cols):
+        return tuple(get_op(op)(c, scalars[0]) for c in cols)
+
+    cols = [shapes((rows,), np.int64) for _ in range(n_cols)]
+    compiled = jax.jit(plan).lower((2,), *cols).compile()
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes >= rows * 8 * n_cols
+    assert mem.temp_size_in_bytes < temp_bound, mem
+    resident = mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes
+    assert resident < HBM_BYTES
+    text = compiled.as_text()
+    assert len(re.findall(r" conditional\(", text)) == n_cols
+    integer_divides = re.findall(r"= [su]32\[[^=]*? (?:divide|remainder)\(", text)
+    assert integer_divides == []
+
+
 def test_sharded_bincount_compiles_for_four_v5e_chips(topo, shapes):
     """Mosaic kernels cannot be partitioned automatically: over a row-sharded
     operand the bincount must sit in a ``shard_map`` (first four-chip run of
@@ -226,15 +270,7 @@ def test_bincount_reduces_its_one_hot_on_the_mxu():
     import jax.numpy as jnp
 
     from modin_tpu.ops.pallas.groupby_kernels import pallas_bincount
-
-    def eqns_of(jaxpr):
-        for eqn in jaxpr.eqns:
-            yield eqn
-            for param in eqn.params.values():
-                for sub in param if isinstance(param, (tuple, list)) else (param,):
-                    sub = getattr(sub, "jaxpr", sub)
-                    if hasattr(sub, "eqns"):
-                        yield from eqns_of(sub)
+    from tests.utils import jaxpr_eqns as eqns_of
 
     codes = jnp.zeros(5_000, jnp.int32)
     traced = jax.make_jaxpr(lambda c: pallas_bincount(c, 100, interpret=True))(codes)

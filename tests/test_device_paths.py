@@ -13,7 +13,7 @@ import pytest
 
 import modin_tpu.pandas as pd
 from modin_tpu.core.storage_formats.tpu.query_compiler import TpuQueryCompiler
-from tests.utils import df_equals
+from tests.utils import df_equals, jaxpr_eqns
 
 
 @pytest.fixture(autouse=True)
@@ -283,3 +283,138 @@ def test_float64_policy_downcast():
         # computed results carry f32 precision (the policy's tradeoff)
         got = float((md["a"] * 2.0).sum())
         np.testing.assert_allclose(got, (x.astype(np.float32) * 2).sum(), rtol=1e-5)
+
+
+# ---------------------------------------------------------------------- #
+# the guarded 64-bit integer mod / floordiv (ops/elementwise.py)
+# ---------------------------------------------------------------------- #
+
+_I32 = 2**31
+_DIVMOD_OPERANDS = {
+    # the asv frame's range: every word fits, the narrow branch answers
+    "small": np.arange(200, dtype=np.int64) % 100,
+    "negative": np.arange(-150, 150, dtype=np.int64),
+    # the widest operands the narrow branch takes
+    "narrow_edges": np.array(
+        [-_I32 + 1, _I32 - 1, -_I32 + 2, _I32 - 2, 0, 1, -1, 7, -7, 65536, -65537],
+        dtype=np.int64,
+    ),
+    # fits int32, but its magnitude and its quotient by -1 do not
+    "int32_min": np.array([-_I32, 5, -5, 0, _I32 - 1], dtype=np.int64),
+    "past_the_edges": np.array(
+        [-_I32 - 1, _I32, _I32 + 1, -_I32 + 1, 2**40, -(2**40), 3, -3],
+        dtype=np.int64,
+    ),
+    "one_wide_value": np.where(
+        np.arange(300) == 123, 2**40 + 17, np.arange(300) % 100
+    ).astype(np.int64),
+}
+_DIVMOD_DIVISORS = [2, -3, 1, -1, _I32 - 1, -_I32, _I32, 2**40]
+
+
+@pytest.mark.parametrize("divisor", _DIVMOD_DIVISORS)
+@pytest.mark.parametrize("operands", list(_DIVMOD_OPERANDS))
+@pytest.mark.parametrize("op", ["mod", "floordiv"])
+@pytest.mark.parametrize("kind", ["frame", "series"])
+def test_int64_divmod_by_scalar_is_exact_on_device(kind, op, operands, divisor):
+    values = _DIVMOD_OPERANDS[operands]
+    if kind == "frame":
+        pdf = pandas.DataFrame({"a": values, "b": values[::-1], "c": -values})
+        mdf = pd.DataFrame(pdf)
+    else:
+        pdf = pandas.Series(values, name="a")
+        mdf = pd.Series(pdf)
+    result = assert_no_fallback(lambda: getattr(mdf, op)(divisor))
+    assert all(c.is_device for c in result._query_compiler._modin_frame._columns)
+    df_equals(result, getattr(pdf, op)(divisor))
+
+
+@pytest.mark.parametrize("operands", list(_DIVMOD_OPERANDS))
+@pytest.mark.parametrize("op", ["mod", "floordiv"])
+@pytest.mark.parametrize(
+    "divisor_kind", ["list_a_column", "frame", "operators", "numpy_reflected"]
+)
+def test_int64_divmod_by_other_divisors_is_exact(divisor_kind, op, operands):
+    """A divisor a column, a frame divisor, ``%`` / ``//`` / ``divmod`` and
+    the reflected forms through ``modin_tpu.numpy``: whichever path answers
+    (the query compiler refuses integer data as a divisor), pandas' values."""
+    import modin_tpu.numpy as mnp
+
+    values = _DIVMOD_OPERANDS[operands]
+    pdf = pandas.DataFrame({"a": values, "b": values[::-1]})
+    mdf = pd.DataFrame(pdf)
+    if divisor_kind == "list_a_column":
+        df_equals(getattr(mdf, op)([2, -3], axis=1), getattr(pdf, op)([2, -3], axis=1))
+    elif divisor_kind == "frame":
+        other = pandas.DataFrame({"a": np.full(len(values), -3), "b": np.full(len(values), _I32 - 1)})
+        df_equals(getattr(mdf, op)(pd.DataFrame(other)), getattr(pdf, op)(other))
+    elif divisor_kind == "operators":
+        if op == "mod":
+            df_equals(mdf % 7, pdf % 7)
+            df_equals(divmod(mdf["a"], -7)[1], divmod(pdf["a"], -7)[1])
+        else:
+            df_equals(mdf // 7, pdf // 7)
+            df_equals(divmod(mdf["a"], -7)[0], divmod(pdf["a"], -7)[0])
+    else:
+        nonzero = np.where(values == 0, 3, values)
+        arr = mnp.array(nonzero)
+        fn, ref = (
+            (mnp.remainder, np.remainder) if op == "mod" else (mnp.floor_divide, np.floor_divide)
+        )
+        np.testing.assert_array_equal(np.asarray(fn(1000003, arr)), ref(1000003, nonzero))
+        np.testing.assert_array_equal(np.asarray(fn(arr, -3)), ref(nonzero, -3))
+
+
+@pytest.mark.parametrize("op", ["mod", "floordiv"])
+@pytest.mark.parametrize("dtype", ["int32", "uint64", "int16", "float64"])
+def test_divmod_of_other_dtypes_is_unchanged(dtype, op):
+    """Only an int64 result is guarded: narrower integers, uint64 and floats
+    keep their dtype and their answers."""
+    values = np.array([0, 1, 5, 99, 100, 32767, 7, 12], dtype=dtype)
+    pdf = pandas.DataFrame({"a": values, "b": values[::-1]})
+    result = assert_no_fallback(lambda: getattr(pd.DataFrame(pdf), op)(3))
+    expected = getattr(pdf, op)(3)
+    assert list(result.dtypes) == list(expected.dtypes)
+    df_equals(result, expected)
+
+
+@pytest.mark.parametrize("want", ["mod", "floordiv"])
+def test_the_guard_is_one_cond_whose_narrow_branch_never_divides_integers(want):
+    import jax
+    import jax.numpy as jnp
+
+    from modin_tpu.ops import elementwise
+
+    column = jnp.arange(64, dtype=jnp.int64)
+    traced = jax.make_jaxpr(elementwise._jit_guarded_divmod(want))(column, jnp.asarray(2))
+    conds = [e for e in jaxpr_eqns(traced.jaxpr) if e.primitive.name == "cond"]
+    assert len(conds) == 1
+    # branches[1] answers a true predicate: the narrow one
+    wide, narrow = conds[0].params["branches"]
+    int_divides = lambda branch: [  # noqa: E731
+        e.primitive.name
+        for e in jaxpr_eqns(branch.jaxpr)
+        if e.primitive.name in ("div", "rem")
+        and jnp.issubdtype(e.invars[0].aval.dtype, jnp.integer)
+    ]
+    assert int_divides(narrow) == []
+    assert int_divides(wide)  # today's 64-bit division, kept for wide operands
+    assert all(v.aval.dtype == jnp.int64 for v in conds[0].outvars)
+
+
+@pytest.mark.parametrize(
+    "values, narrow",
+    [
+        ([0, 99, -99, _I32 - 1, -_I32 + 1], True),
+        ([0, 99, _I32], False),  # one value past int32
+        ([0, 99, -_I32 - 1], False),
+        ([0, 99, 2**40], False),
+        ([0, 99, -_I32], False),  # fits, but -2**31 // -1 would not
+    ],
+)
+def test_the_guards_predicate_reads_the_operands_words(values, narrow):
+    import jax.numpy as jnp
+
+    from modin_tpu.ops import elementwise
+
+    assert bool(elementwise._fits_narrow(jnp.asarray(values, jnp.int64))) is narrow

@@ -344,6 +344,37 @@ class TestQueryStats:
         assert "api_calls" not in record  # removed: nothing read it
         json.dumps(record)  # /debug/queries and recent_queries serialise it
 
+    @pytest.mark.parametrize(
+        "dtype, method, scoped, expect",
+        [
+            ("int64", "mod", True, {"divmod_guarded": 10}),
+            ("int64", "floordiv", True, {"divmod_guarded": 10}),
+            ("float64", "mod", True, {}),
+            ("int32", "mod", True, {}),  # the result stays int32: nothing to guard
+            ("int64", "add", True, {}),
+            ("int64", "mod", False, {}),
+        ],
+    )
+    def test_elementwise_forms_count_the_guarded_divmod_columns(
+        self, dtype, method, scoped, expect
+    ):
+        """One count a column where a 64-bit integer ``mod`` / ``floordiv``
+        node is built, in the request record; nothing outside a scope."""
+        _require_tpu_on_jax()
+        frame = pd.DataFrame(
+            np.arange(40, dtype=dtype).reshape(4, 10), columns=list("abcdefghij")
+        )
+        if not scoped:
+            getattr(frame, method)(2)
+            with meters.query_stats("after") as qs:
+                pass
+        else:
+            with meters.query_stats("q") as qs:
+                answer = getattr(frame, method)(2)
+            assert answer._query_compiler._modin_frame._columns[0].is_device
+        assert qs.elementwise_forms == expect
+        assert qs.as_dict()["elementwise_forms"] == expect
+
     def test_uploads_are_counted_at_put_and_upload(self):
         from modin_tpu.parallel.engine import JaxWrapper, upload
 

@@ -467,3 +467,30 @@ def test_spmd_declines_on_single_shard_mesh(mesh_reshaper, forced_sharded):
     dev = JaxWrapper.put(pad_host(np.arange(64, dtype=np.int64)))
     assert sharded_sorted_valid(dev, 64) is None
     assert sharded_merge_positions(dev, dev, 64, 64, "inner") is None
+
+
+# ---------------------------------------------------------------------- #
+# the guarded int64 mod / floordiv: its predicate is one scalar over the
+# whole row-sharded column, its conditional the same on every shard
+# ---------------------------------------------------------------------- #
+
+
+@pytest.mark.parametrize("shape", [(1, 1), (4, 1), (8, 1)])
+@pytest.mark.parametrize("op", ["mod", "floordiv"])
+def test_guarded_divmod_answers_alike_on_one_shard_and_row_sharded(
+    shape, op, mesh_reshaper
+):
+    assert mesh_reshaper(shape) == shape[0]
+    rng = np.random.default_rng(37)
+    n = 803  # ragged: the last shard holds pad rows
+    narrow = rng.integers(-(2**31) + 1, 2**31, n)
+    wide_in_last_shard = narrow.copy()
+    wide_in_last_shard[790] = 2**40 + 3  # one shard alone sees a wide word
+    wide_in_first_shard = narrow.copy()
+    wide_in_first_shard[2] = -(2**31)
+    pdf = pandas.DataFrame(
+        {"narrow": narrow, "last": wide_in_last_shard, "first": wide_in_first_shard}
+    )
+    mdf = pd.DataFrame(pdf)
+    for divisor in (2, -3, 2**31 - 1, 2**40):
+        df_equals(getattr(mdf, op)(divisor), getattr(pdf, op)(divisor))

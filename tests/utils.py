@@ -180,3 +180,15 @@ def require_tpu_execution() -> None:
 
     if get_current_execution() != "TpuOnJax":
         pytest.skip("TpuOnJax-specific path")
+
+
+def jaxpr_eqns(jaxpr):
+    """Every equation of a jaxpr, those of its sub-jaxprs (a ``jit`` call, a
+    ``cond``'s branches, a Pallas kernel's body) included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (tuple, list)) else (param,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from jaxpr_eqns(sub)
