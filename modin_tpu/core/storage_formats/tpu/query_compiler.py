@@ -82,6 +82,19 @@ def _decide_windowed(op: str, frames: tuple) -> bool:
     return router.decide_residency(op, est, resident) == "windowed"
 
 
+def _named_aggs(f: Any) -> bool:
+    """A dict ``agg``'s value the device path takes: an aggregation's name, or
+    a list of distinct names."""
+    if isinstance(f, str):
+        return True
+    return (
+        isinstance(f, list)
+        and bool(f)
+        and all(isinstance(x, str) for x in f)
+        and len(set(f)) == len(f)
+    )
+
+
 class TpuQueryCompiler(BaseQueryCompiler):
     """Query compiler over a TpuDataframe (sharded jax.Array columns).
 
@@ -4334,10 +4347,12 @@ class TpuQueryCompiler(BaseQueryCompiler):
         self, by, agg_func, axis, groupby_kwargs, agg_args, agg_kwargs, drop,
         series_groupby, selection,
     ) -> Optional["TpuQueryCompiler"]:
-        """agg(["sum", "mean"]) / agg({"col": "sum"}) on device: one
-        factorization (memoized), one segment kernel per aggregation, columns
-        combined like pandas (MultiIndex (col, agg) for lists, flat for
-        dicts).  The factorize cache makes the per-agg passes cheap.
+        """agg(["sum", "mean"]) / agg({"col": "sum"}) / agg({"col": ["median",
+        "std"]}) on device: one factorization (memoized), one kernel per
+        aggregation, columns combined like pandas (MultiIndex (col, agg) for
+        lists and for a dict with a list among its values, in the dict's and
+        the lists' order; flat for a dict of strings).  The factorize cache
+        makes the per-agg passes cheap.
 
         ``sort=False`` and ``as_index=False`` (the H2O script's phrasing) are
         honoured once for the combined answer by :meth:`_assemble_groupby`:
@@ -4388,17 +4403,24 @@ class TpuQueryCompiler(BaseQueryCompiler):
             and agg_func
             and not series_groupby
             and selection is None
-            and all(isinstance(f, str) for f in agg_func.values())
+            and all(_named_aggs(f) for f in agg_func.values())
         ):
-            picks = []
+            # a list among the values labels every column (column, agg), as
+            # pandas does, a string value included
+            nested = any(isinstance(f, list) for f in agg_func.values())
+            picks, labels = [], []
             for col, f in agg_func.items():
-                part = run_one(f, [col])
-                if part is None:
-                    return None
-                if len(part.datas) != 1:
-                    return None
-                picks.append((part, 0))
-            new_labels = pandas.Index(list(agg_func))
+                for fname in f if isinstance(f, list) else [f]:
+                    part = run_one(fname, [col])
+                    if part is None:
+                        return None
+                    if len(part.datas) != 1:
+                        return None
+                    picks.append((part, 0))
+                    labels.append((col, fname) if nested else col)
+            new_labels = (
+                pandas.MultiIndex.from_tuples(labels) if nested else pandas.Index(labels)
+            )
         else:
             return None
         combined = picks[0][0]._replace(

@@ -506,8 +506,10 @@ def note_launch(program: str) -> None:
 def note_groupby_form(form: str) -> None:
     """One choice of a groupby reduction's or histogram's device form
     (``ops/groupby.py``, which checks ``ACCOUNTING_ON`` first): ``limb_dot``,
-    ``masked_scan``, ``sorted_tiles``, ``segment``, ``pallas_bincount`` or
-    ``scatter_counts``."""
+    ``masked_scan``, ``sorted_tiles`` (``sorted_tiles_max`` / ``_min`` /
+    ``_var`` / ``_std`` / ``_sem`` for the tiles' other reductions),
+    ``segment``, ``pallas_bincount``, ``scatter_counts``, or a per-group
+    quantile's ``median_sort_select`` / ``quantile_sort_select``."""
     stack = _spans.thread_requests()
     if stack:
         for qs in stack:

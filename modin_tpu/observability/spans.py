@@ -141,10 +141,18 @@ SPANS = (
     (
         "groupby.reduce",
         "one device groupby aggregation (ops/groupby.py groupby_reduce): the "
-        "form chosen for it (limb_dot / masked_scan / sorted_tiles / segment / "
+        "form chosen for it (limb_dot / masked_scan / sorted_tiles / "
+        "sorted_tiles_max / _min / _var / _std / _sem / segment / "
         "pallas_bincount / scatter_counts), agg, num_groups and n_cols in "
         "attributes; innermost QUERY-COMPILER span, so the compile ledger "
         "bills the reduction's programs to it",
+    ),
+    (
+        "groupby.quantile",
+        "one device per-group quantile or median (ops/groupby.py "
+        "groupby_quantile): the form counted for it (median_sort_select / "
+        "quantile_sort_select), q, num_groups and n_cols in "
+        "attributes; tagged QUERY-COMPILER as groupby.reduce is",
     ),
     (
         "groupby.factorize",

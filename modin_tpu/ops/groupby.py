@@ -42,7 +42,7 @@ SEGMENT_AGGS = {
 # order-statistic aggregations: device sort within groups (the reference
 # routes these through range-partitioning + per-shard pandas,
 # modin/core/dataframe/pandas/dataframe/dataframe.py:4163; on TPU a
-# lexsort + gather keeps the whole thing on device)
+# sort + gather keeps the whole thing on device)
 ORDER_AGGS = {"median", "quantile", "nunique", "first", "last"}
 
 _RANGE_LIMIT = 1 << 22  # max direct-range width before falling back to unique
@@ -697,14 +697,17 @@ def _jit_pad_to(p_out: int):
 # - masked_scan: the one-hot spans every group at once and is reduced on the
 #   VPU, O(n*G) 64-bit selects and adds, no scatter: min/max/prod/any/all up to
 #   that limit, and sum/mean/count of a row-sharded operand.
-# - sorted_tiles: sum/mean/count above the limit, up to _RANGE_LIMIT, on a
-#   one-shard TPU (sort row chunks by code, one-hot each run of sorted rows
-#   against the few consecutive codes it holds).
+# - sorted_tiles: sum/mean/count/max/min above the limit, and var/std/sem at
+#   any group count, up to _RANGE_LIMIT, on a one-shard TPU (sort row chunks by
+#   code, one-hot each run of sorted rows against the few consecutive codes it
+#   holds; the spread walks twice, centred on the group means).
 # - segment: XLA's scatter-based segment ops, which serialise on a TPU (146 ns
-#   a row and 64-bit column at 1e8 rows), are left with var/std/sem, with
-#   min/max/prod/any/all above the limit, with row-sharded operands above it,
-#   and with the CPU, where scatters are fine.
+#   a row and 64-bit column at 1e8 rows), are left with prod/any/all above the
+#   limit, with row-sharded operands above it (var/std/sem at any count), and
+#   with the CPU, where scatters are fine.
 _MASKED_SCAN_MAX_GROUPS = 1024
+# what the sorted tiles reduce (the spread's own walk aside)
+_TILES_AGGS = frozenset({"sum", "mean", "count", "max", "min"})
 _SCAN_CHUNK = 65536
 # limb_dot: rows cut into words at a time (a word temporary is 4 MB)
 _LIMB_CHUNK = 1 << 20
@@ -1384,17 +1387,20 @@ def _jit_sorted_tiles(
     p_out: int,
     has_sizes: bool,
     chunk: int,
+    ddof: int = 1,
 ):
-    """sum/mean/count (and ``size``: the histogram, no value column) for many
-    groups, with neither scatter nor per-row gather.
+    """sum/mean/count (and ``size``: the histogram, no value column), max/min
+    and the centred var/std/sem for many groups, with neither scatter nor
+    per-row gather.
 
     Rows are taken ``chunk`` at a time and sorted by code, the value columns
     travelling with their key (one variadic ``lax.sort``).  Sorted rows are cut
     into blocks of ``_tile_rows``; a block's codes ascend from its first, so a
     one-hot against ``first + arange(_TILE_IDS)`` reduces the block into
-    ``_TILE_IDS`` partial sums (the masked scan's ``where(oh, x, 0).sum``), for
-    every block of the chunk at once.  The partials are then added into the
-    ``[G]`` accumulators at offset ``first``, one contiguous
+    ``_TILE_IDS`` partials (the masked scan's ``where(oh, x, 0).sum``, or its
+    ``where(oh, x, -inf).max`` for max/min), for every block of the chunk at
+    once.  The partials are then combined into the ``[G]`` accumulators at
+    offset ``first`` (added, or taken the max/min of), one contiguous
     ``dynamic_update_slice`` a block.  A block whose codes reach past its first
     tile (a sparse stretch of the key) takes further tiles afterwards, each
     starting at its next code not yet summed, so a chunk costs at most
@@ -1402,8 +1408,18 @@ def _jit_sorted_tiles(
 
     Sums are exact for integers and accumulated in the column's own float
     width, NaN skipped, as in the other forms; only the order of a group's
-    float additions differs.  Pad and dropped rows carry the overflow code
-    (``factorize_keys``) and land in its bucket, which is sliced off.
+    float additions differs.  max/min keep the column's dtype; a float column
+    skips NaN and counts its valid rows, so a group of NaN alone is NaN and a
+    group whose max is -inf stays -inf.  var/std/sem are two walks in one
+    program: the first sums and counts (every column read as float64, as
+    pandas computes the spread; a float32 answer is cast back), the second
+    adds each row's distance ``d`` from its group's mean, read through the
+    block's one-hot, and its square: ``(sum(d**2) - sum(d)**2 / n) / (n -
+    ddof)``, the corrected
+    two-pass form whose second term takes the mean's own rounding out (never
+    ``sum(x**2) - sum(x)**2 / n``; NaN where ``n - ddof`` is not positive).
+    Pad and dropped rows carry the overflow code (``factorize_keys``) and land
+    in its bucket, which is sliced off.
     """
     import jax
     import jax.lax as lax
@@ -1412,8 +1428,10 @@ def _jit_sorted_tiles(
     n_groups = num_segments - 1  # also the code of pad and dropped rows
     T = _TILE_IDS
     acc_len = num_segments + T  # a tile written at offset n_groups still fits
-    need_sum = agg in ("sum", "mean")
-    need_cnt = agg in ("mean", "count")
+    extreme = agg in ("max", "min")
+    spread = agg in ("var", "std", "sem")
+    need_sum = agg in ("sum", "mean") or spread
+    need_cnt = agg in ("mean", "count") or spread
 
     def fn(cols: Tuple, codes, sizes_in=None):
         # ``codes``: the array, or (key, kmin, n) of a dense range (RangeCodes)
@@ -1424,26 +1442,43 @@ def _jit_sorted_tiles(
         W = _tile_rows(take, n_groups)
         cp = -(-take // W) * W  # rows sorted a step
         B = cp // W
-        is_float = [bool(jnp.issubdtype(c.dtype, jnp.floating)) for c in cols]
-        # which one-hot sums a tile makes: a sum a column, a valid count a
-        # float column (NaN skipped), and one shared row count for the integer
-        # columns and ``size`` unless the factorisation brought it
-        col_cnt = [need_cnt and f for f in is_float]
+        # the dtype a column is reduced in: its own, or float64 for the spread
+        # of any column (as pandas computes it; a float32 spread is cast back)
+        work = [np.dtype(np.float64) if spread else np.dtype(c.dtype) for c in cols]
+        is_float = [bool(jnp.issubdtype(w, jnp.floating)) for w in work]
+        # which one-hot reductions a tile makes: a sum (or max / min) a
+        # column, a valid count a float column (NaN skipped), and one shared
+        # row count for the integer columns and ``size`` unless the
+        # factorisation brought it
+        col_cnt = [(need_cnt or extreme) and f for f in is_float]
         shared_cnt = agg == "size" or (
             need_cnt and not has_sizes and not all(is_float)
         )
         # the columns that travel with the codes (a count reads only floats)
-        moved = [i for i in range(n_cols) if need_sum or col_cnt[i]]
+        moved = [i for i in range(n_cols) if need_sum or extreme or col_cnt[i]]
         tid = jnp.arange(T, dtype=jnp.int32)
+        if extreme:
+            neutral = {
+                i: (
+                    (-jnp.inf if agg == "max" else jnp.inf)
+                    if is_float[i]
+                    else (_INT_MINS if agg == "max" else _INT_MAXES)[str(work[i])]
+                )
+                for i in moved
+            }
+            pick = jnp.max if agg == "max" else jnp.min
 
         def tile(s, xs, first):
-            """Partial sums of blocks ``s`` ([b, W] sorted codes, ``xs`` their
+            """Partials of blocks ``s`` ([b, W] sorted codes, ``xs`` their
             values) against the codes ``first[:, None] + arange(T)``."""
             oh = (s - first[:, None])[:, :, None] == tid[None, None, :]
             out = []
             for i, x in zip(moved, xs):
                 nanm = jnp.isnan(x) if is_float[i] else None
-                if need_sum:
+                if extreme:
+                    xz = jnp.where(nanm, neutral[i], x) if is_float[i] else x
+                    out.append(pick(jnp.where(oh, xz[:, :, None], neutral[i]), axis=1))
+                elif need_sum:
                     xz = jnp.where(nanm, 0, x) if is_float[i] else x
                     out.append(jnp.sum(jnp.where(oh, xz[:, :, None], 0), axis=1))
                 if col_cnt[i]:
@@ -1454,83 +1489,148 @@ def _jit_sorted_tiles(
                 out.append(jnp.sum(oh, axis=1, dtype=jnp.int32))
             return out
 
-        def add_at(accs, offset, parts):
-            return [
-                lax.dynamic_update_slice(
-                    a, lax.dynamic_slice(a, (offset,), (T,)) + p.astype(a.dtype), (offset,)
-                )
-                for a, p in zip(accs, parts)
-            ]
+        def walk(init, tile, combine):
+            """One pass over the rows, a chunk at a time: sort, reduce the
+            blocks with ``tile``, place the partials with ``combine``."""
 
-        def step(i, accs):
-            start = jnp.minimum(i * take, P - take)
-            pos = start + jnp.arange(take)
-            # the last step reaches back over rows the one before has summed
-            live = pos >= i * take
-            with jax.named_scope("chunk_sort"):
-                if key is None:
-                    cc = lax.dynamic_slice(codes, (start,), (take,))
-                else:
-                    live &= pos < n
-                    kc = lax.dynamic_slice(key, (start,), (take,)).astype(jnp.int64)
-                    cc = jnp.clip(kc - kmin, 0, n_groups)
-                cc = jnp.where(live, cc.astype(jnp.int32), n_groups)
-                xs = [lax.dynamic_slice(cols[i], (start,), (take,)) for i in moved]
-                if cp > take:
-                    cc = jnp.concatenate([cc, jnp.full(cp - take, n_groups, jnp.int32)])
-                    xs = [jnp.concatenate([x, jnp.zeros(cp - take, x.dtype)]) for x in xs]
-                s, *xs = lax.sort((cc, *xs), num_keys=1, is_stable=False)
-                s = s.reshape(B, W)
-                xs = [x.reshape(B, W) for x in xs]
-            first, last = s[:, 0], s[:, -1]
-            with jax.named_scope("tile_reduce"):
-                parts = tile(s, xs, first)
-            with jax.named_scope("tile_place"):
-                accs = lax.fori_loop(
-                    0, B,
-                    lambda b, accs: add_at(accs, first[b], [p[b] for p in parts]),
-                    accs, unroll=4,
-                )
+            def add_at(accs, offset, parts):
+                return [
+                    lax.dynamic_update_slice(
+                        a,
+                        op(lax.dynamic_slice(a, (offset,), (T,)), p.astype(a.dtype)),
+                        (offset,),
+                    )
+                    for a, p, op in zip(accs, parts, combine)
+                ]
 
-            # the blocks whose codes reach past their first tile, in turn: each
-            # takes tiles from its next code not yet summed until its last
-            spills = last - first >= T
-            n_spills = jnp.sum(spills, dtype=jnp.int32)
-            turn = jnp.argsort(~spills, stable=True).astype(jnp.int32)
+            def step(i, accs):
+                start = jnp.minimum(i * take, P - take)
+                pos = start + jnp.arange(take)
+                # the last step reaches back over rows the one before has summed
+                live = pos >= i * take
+                with jax.named_scope("chunk_sort"):
+                    if key is None:
+                        cc = lax.dynamic_slice(codes, (start,), (take,))
+                    else:
+                        live &= pos < n
+                        kc = lax.dynamic_slice(key, (start,), (take,)).astype(jnp.int64)
+                        cc = jnp.clip(kc - kmin, 0, n_groups)
+                    cc = jnp.where(live, cc.astype(jnp.int32), n_groups)
+                    xs = [lax.dynamic_slice(cols[i], (start,), (take,)) for i in moved]
+                    xs = [
+                        x if x.dtype == work[i] else x.astype(work[i])
+                        for i, x in zip(moved, xs)
+                    ]
+                    if cp > take:
+                        cc = jnp.concatenate([cc, jnp.full(cp - take, n_groups, jnp.int32)])
+                        xs = [jnp.concatenate([x, jnp.zeros(cp - take, x.dtype)]) for x in xs]
+                    s, *xs = lax.sort((cc, *xs), num_keys=1, is_stable=False)
+                    s = s.reshape(B, W)
+                    xs = [x.reshape(B, W) for x in xs]
+                first, last = s[:, 0], s[:, -1]
+                with jax.named_scope("tile_reduce"):
+                    parts = tile(s, xs, first)
+                with jax.named_scope("tile_place"):
+                    accs = lax.fori_loop(
+                        0, B,
+                        lambda b, accs: add_at(accs, first[b], [p[b] for p in parts]),
+                        accs, unroll=4,
+                    )
 
-            def further(state):
-                k, done, accs = state
-                b = turn[k]
-                sb = lax.dynamic_slice(s, (b, jnp.int32(0)), (1, W))
-                xb = [lax.dynamic_slice(x, (b, jnp.int32(0)), (1, W)) for x in xs]
-                nxt = jnp.min(jnp.where(sb >= done, sb, n_groups))
-                accs = add_at(accs, nxt, [p[0] for p in tile(sb, xb, nxt[None])])
-                over = nxt + T > last[b]
-                after = turn[jnp.minimum(k + 1, B - 1)]
-                return (
-                    jnp.where(over, k + 1, k),
-                    jnp.where(over, first[after] + T, nxt + T),
-                    accs,
-                )
+                # the blocks whose codes reach past their first tile, in turn:
+                # each takes tiles from its next code not yet summed until its
+                # last
+                spills = last - first >= T
+                n_spills = jnp.sum(spills, dtype=jnp.int32)
+                turn = jnp.argsort(~spills, stable=True).astype(jnp.int32)
 
-            with jax.named_scope("tile_spill"):
-                return lax.while_loop(
-                    lambda state: state[0] < n_spills,
-                    further,
-                    (jnp.int32(0), first[turn[0]] + T, accs),
-                )[2]
+                def further(state):
+                    k, done, accs = state
+                    b = turn[k]
+                    sb = lax.dynamic_slice(s, (b, jnp.int32(0)), (1, W))
+                    xb = [lax.dynamic_slice(x, (b, jnp.int32(0)), (1, W)) for x in xs]
+                    nxt = jnp.min(jnp.where(sb >= done, sb, n_groups))
+                    accs = add_at(accs, nxt, [p[0] for p in tile(sb, xb, nxt[None])])
+                    over = nxt + T > last[b]
+                    after = turn[jnp.minimum(k + 1, B - 1)]
+                    return (
+                        jnp.where(over, k + 1, k),
+                        jnp.where(over, first[after] + T, nxt + T),
+                        accs,
+                    )
 
+                with jax.named_scope("tile_spill"):
+                    return lax.while_loop(
+                        lambda state: state[0] < n_spills,
+                        further,
+                        (jnp.int32(0), first[turn[0]] + T, accs),
+                    )[2]
+
+            return lax.fori_loop(0, steps, step, init) if init else []
+
+        add, combine = (lambda a, p: a + p), []
         init = []
         for i in moved:
-            if need_sum:
-                init.append(jnp.zeros(acc_len, cols[i].dtype))
+            if extreme:
+                init.append(jnp.full(acc_len, neutral[i], work[i]))
+                combine.append(jnp.maximum if agg == "max" else jnp.minimum)
+            elif need_sum:
+                init.append(jnp.zeros(acc_len, work[i]))
+                combine.append(add)
             if col_cnt[i]:
                 init.append(jnp.zeros(acc_len, jnp.int64))
+                combine.append(add)
         if shared_cnt:
             init.append(jnp.zeros(acc_len, jnp.int64))
-        accs = lax.fori_loop(0, steps, step, init) if init else []
-        accs = [a[:num_segments] for a in accs]
+            combine.append(add)
+        accs = walk(init, tile, combine)
 
+        if spread:
+            # every column is a float here: (sum, valid count) a column
+            totals, cnts = accs[0::2], accs[1::2]
+            means = [
+                jnp.where(c > 0, t / jnp.maximum(c, 1).astype(t.dtype), 0)
+                for t, c in zip(totals, cnts)
+            ]
+
+            def squares(s, xs, first):
+                """Each row's distance from its group's mean (the block's
+                ``T`` means, read through its one-hot): the distances and their
+                squares, summed."""
+                oh = (s - first[:, None])[:, :, None] == tid[None, None, :]
+                out = []
+                for m, x in zip(means, xs):
+                    mt = jax.vmap(lambda f: lax.dynamic_slice(m, (f,), (T,)))(first)
+                    at = jnp.sum(jnp.where(oh, mt[:, None, :], 0), axis=2)
+                    d = jnp.where(jnp.isnan(x), 0, x - at)
+                    out.append(jnp.sum(jnp.where(oh, d[:, :, None], 0), axis=1))
+                    out.append(jnp.sum(jnp.where(oh, (d * d)[:, :, None], 0), axis=1))
+                return out
+
+            with jax.named_scope("spread_walk"):
+                dev = walk(
+                    [jnp.zeros(acc_len, t.dtype) for t in totals for _ in "12"],
+                    squares, [add] * (2 * n_cols),
+                )
+            out = []
+            for d1, d2, c, col in zip(dev[0::2], dev[1::2], cnts, cols):
+                d1, d2, c = d1[:num_segments], d2[:num_segments], c[:num_segments]
+                # the corrected two-pass sum: the rounding of the mean, which
+                # adds n * err**2 to the squares, is taken out again
+                n_f = jnp.maximum(c, 1).astype(d2.dtype)
+                q = d2 - d1 * d1 / n_f
+                dof = c - ddof
+                r = jnp.where(dof > 0, q / jnp.maximum(dof, 1).astype(q.dtype), jnp.nan)
+                if agg == "std":
+                    r = jnp.sqrt(r)
+                elif agg == "sem":
+                    r = jnp.sqrt(r / c.astype(r.dtype))
+                if jnp.issubdtype(col.dtype, jnp.floating):
+                    r = r.astype(col.dtype)
+                out.append(_slice_pad(r, n_groups, p_out))
+            return tuple(out)
+
+        accs = [a[:num_segments] for a in accs]
         if agg == "size":
             return _slice_pad(accs[0], n_groups, p_out)
         sizes = sizes_in if has_sizes else (accs[-1] if shared_cnt else None)
@@ -1538,13 +1638,15 @@ def _jit_sorted_tiles(
         k = 0
         for i in range(n_cols):
             total = cnt = None
-            if need_sum:
+            if need_sum or extreme:
                 total = accs[k]; k += 1
             if col_cnt[i]:
                 cnt = accs[k]; k += 1
             elif need_cnt:
                 cnt = sizes
-            if agg == "sum":
+            if extreme:
+                r = jnp.where(cnt == 0, jnp.nan, total) if is_float[i] else total
+            elif agg == "sum":
                 r = total
             elif agg == "count":
                 r = cnt.astype(jnp.int64)
@@ -1894,9 +1996,9 @@ def groupby_reduce(
         )
     form = _reduce_form(agg, codes, num_groups, sizes, value_cols)
     if _meters.ACCOUNTING_ON and form != "host_sizes":
-        _meters.note_groupby_form(form)
+        _meters.note_groupby_form(_form_name(form, agg))
     with _spans.span(
-        "groupby.reduce", layer="QUERY-COMPILER", form=form, agg=agg,
+        "groupby.reduce", layer="QUERY-COMPILER", form=_form_name(form, agg), agg=agg,
         num_groups=num_groups, n_cols=len(value_cols),
     ):
         if agg == "size":
@@ -1928,8 +2030,9 @@ def groupby_reduce(
                 agg, len(value_cols), ns, p_out, _SCAN_CHUNK, single, has_sizes
             )
         elif form == "sorted_tiles":
+            spread = (int(ddof),) if agg in ("var", "std", "sem") else ()
             fn = _jit_sorted_tiles(
-                agg, len(value_cols), ns, p_out, has_sizes, _SORT_CHUNK
+                agg, len(value_cols), ns, p_out, has_sizes, _SORT_CHUNK, *spread
             )
         else:
             has_sizes = single and sizes is not None and agg in ("sum", "mean", "count")
@@ -1950,27 +2053,42 @@ def _reduce_form(
 ) -> str:
     """The device form of one aggregation, read from the aggregation, the
     group count, the platform, the shard count and the columns' dtypes (no
-    option): see the note above ``_MASKED_SCAN_MAX_GROUPS``.  min/max/prod/any/
-    all above the masked scan's limit, var/std/sem (two-pass, centred), and
-    everything on a row-sharded mesh above that limit keep the scatter-based
-    segment ops."""
+    option): see the note above ``_MASKED_SCAN_MAX_GROUPS``.  On a one-shard
+    TPU var/std/sem take the sorted tiles (two walks, centred) at every group
+    count up to ``_RANGE_LIMIT``, and max/min above the masked scan's limit;
+    prod/any/all above that limit, everything on a row-sharded mesh above it
+    (var/std/sem at any count) and the CPU keep the scatter-based segment
+    ops."""
     from modin_tpu.parallel.mesh import num_row_shards
 
     if agg == "size":
         return "host_sizes" if sizes is not None else _histogram_form(codes, num_groups)
-    if _tpu_forms(codes) and agg not in ("var", "std", "sem"):
-        smc = agg in ("sum", "mean", "count") and num_row_shards() == 1
-        if num_groups <= _MASKED_SCAN_MAX_GROUPS:
-            if (
-                smc
-                and _FORCE_KERNEL != "masked_scan"
-                and all(_limb_dot_takes(c.dtype) for c in value_cols)
-            ):
-                return "limb_dot"
-            return "masked_scan"
-        if smc and num_groups <= _RANGE_LIMIT:
-            return "sorted_tiles"
+    if not _tpu_forms(codes):
+        return "segment"
+    single = num_row_shards() == 1
+    if agg in ("var", "std", "sem"):
+        return "sorted_tiles" if single and num_groups <= _RANGE_LIMIT else "segment"
+    if num_groups <= _MASKED_SCAN_MAX_GROUPS:
+        if (
+            single
+            and agg in ("sum", "mean", "count")
+            and _FORCE_KERNEL != "masked_scan"
+            and all(_limb_dot_takes(c.dtype) for c in value_cols)
+        ):
+            return "limb_dot"
+        return "masked_scan"
+    if single and num_groups <= _RANGE_LIMIT and agg in _TILES_AGGS:
+        return "sorted_tiles"
     return "segment"
+
+
+def _form_name(form: str, agg: str) -> str:
+    """What ``groupby_forms`` counts: the sorted tiles' max / min and spread
+    under names of their own (``sorted_tiles_max``, ``sorted_tiles_var``...),
+    sum / mean / count under the form's name, as before they existed."""
+    if form == "sorted_tiles" and agg not in ("sum", "mean", "count"):
+        return f"{form}_{agg}"
+    return form
 
 
 # ---------------------------------------------------------------------- #
@@ -1978,78 +2096,86 @@ def _reduce_form(
 # ---------------------------------------------------------------------- #
 
 
+# A per-group quantile (median included): one sort of the whole column by
+# (code, value), the value travelling; a NaN row takes the overflow code, so a
+# group's valid values are one ascending run, found by a binary search of the
+# sorted codes for every group's first row (G searches of log2(rows) steps; no
+# count is scattered); the order statistics are gathered at each run's ranks.
+# Exact: a result is a value of the column, or pandas' arithmetic on two of
+# them.  The same program on every platform and mesh (a row-sharded column is
+# sorted whole, as any global sort is).
+
+
 @functools.lru_cache(maxsize=None)
-def _jit_group_quantile(
+def _jit_group_sort_select(
     n_cols: int,
     num_segments: int,
     p_out: int,
     q: float,
     interpolation: str,
-    preserve_float_dtype: bool = False,
+    median: bool,
 ):
-    """Grouped quantile: lexsort by (code, value), gather at quantile ranks.
-
-    NaNs sort to each group's tail (jnp sort order), so the non-NaN prefix of
-    a group is its valid sample; ranks index into that prefix.
-    """
+    """Per-group quantile of each column by one sort of (code, value) and a
+    binary search of the sorted codes (see the note above).  ``median``:
+    pandas' median, ``(a + b) / 2`` of the two middle values for an even
+    count, float32 kept; otherwise pandas' ``quantile(q, interpolation)``, in
+    float64, or for lower / higher / nearest of an integer column sorted in
+    its own dtype (so int64 stays exact) and answered in int64, as pandas
+    answers."""
     import jax
+    import jax.lax as lax
     import jax.numpy as jnp
 
     n_groups = num_segments - 1
 
-    def finish(r):
+    def one(c, codes):
+        is_float = jnp.issubdtype(c.dtype, jnp.floating)
+        # pandas keeps the integer dtype for the non-interpolating kinds
+        keep_int = not median and not is_float and interpolation in ("lower", "higher", "nearest")
+        x = c if keep_int or c.dtype == jnp.float32 else c.astype(jnp.float64)
+        if is_float:
+            # NaN rows join the pad rows past every group
+            codes = jnp.where(jnp.isnan(c), n_groups, codes)
+        s, xs = lax.sort((codes.astype(jnp.int32), x), num_keys=2)
+        with jax.named_scope("group_runs"):
+            bounds = jnp.searchsorted(
+                s, jnp.arange(num_segments, dtype=jnp.int32), side="left"
+            ).astype(jnp.int32)
+        start, vcnt = bounds[:-1], bounds[1:] - bounds[:-1]
+        last = xs.shape[0] - 1
+
+        def at(rank):
+            v = jnp.take(xs, jnp.clip(start + rank, 0, last))
+            return v if keep_int else v.astype(jnp.float64)
+
+        if median:
+            r = (at((vcnt - 1) // 2) + at(vcnt // 2)) / 2
+        else:
+            target = q * (vcnt.astype(jnp.float64) - 1.0)
+            lo = jnp.floor(target).astype(jnp.int32)
+            v_lo, v_hi = at(lo), at(jnp.ceil(target).astype(jnp.int32))
+            if interpolation == "linear":
+                r = v_lo + (v_hi - v_lo) * (target - lo.astype(jnp.float64))
+            elif interpolation == "lower":
+                r = v_lo
+            elif interpolation == "higher":
+                r = v_hi
+            elif interpolation == "midpoint":
+                r = (v_lo + v_hi) * 0.5
+            else:  # nearest: numpy rounds the virtual rank half-to-even
+                r = at(jnp.round(target).astype(jnp.int32))
+        if keep_int:
+            r = r.astype(jnp.int64)  # pandas answers any integer width in int64
+        else:
+            r = jnp.where(vcnt == 0, jnp.nan, r)
+        if median and c.dtype == jnp.float32:
+            r = r.astype(c.dtype)  # pandas' median keeps float32
         return _slice_pad(r, n_groups, p_out)
 
-    def one(c, codes, starts):
-        # pandas keeps the integer dtype for the non-interpolating kinds
-        keep_int = (
-            interpolation in ("lower", "higher", "nearest")
-            and not jnp.issubdtype(c.dtype, jnp.floating)
-        )
-        x = c if keep_int else c.astype(jnp.float64)
-        nanm = (
-            jnp.isnan(x) if jnp.issubdtype(x.dtype, jnp.floating)
-            else jnp.zeros(x.shape, bool)
-        )
-        order = jnp.lexsort((x, codes))
-        xs = jnp.take(x, order)
-        vcnt = jax.ops.segment_sum(
-            (~nanm).astype(jnp.int64), codes, num_segments=num_segments
-        )[:n_groups]
-        g_start = starts[:n_groups]
-        target = q * (vcnt.astype(jnp.float64) - 1.0)
-        lo = jnp.floor(target).astype(jnp.int64)
-        hi = jnp.ceil(target).astype(jnp.int64)
-        max_pos = xs.shape[0] - 1
-        v_lo = jnp.take(xs, jnp.clip(g_start + lo, 0, max_pos))
-        v_hi = jnp.take(xs, jnp.clip(g_start + hi, 0, max_pos))
-        frac = target - lo.astype(jnp.float64)
-        if interpolation == "linear":
-            r = v_lo + (v_hi - v_lo) * frac
-        elif interpolation == "lower":
-            r = v_lo
-        elif interpolation == "higher":
-            r = v_hi
-        elif interpolation == "midpoint":
-            r = (v_lo + v_hi) * 0.5
-        else:  # nearest — numpy rounds the virtual rank half-to-even
-            pos = jnp.round(target).astype(jnp.int64)
-            r = jnp.take(xs, jnp.clip(g_start + pos, 0, max_pos))
-        if not keep_int:
-            r = jnp.where(vcnt == 0, jnp.nan, r)
-            if preserve_float_dtype and jnp.issubdtype(c.dtype, jnp.floating):
-                # pandas groupby median keeps float32; quantile widens to f64
-                r = r.astype(c.dtype)
-        return finish(r)
-
     def fn(cols: Tuple, codes):
-        total = jax.ops.segment_sum(
-            jnp.ones(codes.shape, jnp.int64), codes, num_segments=num_segments
-        )
-        starts = jnp.cumsum(total) - total
-        return tuple(one(c, codes, starts) for c in cols)
+        return tuple(one(c, codes) for c in cols)
 
-    return named_jit(fn, "groupby_group_quantile")
+    return named_jit(fn, "groupby_group_median" if median else "groupby_group_sort_select")
 
 
 def groupby_quantile(
@@ -2061,14 +2187,26 @@ def groupby_quantile(
     interpolation: str = "linear",
     preserve_float_dtype: bool = False,
 ) -> List[Any]:
-    """Per-group quantile of each value column (device lexsort + gather)."""
+    """Per-group quantile of each value column, every ``q`` and
+    interpolation by one program (``_jit_group_sort_select``);
+    ``preserve_float_dtype`` is the median (pandas keeps float32 there and
+    takes ``(a + b) / 2``).  Counted in ``groupby_forms`` as
+    ``median_sort_select`` / ``quantile_sort_select``, under a span
+    ``groupby.quantile``."""
     from modin_tpu.ops.structural import pad_len
 
-    fn = _jit_group_quantile(
-        len(value_cols), num_groups + 1, pad_len(num_groups), float(q),
-        str(interpolation), bool(preserve_float_dtype),
-    )
-    return list(fn(tuple(value_cols), codes_array(codes)))
+    name = ("median_" if preserve_float_dtype else "quantile_") + "sort_select"
+    if _meters.ACCOUNTING_ON:
+        _meters.note_groupby_form(name)
+    with _spans.span(
+        "groupby.quantile", layer="QUERY-COMPILER", form=name, q=float(q),
+        num_groups=num_groups, n_cols=len(value_cols),
+    ):
+        fn = _jit_group_sort_select(
+            len(value_cols), num_groups + 1, pad_len(num_groups), float(q),
+            str(interpolation), bool(preserve_float_dtype),
+        )
+        return list(fn(tuple(value_cols), codes_array(codes)))
 
 
 @functools.lru_cache(maxsize=None)

@@ -291,12 +291,17 @@ class DataFrameGroupBy(ClassLogger, modin_layer="PANDAS-API"):
                 (isinstance(func, list) and all(isinstance(f, str) for f in func))
                 or (
                     isinstance(func, dict)
-                    and all(isinstance(f, str) for f in func.values())
+                    and all(
+                        isinstance(f, str)
+                        or (isinstance(f, list) and all(isinstance(g, str) for g in f))
+                        for f in func.values()
+                    )
                 )
             )
         ):
-            # list-of-strings / dict-of-strings pass through intact so the
-            # compiler's device multi-agg path can see them
+            # list-of-strings / dict of strings or of lists of strings pass
+            # through intact so the compiler's device multi-agg path can see
+            # them
             return self._groupby_agg(func)
         return self._groupby_agg(
             func if isinstance(func, str) else (lambda grp, *a, **kw: grp.agg(try_cast_to_pandas(func), *a, **kw)),

@@ -17,7 +17,7 @@ described-chip entry cannot be read back without a chip).  Code that asks
 lowered directly over ``ShapeDtypeStruct``s.
 
 Only the fast compiles live here (each a second or two; the many-groups
-histogram, which sorts, about five).  The 1e8-row lexsort (about 70 s) and
+histogram, which sorts, about five; the tiles' max and spread about 20).  The 1e8-row lexsort (about 70 s) and
 the ewm blocked scan (minutes) were compiled once by hand for PR 22, the
 sorted-tiles sums (25 s a 64-bit column here, 12 s on the chip's host) for
 PR 31; their numbers are in PERF.md.
@@ -237,6 +237,57 @@ def test_guarded_divmod_compiles_for_v5e_at_the_asv_frame(op, temp_bound, shapes
     assert len(re.findall(r" conditional\(", text)) == n_cols
     integer_divides = re.findall(r"= [su]32\[[^=]*? (?:divide|remainder)\(", text)
     assert integer_divides == []
+
+
+@pytest.mark.parametrize(
+    "agg, groups, dtype",
+    [
+        # q7 of the H2O script: max v1 (min v2 is the same program) by id3
+        ("max", 1_000_000, "int64"),
+        # q6: the centred sd of v3 by id4 x id5, two walks in one program
+        ("std", 10_000, "float64"),
+    ],
+)
+def test_sorted_tiles_statistics_compile_for_v5e_at_1e8_rows(agg, groups, dtype, shapes):
+    """The tiles' max / min and centred spread (PR 39): no scatter, and
+    temporaries of a chunk's sort and one-hots, not of the column (18 and 20 s
+    here; 0.80 and 0.84 GB of temporaries when written)."""
+    import numpy as np
+
+    from modin_tpu.ops import groupby
+
+    spread = (1,) if agg == "std" else ()
+    lowered = groupby._jit_sorted_tiles(
+        agg, 1, groups + 1, groups, False, groupby._SORT_CHUNK, *spread
+    ).lower((shapes((ROWS,), dtype),), shapes((ROWS,), np.int32))
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    text = compiled.as_text()
+    assert " sort(" in text and " scatter(" not in text
+    assert mem.temp_size_in_bytes < 1.2e9, mem
+    assert mem.argument_size_in_bytes >= ROWS * (4 + np.dtype(dtype).itemsize)
+
+
+@pytest.mark.slow
+def test_group_median_compiles_for_v5e_at_1e8_rows(shapes):
+    """q6's median (PR 39): one sort of the (int32 code, float64 value) pairs
+    and a binary search, no scatter; the sort's result beside its operands.
+    Slow: a sort keyed on float64 compiles for 255 s here (PERF.md), so this
+    compile is not among the tier-1 tests."""
+    import numpy as np
+
+    from modin_tpu.ops import groupby
+
+    lowered = groupby._jit_group_sort_select(1, 10_001, 10_000, 0.5, "linear", True).lower(
+        (shapes((ROWS,), np.float64),), shapes((ROWS,), np.int32)
+    )
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    text = compiled.as_text()
+    assert " sort(" in text and " scatter(" not in text
+    assert mem.temp_size_in_bytes < ROWS * (4 + 8) * 1.5, mem
+    resident = mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes
+    assert resident < HBM_BYTES
 
 
 def test_sharded_bincount_compiles_for_four_v5e_chips(topo, shapes):

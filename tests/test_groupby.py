@@ -1044,9 +1044,20 @@ def test_groupby_form_reads_group_count_platform_and_shards():
             assert gb_ops._reduce_form(agg, codes, gb_ops._RANGE_LIMIT + 1, None) == "segment"
         for agg in ("min", "max", "prod", "any", "all"):
             assert gb_ops._reduce_form(agg, codes, limit, None) == "masked_scan"
+        # max / min take the tiles past the scan's limit, the others scatter
+        for agg in ("min", "max"):
+            assert gb_ops._reduce_form(agg, codes, limit + 1, None) == "sorted_tiles"
+            assert gb_ops._reduce_form(agg, codes, gb_ops._RANGE_LIMIT + 1, None) == "segment"
+        for agg in ("prod", "any", "all"):
             assert gb_ops._reduce_form(agg, codes, limit + 1, None) == "segment"
+        # the centred spread walks the tiles twice at every group count
         for agg in ("var", "std", "sem"):
-            assert gb_ops._reduce_form(agg, codes, 100, None) == "segment"
+            assert gb_ops._reduce_form(agg, codes, 100, None) == "sorted_tiles"
+            assert gb_ops._reduce_form(agg, codes, limit + 1, None) == "sorted_tiles"
+            assert gb_ops._reduce_form(agg, codes, gb_ops._RANGE_LIMIT + 1, None) == "segment"
+            assert gb_ops._form_name("sorted_tiles", agg) == "sorted_tiles_" + agg
+        for agg in ("sum", "mean", "count"):
+            assert gb_ops._form_name("sorted_tiles", agg) == "sorted_tiles"
         assert gb_ops._reduce_form("size", codes, limit + 1, np.ones(limit + 1)) == "host_sizes"
         assert gb_ops._reduce_form("size", codes, limit + 1, None) == "sorted_tiles"
         assert gb_ops._histogram_form(codes, 512) == "pallas_bincount"
@@ -1072,6 +1083,8 @@ def test_groupby_form_reads_group_count_platform_and_shards():
         assert num_row_shards() == 4
         assert gb_ops._reduce_form("sum", codes, 1024, None) == "masked_scan"
         assert gb_ops._reduce_form("sum", codes, 1025, None) == "segment"
+        assert gb_ops._reduce_form("max", codes, 1025, None) == "segment"
+        assert gb_ops._reduce_form("std", codes, 100, None) == "segment"
         assert gb_ops._histogram_form(codes, 70_000) == "scatter_counts"
 
 
