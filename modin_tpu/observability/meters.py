@@ -529,6 +529,17 @@ def note_elementwise_form(form: str, times: int) -> None:
             qs._on_elementwise_form(form, times)
 
 
+def note_reduction_form(form: str) -> None:
+    """One row-wise reduction's form, chosen from its column count
+    (``ops/reductions.py``, which checks ``ACCOUNTING_ON`` first):
+    ``axis1_columns`` (the k columns read as k arrays) or ``axis1_stacked``
+    (a frame wider than the network stacks them into one matrix)."""
+    stack = _spans.thread_requests()
+    if stack:
+        for qs in stack:
+            qs._on_reduction_form(form)
+
+
 def note_host_sync(nbytes: int) -> None:
     """One blocking device->host fetch of ``nbytes`` on this thread."""
     stack = _spans.thread_requests()
@@ -607,6 +618,7 @@ class QueryStats:
         "launches_by_program",
         "groupby_forms",
         "elementwise_forms",
+        "reduction_forms",
         "host_syncs",
         "d2h_bytes",
         "h2d_bytes",
@@ -693,6 +705,8 @@ class QueryStats:
         self.groupby_forms: Dict[str, int] = {}
         # columns of elementwise nodes built in an operand-adaptive form
         self.elementwise_forms: Dict[str, int] = {}
+        # row-wise reductions by the form their column count chose
+        self.reduction_forms: Dict[str, int] = {}
         self.host_syncs = 0
         self.d2h_bytes = 0
         self.h2d_bytes = 0
@@ -823,6 +837,11 @@ class QueryStats:
                     self.elementwise_forms.get(form, 0) + times
                 )
 
+    def _on_reduction_form(self, form: str) -> None:
+        with self._lock:
+            if not self._closed:
+                self.reduction_forms[form] = self.reduction_forms.get(form, 0) + 1
+
     def _on_host_sync(self, nbytes: int) -> None:
         with self._lock:
             if not self._closed:
@@ -898,6 +917,7 @@ class QueryStats:
             "launches_by_program": dict(self.launches_by_program),
             "groupby_forms": dict(self.groupby_forms),
             "elementwise_forms": dict(self.elementwise_forms),
+            "reduction_forms": dict(self.reduction_forms),
             "host_syncs": self.host_syncs,
             "d2h_bytes": self.d2h_bytes,
             "h2d_bytes": self.h2d_bytes,

@@ -239,6 +239,51 @@ def test_guarded_divmod_compiles_for_v5e_at_the_asv_frame(op, temp_bound, shapes
     assert integer_divides == []
 
 
+@pytest.mark.parametrize("method", ["median", "nunique"])
+def test_row_reductions_compile_for_v5e_at_the_asv_frame(method, shapes):
+    """``median(axis=1)`` / ``nunique(axis=1)`` of the asv frame (ten int64
+    columns of 5e7 rows) as a one-chip mesh lowers them: the columns read as
+    ten arrays, a chunk of rows at a time through the sorting network, so
+    neither the (n, 10) matrix nor its sort is in the program."""
+    import jax
+    import numpy as np
+
+    from modin_tpu.config import MeshShape
+    from modin_tpu.ops import reductions
+    from modin_tpu.parallel.mesh import reset_mesh
+
+    rows, n_cols = 50_000_000, 10
+    cols = tuple(shapes((rows,), np.int64) for _ in range(n_cols))
+    was = MeshShape.get()
+    MeshShape.put((1, 1))
+    reset_mesh()
+    try:
+        form = reductions._axis1_form(n_cols)
+        assert form == "axis1_columns"
+        if method == "nunique":
+            lowered = reductions._jit_nunique_axis1(n_cols, rows, True, form).lower(cols)
+        else:
+            inner = reductions._make_axis1_fn(method, form, True, 1)
+            lowered = jax.jit(lambda *c: inner(c)).lower(*cols)
+        compiled = lowered.compile()
+    finally:
+        MeshShape.put(was)
+        reset_mesh()
+    text = compiled.as_text()
+    assert " sort(" not in text and "concatenate(" not in text
+    mem = compiled.memory_analysis()
+    # The compile reads 4.20 GB of temporaries for both.  The chip keeps an
+    # int64 as two 32-bit words, and XLA splits each input column into them
+    # once (X64SplitLow / X64SplitHigh): 8 bytes a value, 4.0 GB for the ten
+    # columns, which the parent's stacked program held too, beside its 4 GB
+    # matrix and the sort's copy (9.6 GB).  What is left is the answer's
+    # buffer; the network's intermediates stay in a chunk's vector memory
+    # (unchunked, the median held 8.85 GB of them).
+    halves = rows * 8 * n_cols
+    assert mem.temp_size_in_bytes < halves + rows * 8 + (64 << 20), mem
+    assert mem.argument_size_in_bytes >= halves
+
+
 @pytest.mark.parametrize(
     "agg, groups, dtype",
     [

@@ -375,6 +375,39 @@ class TestQueryStats:
         assert qs.elementwise_forms == expect
         assert qs.as_dict()["elementwise_forms"] == expect
 
+    @pytest.mark.parametrize(
+        "n_cols, method, scoped, expect",
+        [
+            (10, "median", True, {"axis1_columns": 1}),
+            (10, "nunique", True, {"axis1_columns": 1}),
+            (10, "sum", True, {"axis1_columns": 1}),
+            (33, "median", True, {"axis1_stacked": 1}),
+            (33, "nunique", True, {"axis1_stacked": 1}),
+            (10, "median", False, {}),
+        ],
+    )
+    def test_reduction_forms_count_the_row_reductions_by_form(
+        self, n_cols, method, scoped, expect
+    ):
+        """One count a row-wise reduction, by the form its column count
+        chose: up to ``_AXIS1_COLUMNS_MAX`` columns read as column arrays,
+        one more stacked; nothing outside a scope."""
+        from modin_tpu.ops.reductions import _AXIS1_COLUMNS_MAX
+
+        _require_tpu_on_jax()
+        assert _AXIS1_COLUMNS_MAX + 1 == 33
+        frame = pd.DataFrame(np.arange(8 * n_cols).reshape(8, n_cols))
+        if not scoped:
+            getattr(frame, method)(axis=1)
+            with meters.query_stats("after") as qs:
+                pass
+        else:
+            with meters.query_stats("q") as qs:
+                answer = getattr(frame, method)(axis=1)
+            assert answer._query_compiler._modin_frame._columns[0].is_device
+        assert qs.reduction_forms == expect
+        assert qs.as_dict()["reduction_forms"] == expect
+
     def test_uploads_are_counted_at_put_and_upload(self):
         from modin_tpu.parallel.engine import JaxWrapper, upload
 
