@@ -948,9 +948,8 @@ class CostCapture(EnvironmentVariable, type=str):
     - ``On``: always capture (cost_analysis via the compile-free AOT
       ``lower()`` path);
     - ``Full``: also capture ``memory_analysis()`` (peak/temp/argument
-      bytes) — pays one extra AOT backend compile per billed compile, with
-      the compile-ledger listener suppressed so the extra compile is never
-      billed as workload;
+      bytes) of the executable each billed compile built, read from jax's
+      caches with no second backend compile (``costs.program_memory``);
     - ``Off``: never capture, even while accounting is on.
     """
 

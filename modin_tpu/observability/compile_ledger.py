@@ -20,9 +20,17 @@ turns that into a per-op-signature ledger:
   for its signature.
 - **recompile storms** — ``recompile_storms(min_compiles)`` names the
   signatures compiled suspiciously often; ``snapshot()`` feeds dashboards.
+- **how each program was made** — while a ``query_stats`` scope is open on
+  the thread, the listeners also take jax's trace, lowering and
+  persistent-cache-retrieval events and bill each program's seconds into the
+  scope's ``programs_made`` (``meters.note_made``).  jax opens a trace event
+  for every jitted function it traces, and a program's trace encloses the
+  traces of the functions its body calls; only the outermost trace or
+  lowering open on the thread is billed, less the compiles that fired inside
+  it, so ``trace_s + lower_s + compile_s`` never counts a second twice.
 
-The listener is process-global and effectively free when idle (it runs only
-when XLA actually compiles); it is installed at engine startup
+The listeners are process-global and effectively free when idle (they run
+only when jax traces or XLA compiles); they are installed at engine startup
 (``initialize_jax``), when ``MODIN_TPU_TRACE`` turns on, and by
 ``profile()``.
 """
@@ -31,31 +39,38 @@ from __future__ import annotations
 
 import contextlib
 import threading
-from typing import Dict, Iterator, Optional
+from typing import Dict, Iterator, List, Optional
 
 from modin_tpu.concurrency import named_lock
+from modin_tpu.observability import meters as _meters
 from modin_tpu.observability import spans as _spans
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+#: fires inside the compile event, and only when the persistent cache held
+#: the executable
+CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+#: ``programs_made`` field each made-program event is billed to
+_PHASES = {TRACE_EVENT: "trace_s", LOWER_EVENT: "lower_s", COMPILE_EVENT: "compile_s"}
 
 _tls = threading.local()
 
 
 @contextlib.contextmanager
-def suppress_listener() -> Iterator[None]:
-    """Hide compile events fired on this thread from the ledger.
+def relowering() -> Iterator[None]:
+    """Bill no trace or lowering that fires on this thread inside the block.
 
-    graftcost's ``Full`` capture mode AOT-compiles a program the engine
-    already compiled (``memory_analysis()`` needs the executable); without
-    suppression that duplicate backend compile would be billed as workload
-    — doubling ``engine.compile`` counts and poisoning the cache-hit
-    accounting the metrics gate checks.
+    graftcost's static capture and the memory read of a made program lower
+    a program again after its call: jax answers from its caches, but still
+    fires a (short) trace event, and the call already billed the real one.
     """
-    _tls.suppress = getattr(_tls, "suppress", 0) + 1
+    _tls.relowering = getattr(_tls, "relowering", 0) + 1
     try:
         yield
     finally:
-        _tls.suppress -= 1
+        _tls.relowering -= 1
 
 
 class CompileLedger:
@@ -151,9 +166,9 @@ def compiles_on_this_thread() -> int:
 
 def _on_event_duration(event: str, duration: float, **kwargs: object) -> None:
     if event != COMPILE_EVENT:
+        if _meters.ACCOUNTING_ON:
+            _phase_end(event, duration, kwargs.get("fun_name"))
         return
-    if getattr(_tls, "suppress", 0):
-        return  # graftcost's own AOT capture compile: not workload
     try:
         _tls.compiles = getattr(_tls, "compiles", 0) + 1
         _LEDGER.record_compile(_spans.attribution_signature(), duration)
@@ -161,12 +176,90 @@ def _on_event_duration(event: str, duration: float, **kwargs: object) -> None:
             sp = _spans.current_span()
             if sp is not None:
                 sp.attrs["compile_s"] = sp.attrs.get("compile_s", 0.0) + duration
-        from modin_tpu.observability import meters as _meters
-
         if _meters.ACCOUNTING_ON:
             _meters.note_compile(duration)
+            _phase_end(event, duration, kwargs.get("fun_name"))
     except Exception:
         # a broken listener must never break the compile it observes
+        pass
+
+
+def _on_event_start(event: str, value: float, **kwargs: object) -> None:
+    """jax's ``record_scalar`` at the start of a trace, lowering or compile
+    (its start time): opens the event's frame on this thread's stack."""
+    if not _meters.ACCOUNTING_ON:
+        return
+    try:
+        if event not in _PHASES or (event != COMPILE_EVENT and _relowering()):
+            return
+        stack = _phase_stack()
+        if stack is not None:
+            # [event, fun_name, compile seconds inside it, loaded from the cache]
+            stack.append([event, kwargs.get("fun_name"), 0.0, False])
+    except Exception:
+        pass
+
+
+def _relowering() -> bool:
+    return bool(getattr(_tls, "relowering", 0))
+
+
+def _phase_stack() -> Optional[List[list]]:
+    """This thread's open trace / lowering / compile frames, or None where no
+    ``query_stats`` scope is open on it.  The stack belongs to the outermost
+    open scope: one that closed with a frame still open (a scope closed
+    inside a trace) leaves nothing behind for the next."""
+    scopes = _spans.thread_requests()
+    if not scopes:
+        return None
+    owner = scopes[0].request_id
+    held = getattr(_tls, "phases", None)
+    if held is None or held[0] != owner:
+        held = _tls.phases = (owner, [])
+    return held[1]
+
+
+def _program_of(fun_name: object) -> str:
+    """The ``named_jit`` name of a program from jax's ``fun_name``: ``name``
+    for a trace, ``jit(name)`` (``pmap(name)``) for its lowering and compile."""
+    name = str(fun_name)
+    if name.endswith(")") and "(" in name:
+        return name[name.index("(") + 1 : -1]
+    return name
+
+
+def _phase_end(event: str, duration: float, fun_name: object) -> None:
+    """A trace, lowering or compile event ended (or a cache retrieval fired
+    inside a compile): bill it to the open scopes as its program's, if it is
+    the outermost open on this thread; a compile always, and its seconds are
+    taken off the trace or lowering it fired in."""
+    try:
+        if event == CACHE_LOAD_EVENT:
+            stack = _phase_stack()
+            if stack and stack[-1][0] == COMPILE_EVENT:
+                stack[-1][3] = True
+            return
+        if event not in _PHASES or (event != COMPILE_EVENT and _relowering()):
+            return
+        stack = _phase_stack()
+        if stack is None:
+            return
+        frame = None
+        if stack and stack[-1][0] == event and stack[-1][1] == fun_name:
+            frame = stack.pop()
+        if event == COMPILE_EVENT:
+            if stack:
+                stack[-1][2] += duration
+            loaded = frame is not None and frame[3]
+            _meters.note_made(_program_of(fun_name), "compile_s", duration, loaded)
+            return
+        nested = frame[2] if frame is not None else 0.0
+        if stack:
+            # part of the trace or lowering that encloses it: billed there
+            stack[-1][2] += nested
+            return
+        _meters.note_made(_program_of(fun_name), _PHASES[event], max(duration - nested, 0.0))
+    except Exception:
         pass
 
 
@@ -175,7 +268,7 @@ _install_lock = named_lock("compile_ledger.install")
 
 
 def ensure_listener() -> bool:
-    """Idempotently register the jax.monitoring compile listener.
+    """Idempotently register the jax.monitoring listeners.
 
     Returns True when the listener is (now) installed; False when jax is
     unavailable (the ledger then simply stays empty).
@@ -191,5 +284,6 @@ def ensure_listener() -> bool:
         except Exception:
             return False
         monitoring.register_event_duration_secs_listener(_on_event_duration)
+        monitoring.register_scalar_listener(_on_event_start)
         _installed = True
         return True

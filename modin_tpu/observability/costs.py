@@ -10,12 +10,11 @@ legs, all riding the seams the earlier layers already cut:
    accessed, transcendentals — available WITHOUT a backend compile, so the
    default capture adds only a re-trace/lower, never a second backend
    compile) and, under ``MODIN_TPU_COST_CAPTURE=Full``,
-   ``compiled.memory_analysis()`` (peak/temp/argument bytes — this one
-   needs a real AOT compile, so it is opt-in and the compile-ledger
-   listener is suppressed while it runs to keep the billing honest).
-   Anything missing — None analysis, absent keys, a backend that cannot
-   answer — degrades to ``"unknown"``; capture NEVER raises into the
-   dispatch it observes.
+   ``compiled.memory_analysis()`` (peak/temp/argument bytes) of the
+   executable the call built: :func:`program_memory`, which the memory read
+   of every made program shares.  Anything missing — None analysis, absent
+   keys, a backend that cannot answer — degrades to ``"unknown"``; capture
+   NEVER raises into the dispatch it observes.
 
 2. **Achieved efficiency.**  Captured flops/bytes join the engine-seam
    dispatch wall into achieved FLOP/s, achieved bandwidth, and a roofline
@@ -57,8 +56,8 @@ from modin_tpu.observability import spans as _spans
 #: attribute before doing anything else.
 COST_ON: bool = False
 
-#: True only under ``MODIN_TPU_COST_CAPTURE=Full``: memory_analysis capture
-#: pays a real AOT backend compile (listener-suppressed) per billed compile.
+#: True only under ``MODIN_TPU_COST_CAPTURE=Full``: a billed compile also
+#: captures the memory_analysis of the executable it built.
 FULL_CAPTURE: bool = False
 
 UNKNOWN = "unknown"
@@ -195,34 +194,26 @@ def capture_static(func: Any, f_args: tuple, f_kwargs: Optional[dict]) -> Dict[s
 
     Uses the AOT ``lower()`` path: ``Lowered.cost_analysis()`` answers from
     the unoptimized HLO without a backend compile (measured: no
-    ``backend_compile_duration`` event fires).  Under ``Full`` mode the
-    lowered program IS backend-compiled once more for ``memory_analysis()``
-    — with the compile-ledger listener suppressed so the extra compile is
-    never billed as workload.  Any failure anywhere yields unknown fields.
+    ``backend_compile_duration`` event fires); its trace event is not billed
+    (``compile_ledger.relowering``).  Under ``Full`` mode it adds
+    :func:`program_memory` of the executable the call built.  Any failure
+    anywhere yields unknown fields.
     """
+    from modin_tpu.observability import compile_ledger as _ledger_mod
+
     cost = dict(_UNKNOWN_COST)
     try:
         lower = getattr(func, "lower", None)
         if lower is None:
             return cost
-        lowered = lower(*f_args, **(f_kwargs or {}))
+        with _ledger_mod.relowering():
+            lowered = lower(*f_args, **(f_kwargs or {}))
         try:
             _merge_known(cost, extract_cost(lowered.cost_analysis()))
         except Exception:
             pass
         if FULL_CAPTURE:
-            from modin_tpu.observability import compile_ledger as _ledger_mod
-
-            with _ledger_mod.suppress_listener():
-                compiled = lowered.compile()
-            try:
-                _merge_known(cost, extract_cost(compiled.cost_analysis()))
-            except Exception:
-                pass
-            try:
-                _merge_known(cost, extract_memory(compiled.memory_analysis()))
-            except Exception:
-                pass
+            _merge_known(cost, program_memory(func, f_args, f_kwargs) or {})
     except Exception:
         # a broken capture must never break the dispatch it observes
         pass
@@ -440,6 +431,57 @@ def _arg_key(f_args: tuple, f_kwargs: Optional[dict]) -> tuple:
         else:
             key.append(type(item).__name__)
     return tuple(key)
+
+
+#: per-jitted-function memo of :func:`program_memory`, keyed as _func_costs
+_func_memory: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
+
+#: set once a memory read fired a backend compile of its own (jax did not
+#: hand back the executable the call built): no read is made after it
+_memory_read_compiles = False
+
+
+def program_memory(func: Any, f_args: tuple, f_kwargs: Optional[dict]) -> Optional[Dict[str, Any]]:
+    """:func:`extract_memory` of the executable that a call of the jitted
+    ``func`` (or a ``named_jit`` program) with these arguments built, read
+    once per (function, argument shapes/dtypes); ``None`` where it cannot be
+    read.
+
+    Made after the call: ``lower(...).compile()`` is then answered from jax's
+    caches, the call's own lowering and executable, and fires no backend
+    compile (``tests/test_meters.py`` holds it), a persistent-cache load
+    included.  Should one fire all the same, it is billed as the compile it
+    is, and no memory is read again in this process.
+    """
+    global _memory_read_compiles
+    import jax
+
+    from modin_tpu.observability import compile_ledger as _ledger_mod
+
+    func = getattr(func, "_jitted", func)
+    if _memory_read_compiles or not hasattr(func, "lower"):
+        return None
+    try:
+        if any(isinstance(leaf, jax.core.Tracer) for leaf in jax.tree_util.tree_leaves((f_args, f_kwargs))):
+            return None  # called while an enclosing program traces
+        key = _arg_key(f_args, f_kwargs)
+        per_func = _func_memory.get(func)
+        if per_func is not None and key in per_func:
+            return per_func[key]
+        made = _ledger_mod.compiles_on_this_thread()
+        memory = None
+        try:
+            with _ledger_mod.relowering():
+                compiled = func.lower(*f_args, **(f_kwargs or {})).compile()
+            memory = extract_memory(compiled.memory_analysis())
+        finally:
+            if _ledger_mod.compiles_on_this_thread() != made:
+                _memory_read_compiles = True
+        _func_memory.setdefault(func, {})[key] = memory
+        return memory
+    except Exception:
+        # a memory read must never break the call it reads
+        return None
 
 
 def dispatch_recorder(func: Any, f_args: tuple, f_kwargs: Optional[dict]):

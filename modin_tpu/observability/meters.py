@@ -564,6 +564,28 @@ def note_compile(duration_s: float) -> None:
     emit_metric("engine.compile_s", duration_s)
 
 
+def note_made(program: str, phase: str, seconds: float, loaded: bool = False) -> None:
+    """``seconds`` of making ``program`` on this thread, billed to every open
+    scope (``observability/compile_ledger.py``, which checks ``ACCOUNTING_ON``
+    first): ``phase`` is ``trace_s`` (its outermost trace), ``lower_s`` or
+    ``compile_s`` (a backend compile, or with ``loaded`` a persistent-cache
+    load)."""
+    stack = _spans.thread_requests()
+    if stack:
+        for qs in stack:
+            qs._on_made(program, phase, seconds, loaded)
+
+
+def note_temp_bytes(program: str, temp_bytes: Optional[int]) -> None:
+    """The temporary bytes the compiler gave ``program`` when the call on this
+    thread made it (``ops/_program.py``, which checks ``ACCOUNTING_ON``
+    first); ``None``, where the compiler did not say, writes nothing."""
+    stack = _spans.thread_requests()
+    if stack and temp_bytes is not None:
+        for qs in stack:
+            qs._on_temp_bytes(program, temp_bytes)
+
+
 def _device_resident_bytes() -> int:
     """Device-ledger resident bytes, via the one shared sampling seam
     (``spans._ledger_bytes``: never imports core.memory, swallows ledger
@@ -587,6 +609,11 @@ class QueryStats:
         "dispatches",
         "compiles",
         "compile_s",
+        "trace_s",
+        "lower_s",
+        "cache_loads",
+        "cache_load_s",
+        "programs_made",
         "bytes_parsed",
         "io_reads",
         "spills",
@@ -647,6 +674,15 @@ class QueryStats:
         self.dispatches = 0
         self.compiles = 0
         self.compile_s = 0.0
+        # how the scope's programs were made, from jax's own events: the
+        # outermost trace and lowering seconds, the part of compiles /
+        # compile_s the persistent cache answered, and all of it by program
+        # ({name: {trace_s, lower_s, compile_s, loaded[, temp_bytes]}})
+        self.trace_s = 0.0
+        self.lower_s = 0.0
+        self.cache_loads = 0
+        self.cache_load_s = 0.0
+        self.programs_made: Dict[str, dict] = {}
         self.bytes_parsed = 0
         self.io_reads = 0
         self.spills = 0
@@ -825,6 +861,34 @@ class QueryStats:
             by = self.launches_by_program
             by[program] = by.get(program, 0) + 1
 
+    def _made_entry(self, program: str) -> dict:
+        entry = self.programs_made.get(program)
+        if entry is None:
+            entry = self.programs_made[program] = {
+                "trace_s": 0.0, "lower_s": 0.0, "compile_s": 0.0, "loaded": False,
+            }
+        return entry
+
+    def _on_made(self, program: str, phase: str, seconds: float, loaded: bool) -> None:
+        with self._lock:
+            if self._closed:
+                return
+            entry = self._made_entry(program)
+            entry[phase] += seconds
+            if phase == "trace_s":
+                self.trace_s += seconds
+            elif phase == "lower_s":
+                self.lower_s += seconds
+            elif loaded:
+                entry["loaded"] = True
+                self.cache_loads += 1
+                self.cache_load_s += seconds
+
+    def _on_temp_bytes(self, program: str, temp_bytes: int) -> None:
+        with self._lock:
+            if not self._closed:
+                self._made_entry(program)["temp_bytes"] = temp_bytes
+
     def _on_groupby_form(self, form: str) -> None:
         with self._lock:
             if not self._closed:
@@ -886,6 +950,11 @@ class QueryStats:
             "dispatches": self.dispatches,
             "compiles": self.compiles,
             "compile_s": self.compile_s,
+            "trace_s": self.trace_s,
+            "lower_s": self.lower_s,
+            "cache_loads": self.cache_loads,
+            "cache_load_s": self.cache_load_s,
+            "programs_made": {name: dict(e) for name, e in self.programs_made.items()},
             "bytes_parsed": self.bytes_parsed,
             "io_reads": self.io_reads,
             "spills": self.spills,

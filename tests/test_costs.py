@@ -173,6 +173,28 @@ class TestCaptureSeam:
         assert ledger["signatures"], "cost ledger recorded nothing"
         assert snap is not None
 
+    def test_full_capture_reads_the_calls_executable_and_compiles_nothing(self):
+        """``Full`` takes ``memory_analysis()`` of the executable the call
+        built (``costs.program_memory``), not of a second compile."""
+        import jax.numpy as jnp
+
+        from modin_tpu.observability.compile_ledger import (
+            ensure_listener,
+            get_compile_ledger,
+        )
+        from modin_tpu.ops._program import named_jit
+
+        assert ensure_listener()
+        CostCapture.put("Full")
+        program = named_jit(lambda x: jnp.sort(x) * 2, "full_capture_probe")
+        x = jnp.arange(256.0)
+        program(x)  # the call builds the executable
+        before = get_compile_ledger().totals()[0]
+        cost = costs.capture_static(program, (x,), None)
+        assert get_compile_ledger().totals()[0] == before
+        assert isinstance(cost["temp_bytes"], float) and cost["peak_bytes"] > 0
+        assert cost["flops"] != "unknown" or cost["bytes_accessed"] != "unknown"
+
     def test_registry_series_under_meters(self):
         _require_tpu_on_jax()
         BenchmarkMode.put(True)

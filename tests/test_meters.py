@@ -334,6 +334,7 @@ class TestQueryStats:
         [
             "request_id", "host_self_s", "wait_s", "first_launch_s", "launches",
             "launches_by_program", "host_syncs", "d2h_bytes", "h2d_bytes", "spans",
+            "trace_s", "lower_s", "cache_loads", "cache_load_s", "programs_made",
         ],
     )
     def test_as_dict_carries_the_request_record(self, key):
@@ -450,6 +451,152 @@ class TestQueryStats:
         assert outer.launches_by_program == {"reduce_probe": 2}
         assert outer.first_launch_s is not None
         assert outer.first_launch_s <= outer.wall_s
+
+
+class TestProgramsMade:
+    """How a scope's programs were made: jax's trace, lowering, compile and
+    cache-load events billed by ``compile_ledger``'s listeners, and the
+    temporaries the compiler gave a program its call built."""
+
+    @pytest.fixture(autouse=True)
+    def _listeners(self):
+        from modin_tpu.observability.compile_ledger import ensure_listener
+
+        assert ensure_listener()
+
+    def test_a_first_call_records_how_its_program_was_made(self):
+        import jax.numpy as jnp
+
+        from modin_tpu.ops._program import named_jit
+
+        program = named_jit(lambda x: jnp.cumsum(x) * 2, "made_first_call")
+        x = jnp.arange(64.0)
+        with meters.query_stats("first") as first:
+            program(x)
+        assert first.compiles == 1
+        assert first.trace_s > 0 and first.lower_s > 0
+        made = first.programs_made["made_first_call"]
+        assert made["trace_s"] == first.trace_s and made["lower_s"] == first.lower_s
+        assert made["compile_s"] == pytest.approx(first.compile_s)
+        assert made["loaded"] is False and first.cache_loads == 0
+        assert isinstance(made["temp_bytes"], int) and made["temp_bytes"] >= 0
+        assert first.as_dict()["programs_made"] == first.programs_made
+        with meters.query_stats("second") as second:
+            program(x)
+        assert second.programs_made == {}
+        assert (second.compiles, second.trace_s, second.lower_s) == (0, 0.0, 0.0)
+
+    def test_a_helper_traced_inside_a_program_is_billed_once_in_it(self):
+        import jax.numpy as jnp
+        from jax._src import monitoring
+
+        from modin_tpu.observability.compile_ledger import TRACE_EVENT
+        from modin_tpu.ops._program import named_jit, traced_jit
+
+        traces = []
+
+        def listen(event, duration, **kwargs):
+            if event == TRACE_EVENT:
+                traces.append((kwargs.get("fun_name"), duration))
+
+        helper = traced_jit(lambda x: jnp.sort(x) + 1, "made_helper")
+        program = named_jit(lambda x: helper(x) * 2 - jnp.cumsum(x), "made_outer")
+        x = jnp.arange(64.0)
+        monitoring.register_event_duration_secs_listener(listen)
+        try:
+            with meters.query_stats("q") as qs:
+                program(x)
+        finally:
+            monitoring.unregister_event_duration_listener(listen)
+        outer = [d for name, d in traces if name == "made_outer"][0]
+        assert any(name == "made_helper" for name, _ in traces)
+        assert qs.trace_s == pytest.approx(outer)  # no compile fired inside it
+        assert qs.trace_s < sum(d for _, d in traces)
+        assert set(qs.programs_made) == {"made_outer"}
+
+    def test_the_memory_read_adds_no_compile(self):
+        import jax.numpy as jnp
+
+        from modin_tpu.observability import costs
+        from modin_tpu.observability.compile_ledger import get_compile_ledger
+        from modin_tpu.ops._program import named_jit
+
+        ledger = get_compile_ledger()
+        program = named_jit(lambda x: x[::-1] + 1, "made_no_second_compile")
+        x = jnp.arange(32.0)
+        before = ledger.totals()[0]
+        with meters.query_stats("q") as qs:
+            program(x)
+        assert ledger.totals()[0] - before == qs.compiles == 1
+        assert "temp_bytes" in qs.programs_made["made_no_second_compile"]
+        # read again, past the memo: jax hands back the executable it built
+        costs._func_memory.pop(program._jitted, None)
+        before = ledger.totals()[0]
+        memory = costs.program_memory(program, (x,), None)
+        assert memory["temp_bytes"] != costs.UNKNOWN
+        assert ledger.totals()[0] == before
+        assert not costs._memory_read_compiles
+
+    def test_no_scope_allocates_nothing_and_writes_nothing(self):
+        import jax.numpy as jnp
+
+        from modin_tpu.observability import costs, spans
+        from modin_tpu.ops._program import named_jit
+
+        program = named_jit(lambda x: jnp.tanh(x) * 3, "made_unscoped")
+        x = jnp.arange(16.0)
+        with meters.query_stats("closed") as closed:
+            pass
+        assert not meters.ACCOUNTING_ON
+        spans_before, meters_before = spans.span_alloc_count(), meters.meter_alloc_count()
+        program(x)
+        assert spans.span_alloc_count() == spans_before
+        assert meters.meter_alloc_count() == meters_before
+        assert closed.programs_made == {} and closed.trace_s == 0.0
+        assert program._jitted not in costs._func_memory  # no memory read made
+
+    @pytest.mark.parametrize("loaded", [True, False])
+    def test_a_cache_load_is_the_part_of_the_compiles_it_answered(self, loaded):
+        from modin_tpu.observability import compile_ledger as ledger
+
+        with meters.query_stats("q") as qs:
+            ledger._on_event_start(ledger.COMPILE_EVENT, 0.0, fun_name="jit(made_synthetic)")
+            if loaded:
+                ledger._on_event_duration(ledger.CACHE_LOAD_EVENT, 0.25)
+            ledger._on_event_duration(ledger.COMPILE_EVENT, 0.5, fun_name="jit(made_synthetic)")
+        assert (qs.compiles, qs.compile_s) == (1, 0.5)
+        assert (qs.cache_loads, qs.cache_load_s) == ((1, 0.5) if loaded else (0, 0.0))
+        assert qs.programs_made == {
+            "made_synthetic": {"trace_s": 0.0, "lower_s": 0.0, "compile_s": 0.5, "loaded": loaded}
+        }
+
+    def test_a_compile_inside_a_trace_is_not_billed_as_tracing(self):
+        from modin_tpu.observability import compile_ledger as ledger
+
+        start, end = ledger._on_event_start, ledger._on_event_duration
+        with meters.query_stats("q") as qs:
+            start(ledger.TRACE_EVENT, 0.0, fun_name="made_outer_trace")
+            start(ledger.TRACE_EVENT, 0.0, fun_name="made_inner_trace")
+            start(ledger.COMPILE_EVENT, 0.0, fun_name="jit(made_eager)")
+            end(ledger.COMPILE_EVENT, 0.25, fun_name="jit(made_eager)")
+            end(ledger.TRACE_EVENT, 0.5, fun_name="made_inner_trace")
+            end(ledger.TRACE_EVENT, 1.0, fun_name="made_outer_trace")
+            start(ledger.LOWER_EVENT, 0.0, fun_name="jit(made_outer_trace)")
+            end(ledger.LOWER_EVENT, 0.125, fun_name="jit(made_outer_trace)")
+        assert qs.trace_s == 0.75 and qs.lower_s == 0.125
+        assert set(qs.programs_made) == {"made_outer_trace", "made_eager"}
+        assert qs.programs_made["made_outer_trace"]["trace_s"] == 0.75
+        assert qs.programs_made["made_outer_trace"]["lower_s"] == 0.125
+        assert qs.programs_made["made_eager"]["compile_s"] == 0.25
+
+    def test_a_relowering_is_not_billed(self):
+        from modin_tpu.observability import compile_ledger as ledger
+
+        with meters.query_stats("q") as qs:
+            with ledger.relowering():
+                ledger._on_event_start(ledger.TRACE_EVENT, 0.0, fun_name="made_again")
+                ledger._on_event_duration(ledger.TRACE_EVENT, 0.5, fun_name="made_again")
+        assert qs.trace_s == 0.0 and qs.programs_made == {}
 
 
 # ====================================================================== #
