@@ -66,6 +66,7 @@ LOCKS: Tuple[Tuple[str, str, str], ...] = (
     # -- ops / plan caches --------------------------------------------- #
     ("ops.router_calibration", "lock", "kernel-router calibration table resolve-once"),
     ("ops.fused_cache", "lock", "fused-program LRU cache linkage"),
+    ("ops.aval_memo", "lock", "elementwise-node aval memo LRU linkage"),
     ("plan.storm", "lock", "recompile-storm signature table"),
     ("plan.scan_cache", "lock", "scan-node parse cache (parses happen outside it)"),
     ("views.registry", "rlock", "THE derived-artifact cache (invalidation re-enters via drop hooks)"),

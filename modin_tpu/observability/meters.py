@@ -529,6 +529,17 @@ def note_elementwise_form(form: str, times: int) -> None:
             qs._on_elementwise_form(form, times)
 
 
+def note_aval_memo(outcome: str) -> None:
+    """One elementwise node's abstract value (``ops/lazy.py`` ``_eval_aval``,
+    which checks ``ACCOUNTING_ON`` first): ``hit`` from the memo, ``miss``
+    traced and kept, ``bypass`` traced because an argument is of a kind the
+    memo's key cannot describe."""
+    stack = _spans.thread_requests()
+    if stack:
+        for qs in stack:
+            qs._on_aval_memo(outcome)
+
+
 def note_reduction_form(form: str) -> None:
     """One row-wise reduction's form, chosen from its column count
     (``ops/reductions.py``, which checks ``ACCOUNTING_ON`` first):
@@ -645,6 +656,7 @@ class QueryStats:
         "launches_by_program",
         "groupby_forms",
         "elementwise_forms",
+        "aval_memo",
         "reduction_forms",
         "host_syncs",
         "d2h_bytes",
@@ -741,6 +753,9 @@ class QueryStats:
         self.groupby_forms: Dict[str, int] = {}
         # columns of elementwise nodes built in an operand-adaptive form
         self.elementwise_forms: Dict[str, int] = {}
+        # elementwise nodes whose abstract value the memo answered (hit),
+        # traced and kept (miss), or traced past it (bypass)
+        self.aval_memo: Dict[str, int] = {}
         # row-wise reductions by the form their column count chose
         self.reduction_forms: Dict[str, int] = {}
         self.host_syncs = 0
@@ -901,6 +916,11 @@ class QueryStats:
                     self.elementwise_forms.get(form, 0) + times
                 )
 
+    def _on_aval_memo(self, outcome: str) -> None:
+        with self._lock:
+            if not self._closed:
+                self.aval_memo[outcome] = self.aval_memo.get(outcome, 0) + 1
+
     def _on_reduction_form(self, form: str) -> None:
         with self._lock:
             if not self._closed:
@@ -986,6 +1006,7 @@ class QueryStats:
             "launches_by_program": dict(self.launches_by_program),
             "groupby_forms": dict(self.groupby_forms),
             "elementwise_forms": dict(self.elementwise_forms),
+            "aval_memo": dict(self.aval_memo),
             "reduction_forms": dict(self.reduction_forms),
             "host_syncs": self.host_syncs,
             "d2h_bytes": self.d2h_bytes,

@@ -133,8 +133,82 @@ class LazyExpr:
         return f"LazyExpr({self.op}, size={self.size}, aval={self.aval})"
 
 
+# (op, static, one fact per argument) -> the node's ShapeDtypeStruct.  A
+# frame's columns build the same nodes request after request, and tracing one
+# costs a millisecond of host time in which the device has nothing to run.
+# Holds shapes and dtypes only, never an operand; LRU-bounded, serialized by
+# its own lock like _FUSED_CACHE.
+_AVAL_MEMO: "OrderedDict[Any, Any]" = OrderedDict()
+_AVAL_MEMO_LOCK = named_lock("ops.aval_memo")
+_AVAL_MEMO_SIZE = 4096
+
+# eval_shape refuses a Python int outside int64: such a node is traced, and
+# raises, as without the memo
+_INT64_MIN, _INT64_MAX = -(2**63), 2**63 - 1
+
+
+def _aval_key(op: str, args: Tuple[Any, ...], static: Tuple) -> Optional[Tuple]:
+    """What ``jax.eval_shape`` reads of a node, as the fused cache's
+    fingerprint keys it (:func:`_linearize`): an unmaterialized child's aval,
+    a leaf's dtype, shape, weak type and sharding (which the trace sees, though
+    the aval it returns carries none), a scalar's type (its value never
+    reaches the trace).  Each fact leads with its kind: a numpy dtype
+    compares equal to ``int`` or ``"int64"``.  ``None`` where an argument is
+    of another kind, or the key is unhashable."""
+    import jax
+
+    facts = []
+    for a in args:
+        if isinstance(a, LazyExpr):
+            if a._result is None:
+                av = a.aval
+                facts.append(("node", av.shape, av.dtype, av.weak_type))
+                continue
+            a = a._result
+        if isinstance(a, jax.Array):
+            facts.append(("leaf", a.dtype, a.shape, a.weak_type, a.sharding))
+        elif isinstance(a, (np.integer, np.floating, np.bool_)):
+            facts.append(("np", a.dtype))
+        elif isinstance(a, _SCALAR_TYPES):
+            if isinstance(a, int) and not _INT64_MIN <= a <= _INT64_MAX:
+                return None
+            facts.append(("py", type(a)))
+        else:
+            return None
+    key = (op, static, tuple(facts))
+    try:
+        hash(key)
+    except TypeError:
+        return None
+    return key
+
+
 def _eval_aval(op: str, args: Tuple[Any, ...], static: Tuple):
-    """Abstract-evaluate one node (shape/dtype only; no compile)."""
+    """Abstract-evaluate one node (shape/dtype only; no compile), from
+    :data:`_AVAL_MEMO` where the same node was evaluated before."""
+    key = _aval_key(op, args, static)
+    if key is None:
+        outcome = "bypass"
+        aval = _trace_aval(op, args, static)
+    else:
+        with _AVAL_MEMO_LOCK:
+            aval = _AVAL_MEMO.get(key)
+            if aval is not None:
+                _AVAL_MEMO.move_to_end(key)
+        outcome = "hit"
+        if aval is None:
+            outcome = "miss"
+            aval = _trace_aval(op, args, static)
+            with _AVAL_MEMO_LOCK:
+                _AVAL_MEMO[key] = aval
+                while len(_AVAL_MEMO) > _AVAL_MEMO_SIZE:
+                    _AVAL_MEMO.popitem(last=False)
+    if graftmeter.ACCOUNTING_ON:
+        graftmeter.note_aval_memo(outcome)
+    return aval
+
+
+def _trace_aval(op: str, args: Tuple[Any, ...], static: Tuple):
     import jax
 
     from modin_tpu.ops.elementwise import get_op

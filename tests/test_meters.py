@@ -334,7 +334,7 @@ class TestQueryStats:
         [
             "request_id", "host_self_s", "wait_s", "first_launch_s", "launches",
             "launches_by_program", "host_syncs", "d2h_bytes", "h2d_bytes", "spans",
-            "trace_s", "lower_s", "cache_loads", "cache_load_s", "programs_made",
+            "trace_s", "lower_s", "cache_loads", "cache_load_s", "programs_made", "aval_memo",
         ],
     )
     def test_as_dict_carries_the_request_record(self, key):
@@ -375,6 +375,50 @@ class TestQueryStats:
             assert answer._query_compiler._modin_frame._columns[0].is_device
         assert qs.elementwise_forms == expect
         assert qs.as_dict()["elementwise_forms"] == expect
+
+    @pytest.mark.parametrize(
+        "scoped, expect",
+        [
+            # ten columns of one aval: the first is traced, nine are answered
+            (True, [{"miss": 1, "hit": 9}, {"hit": 10}]),
+            (False, [{}, {}]),
+        ],
+    )
+    def test_aval_memo_counts_each_node_by_outcome(self, monkeypatch, scoped, expect):
+        """One count an elementwise node, by whether the aval memo answered
+        it, traced and kept it, or traced past it; nothing outside a scope."""
+        from collections import OrderedDict
+
+        from modin_tpu.ops import lazy
+
+        _require_tpu_on_jax()
+        monkeypatch.setattr(lazy, "_AVAL_MEMO", OrderedDict())
+        frame = pd.DataFrame(
+            np.arange(40, dtype="int64").reshape(4, 10), columns=list("abcdefghij")
+        )
+        frame._query_compiler._modin_frame.materialize_device()
+        for want in expect:
+            if scoped:
+                with meters.query_stats("q") as qs:
+                    frame.add(2)
+            else:
+                frame.add(2)
+                with meters.query_stats("after") as qs:
+                    pass
+            assert qs.aval_memo == want
+            assert qs.as_dict()["aval_memo"] == want
+
+    def test_aval_memo_counts_what_its_key_cannot_describe_as_bypass(self, monkeypatch):
+        from collections import OrderedDict
+
+        from modin_tpu.ops import lazy
+
+        _require_tpu_on_jax()
+        monkeypatch.setattr(lazy, "_AVAL_MEMO", OrderedDict())
+        column = np.arange(8, dtype=np.int64)
+        with meters.query_stats("q") as qs:
+            lazy.lazy_op("add", np.asarray(column), 1)
+        assert qs.aval_memo == {"bypass": 1}
 
     @pytest.mark.parametrize(
         "n_cols, method, scoped, expect",
